@@ -17,8 +17,7 @@ axis (post-mortem events via the PR 6 anchor-pair mapping, reused from
 3. the merged, monotonically ordered timeline (``--tail N`` for the last
    N events).
 
-One invocation answers "which rank is slow / wedged and what was it doing"
-— the artifact five wedged-relay bench rounds (BENCH_r01–r05) never had.
+One invocation answers "which rank is slow / wedged and what was it doing".
 
 Usage:
   python scripts/fleet.py OUTPUT_DIR [--tail 80] [--json merged.json]

@@ -5,7 +5,7 @@ sequence parallelism + chunked-MLP (ChunkMBS) + remat, one REAL executed
 train step per point plus XLA's compile-time memory analysis per device.
 
 Run: python scripts/long_context_dryrun.py [--seq 32768 65536] [--sp u2cp4]
-Prints one JSON line per point; paste the table into BENCH_NOTES.md.
+Prints one JSON line per point.
 """
 
 import argparse
@@ -159,8 +159,8 @@ def main():
     force_cpu_devices(8)
     import jax
 
-    # reruns of the same points skip the multi-minute XLA:CPU compiles
-    jax.config.update("jax_compilation_cache_dir", "/tmp/veomni_jax_cache")
+    # reruns of the same points skip the multi-minute XLA:CPU compiles when
+    # the caller places a cache with JAX_COMPILATION_CACHE_DIR
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
     point = run_point(
         args.seq[0], LAYOUTS[args.sp], remat_policy=args.remat,

@@ -1,4 +1,4 @@
-"""Evidence artifact for the comm/compute-overlap story (VERDICT r3 #8).
+"""Evidence artifact for the comm/compute-overlap story.
 
 Thin CLI over ``veomni_tpu/utils/overlap_evidence.py`` (the census itself is
 a first-class API, regression-gated by ``tests/test_async_ulysses.py``).
@@ -14,7 +14,7 @@ This script produces the human-readable artifact:
    fetch every step (log_steps=1) vs amortized fetch (log_steps=50).
 
 Usage:  python scripts/overlap_evidence.py [out_dir]
-Writes a summary to stdout — paste into BENCH_NOTES.md.
+Writes a summary to stdout.
 """
 
 import os

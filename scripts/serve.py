@@ -86,16 +86,18 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _build_model(args):
+def build_model(preset: str = "", seed: int = 0):
+    """(params, cfg): a bench.py BENCH_PRESETS model, or the tiny demo model
+    when ``preset`` is empty; random weights from ``seed``."""
     import jax
     import jax.numpy as jnp
 
     from veomni_tpu.models import TransformerConfig, build_foundation_model
 
-    if args.preset:
+    if preset:
         from bench import bench_config
 
-        cfg = bench_config(preset=args.preset)
+        cfg = bench_config(preset=preset)
     else:  # tiny random demo model
         cfg = TransformerConfig(
             model_type="qwen3", vocab_size=256, hidden_size=64,
@@ -104,7 +106,7 @@ def _build_model(args):
             qk_norm=True, dtype=jnp.float32,
         )
     model = build_foundation_model(config=cfg)
-    params = model.family.init_params(jax.random.PRNGKey(args.seed), cfg)
+    params = model.family.init_params(jax.random.PRNGKey(seed), cfg)
     return params, cfg
 
 
@@ -134,6 +136,9 @@ def _ckpt_params_loader(step_dir):
 
 
 def main():
+    from veomni_tpu.utils.xla_flags import apply_performance_flags
+
+    apply_performance_flags()  # before the first JAX backend use
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--prompt-ids", action="append", default=[],
                     help="comma-separated token ids; repeatable")
@@ -256,7 +261,7 @@ def main():
 
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
-    params, cfg = _build_model(args)
+    params, cfg = build_model(args.preset, args.seed)
     ecfg = EngineConfig(
         num_slots=args.slots, block_size=args.block_size,
         max_model_len=args.max_model_len, log_every_steps=args.log_steps,

@@ -1,0 +1,379 @@
+"""What can be known about the chip without the chip.
+
+1. AOT compiles for a DESCRIBED ``v5e:2x2`` (the TPU compiler is installed;
+   no device is attached): both Pallas kernels, forward and backward, at the
+   widths ``chip_smoke.py`` runs them at, and the dense train step at the
+   smoke's size with the compiler's memory analysis held against 16 GiB.
+   Interpret mode and the attention impl are steered here, in the test: the
+   program picks both from ``jax.default_backend()``, which is the CPU.
+2. The flag and compile-cache channels, the peak tables, and the smoke's own
+   behaviour off the chip: it must fail at its device check, and its phase
+   functions must pass at toy size.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+# libtpu lets one process at a time load it (/tmp/libtpu_lockfile). Nothing
+# here touches a device, so this process and the child it starts may share
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+GIB = 2 ** 30
+
+import chip_smoke  # noqa: E402  (repo root is on sys.path via conftest)
+
+
+# --------------------------------------------------------------------------
+# 1. AOT compiles for a described v5e
+# --------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def v5e():
+    """Devices of a described (not attached) v5e 2x2."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    # a compile for a described device is written to a persistent cache but
+    # cannot be read back without the chip: keep the cache off around these
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+_COMPILE_UNDER_FLAGS = """
+import os, sys
+os.environ["TPU_LOG_DIR"] = "disabled"
+os.environ["VEOMNI_COMPILATION_CACHE"] = "0"
+sys.path.insert(0, sys.argv[1])
+from veomni_tpu.utils.xla_flags import apply_performance_flags
+assert apply_performance_flags()
+os.environ["LIBTPU_INIT_ARGS"] += sys.argv[2]
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+dev = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0]
+x = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16, sharding=SingleDeviceSharding(dev))
+jax.jit(lambda a: a @ a).lower(x).compile()
+print("COMPILED_UNDER", os.environ["LIBTPU_INIT_ARGS"])
+"""
+
+
+@pytest.mark.parametrize("extra,ok", [("", True), (" --xla_tpu_no_such_flag=true", False)],
+                         ids=["ours", "bogus"])
+def test_libtpu_takes_the_perf_flags(v5e, extra, ok):
+    """The TPU compiler reads LIBTPU_INIT_ARGS when it is first asked for a
+    topology and kills the process on a flag it does not know: so this runs
+    in a child, and a flag the installed libtpu rejects fails here and not
+    on the chip."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _COMPILE_UNDER_FLAGS, REPO, extra],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True, text=True,
+        timeout=120,
+    )
+    assert ("COMPILED_UNDER --xla_tpu_" in proc.stdout) is ok, proc.stderr[-2000:]
+    if not ok:
+        assert "xla_tpu_no_such_flag" in proc.stdout + proc.stderr
+
+
+@pytest.fixture
+def on_chip_kernels(monkeypatch):
+    """Compile the kernels for Mosaic, not for the interpreter."""
+    from veomni_tpu.ops.pallas import flash_attention, grouped_gemm
+
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped_gemm, "_interpret", lambda: False)
+
+
+def _described(device, shape, dtype):
+    from jax.sharding import SingleDeviceSharding
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(device))
+
+
+@pytest.mark.parametrize("direction,custom_calls", [("fwd", 1), ("bwd", 3)])
+def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, direction, custom_calls):
+    from veomni_tpu.ops.pallas.flash_attention import flash_attention
+
+    b, s, hq, hkv, d = (chip_smoke.FLASH_SHAPE[k] for k in ("b", "s", "hq", "hkv", "d"))
+    q = _described(v5e[0], (b, s, hq, d), jnp.bfloat16)
+    kv = _described(v5e[0], (b, s, hkv, d), jnp.bfloat16)
+    seg = _described(v5e[0], (b, s), jnp.int32)
+
+    def fwd(q, k, v, seg):
+        return flash_attention(q, k, v, segment_ids=seg, causal=True)
+
+    def loss(q, k, v, seg):
+        return fwd(q, k, v, seg).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, kv, kv, seg).compile().as_text()
+    assert text.count("tpu_custom_call") == custom_calls
+
+
+def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
+    """Plain FSDP on four chips: attention sits under GSPMD, which refuses to
+    partition a Mosaic kernel unless the wrapper shard_maps it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from veomni_tpu.ops.pallas.flash_attention import flash_attention
+    from veomni_tpu.parallel import init_parallel_state, use_parallel_state
+
+    ps = init_parallel_state(devices=v5e)
+    sh = NamedSharding(ps.mesh, P(ps.dp_axes))
+    q = jax.ShapeDtypeStruct((4, 1024, 16, 128), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((4, 1024, 8, 128), jnp.bfloat16, sharding=sh)
+    seg = jax.ShapeDtypeStruct((4, 1024), jnp.int32, sharding=sh)
+    with use_parallel_state(ps):
+        text = jax.jit(
+            lambda q, k, v, seg: flash_attention(q, k, v, segment_ids=seg, causal=True)
+        ).lower(q, kv, kv, seg).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("shape", chip_smoke.GMM_SHAPES,
+                         ids=lambda s: f"n{s['n']}e{s['e']}")
+@pytest.mark.parametrize("kernel", ["fwd", "dlhs", "drhs"])
+def test_grouped_gemm_lowers_for_v5e(v5e, on_chip_kernels, kernel, shape):
+    from veomni_tpu.ops.pallas import grouped_gemm as gg
+
+    m, k, n, e = (shape[x] for x in ("m", "k", "n", "e"))
+    lhs = _described(v5e[0], (m, k), jnp.bfloat16)
+    g = _described(v5e[0], (m, n), jnp.bfloat16)
+    rhs = _described(v5e[0], (e, k, n), jnp.bfloat16)
+    starts = _described(v5e[0], (e + 1,), jnp.int32)
+    fn, args = {
+        "fwd": (lambda a, w, st: gg._gmm_raw(a, w, st, gg._BM, gg._BN), (lhs, rhs, starts)),
+        "dlhs": (lambda a, w, st: gg._gmm_dlhs(a, w, st, gg._BM, gg._BK), (g, rhs, starts)),
+        "drhs": (lambda a, b_, st: gg._gmm_transpose(a, b_, st, e, gg._BM, gg._BK, gg._BN),
+                 (lhs, g, starts)),
+    }[kernel]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_smoke_train_step_fits_one_v5e(v5e, on_chip_kernels):
+    """The train step of configs/text/qwen3_0p6b_v5e.yaml, as the trainer
+    builds it, compiled for one described chip: the attention kernel is in
+    it, and arguments + temporaries leave room in 16 GiB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from veomni_tpu.arguments import VeOmniArguments, parse_args
+    from veomni_tpu.models import build_foundation_model
+    from veomni_tpu.models.auto import build_config
+    from veomni_tpu.optim import build_lr_scheduler, build_optimizer
+    from veomni_tpu.parallel import init_parallel_state, use_parallel_state
+    from veomni_tpu.train import build_train_state, build_train_step
+    from veomni_tpu.train.train_step import resolve_state_shardings
+
+    args = parse_args(VeOmniArguments, [os.path.join(REPO, chip_smoke.TRAIN_CONFIG)])
+    t = args.train
+    overrides = dict(args.model.config_overrides)
+    cfg = build_config(
+        overrides.pop("model_type"), **overrides, dtype=t.compute_dtype,
+        param_dtype=t.param_dtype, remat=t.enable_gradient_checkpointing,
+        remat_policy=t.gradient_checkpointing_policy,
+    )
+    ps = init_parallel_state(devices=v5e[:1])
+    with use_parallel_state(ps):
+        # on the chip the registry resolves attention to pallas_flash by
+        # platform; here the platform is the CPU, so the test pins it
+        model = build_foundation_model(
+            config=cfg, ops_implementation={"attention": "pallas_flash"})
+        opt = build_optimizer(
+            model.abstract(), optimizer=t.optimizer,
+            lr=build_lr_scheduler(t.lr_decay_style, lr=t.lr, train_steps=t.train_steps),
+        )
+
+        def make_state(rng):
+            return build_train_state(model.family.init_params(rng, cfg), opt)
+
+        abs_state = jax.eval_shape(make_state, jax.random.PRNGKey(0))
+        shardings = resolve_state_shardings(abs_state, model.get_parallel_plan(), ps)
+        keys = ("input_ids", "labels", "position_ids", "segment_ids")
+        batch_sh = {k: NamedSharding(ps.mesh, P(None, ps.dp_axes, ps.sp_axes)) for k in keys}
+        step = build_train_step(
+            model.loss_fn, opt, ps, state_shardings=shardings, batch_shardings=batch_sh,
+            max_grad_norm=t.max_grad_norm, skip_nonfinite=t.resilience_skip_nonfinite,
+        )
+        state = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            abs_state, shardings,
+        )
+        batch = {k: jax.ShapeDtypeStruct(
+            (1, t.micro_batch_size, args.data.max_seq_len), jnp.int32, sharding=batch_sh[k])
+            for k in keys}
+        compiled = step.lower(state, batch).compile()
+    # fwd, then the recomputed fwd + dkv + dq of the backward
+    assert compiled.as_text().count("tpu_custom_call") == 4
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 6 * GIB  # f32 params + AdamW moments
+    # 1 GiB under the 16 GiB line for what the process holds besides
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * GIB
+
+
+# --------------------------------------------------------------------------
+# 2. channels, tables, and the smoke off the chip
+# --------------------------------------------------------------------------
+def test_perf_flags_go_to_libtpu_init_args_once(monkeypatch):
+    from veomni_tpu.utils.xla_flags import _PERF_FLAGS, apply_performance_flags
+
+    monkeypatch.setenv("VEOMNI_COMPILATION_CACHE", "0")  # flags only
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+    monkeypatch.setenv(
+        "LIBTPU_INIT_ARGS", "--xla_tpu_enable_latency_hiding_scheduler=false")
+    assert apply_performance_flags() is True
+    assert apply_performance_flags() is True  # idempotent
+    assert "--xla_tpu_" not in os.environ["XLA_FLAGS"]
+    toks = os.environ["LIBTPU_INIT_ARGS"].split()
+    # the caller's own value stands; every flag is there exactly once
+    assert "--xla_tpu_enable_latency_hiding_scheduler=false" in toks
+    assert sorted(t.split("=")[0] for t in toks) == sorted(
+        f.split("=")[0] for f in _PERF_FLAGS)
+    monkeypatch.setenv("VEOMNI_XLA_PERF_FLAGS", "0")
+    monkeypatch.delenv("LIBTPU_INIT_ARGS")
+    assert apply_performance_flags() is False
+    assert "LIBTPU_INIT_ARGS" not in os.environ
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env_dir", "checkout_dir"])
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path, from_env):
+    from veomni_tpu.utils import xla_flags
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.delenv("VEOMNI_COMPILATION_CACHE", raising=False)
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert xla_flags.enable_compilation_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates  # JAX reads the variable
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+        assert xla_flags.enable_compilation_cache() == want
+        assert updates["jax_compilation_cache_dir"] == want
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    assert updates["jax_persistent_cache_min_entry_size_bytes"] == -1
+
+
+def test_peak_tables_raise_on_unlisted_accelerator(monkeypatch):
+    from veomni_tpu.utils import device
+
+    class FakeDevice:
+        platform = "tpu"
+        device_kind = "TPU v99 mega"
+
+    monkeypatch.setattr(device.jax, "devices", lambda: [FakeDevice()])
+    device._device_peaks.cache_clear()
+    try:
+        for fn in (device.get_device_peak_flops, device.get_device_peak_bandwidth,
+                   device.get_device_peak_interconnect_bandwidth):
+            with pytest.raises(KeyError, match="TPU v99 mega"):
+                fn()
+        FakeDevice.device_kind = "TPU v5 lite"
+        assert device.get_device_peak_flops() == 197e12
+        assert device.get_device_peak_bandwidth() == 819e9
+    finally:
+        device._device_peaks.cache_clear()
+
+
+def test_chip_smoke_fails_without_a_chip():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "VEOMNI_COMPILATION_CACHE": "0"}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert chip_smoke.NO_CHIP_MSG.format(platform="cpu") in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+TOY_DENSE = {
+    "model_type": "qwen3", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "qk_norm": True,
+    "tie_word_embeddings": True,
+}
+
+
+@pytest.fixture
+def toy_run(monkeypatch):
+    """The phases call the entry points, which set up flags and the compile
+    cache: keep both out of the test process."""
+    monkeypatch.setenv("VEOMNI_COMPILATION_CACHE", "0")
+    monkeypatch.setenv("VEOMNI_XLA_PERF_FLAGS", "0")
+    monkeypatch.chdir(REPO)  # phase_train changes directory; undo it
+
+
+def test_smoke_phase_kernels_at_toy_size(toy_run):
+    doc = chip_smoke.phase_kernels(
+        flash=dict(b=1, s=256, hq=2, hkv=1, d=64),
+        gmm=(dict(m=256, k=128, n=128, e=4), dict(m=256, k=128, n=128, e=8)),
+    )
+    assert doc["flash"]["err"]["dq"] <= chip_smoke.KERNEL_TOL
+    assert [g["groups"]["empty"] > 0 for g in doc["gmm"]] == [True, True]
+    json.dumps(doc)  # a phase's result is one JSON line
+
+
+def test_smoke_phase_train_at_toy_size(toy_run):
+    doc = chip_smoke.phase_train(overrides=[
+        "--model.config_overrides=" + json.dumps(TOY_DENSE),
+        "--data.max_seq_len=256", "--train.micro_batch_size=2", "--train.lr=1e-2",
+    ])
+    assert doc["steps"] == 8 and doc["train_step_traces"] == 1
+    assert doc["losses"][-1] < doc["losses"][0]
+    assert doc["resolved"]["attention"] == "xla"  # the CPU's; no kernel claimed
+    assert not os.path.exists(os.path.join(REPO, "output", "chip_smoke", "train"))
+    json.dumps(doc)
+
+
+def test_smoke_phase_serve_at_toy_size(toy_run):
+    # preset "": the tiny demo model of scripts/serve.py
+    doc = chip_smoke.phase_serve(preset="", prompt_lens=(20, 40), n_requests=6,
+                                 shared_prefix=16, max_new=8)
+    assert doc["completed"] == 6 and doc["prefix_hits"] > 0
+    assert doc["tokens_equal_to_greedy_generate"] == doc["tokens_total"] == 48
+    json.dumps(doc)
+
+
+def test_smoke_phase_serve_catches_a_wrong_token(toy_run, monkeypatch):
+    from veomni_tpu.models import decode
+
+    real = decode.greedy_generate
+
+    def off_by_one(params, cfg, prompt, **kw):
+        ids = real(params, cfg, prompt, **kw)
+        ids[len(prompt) + 3] = (ids[len(prompt) + 3] + 1) % cfg.vocab_size
+        return ids
+
+    monkeypatch.setattr(decode, "greedy_generate", off_by_one)
+    with pytest.raises(AssertionError, match="logit gap"):
+        chip_smoke.phase_serve(preset="", prompt_lens=(20,), n_requests=2,
+                               shared_prefix=16, max_new=8)
+
+
+def test_smoke_phase_multichip_on_four_virtual_devices(toy_run):
+    moe = dict(chip_smoke.MOE_BLOCKS, vocab_size=512, hidden_size=64,
+               intermediate_size=128, num_attention_heads=4, num_key_value_heads=2,
+               head_dim=16, num_experts=8, num_experts_per_tok=2,
+               moe_intermediate_size=32)
+    doc = chip_smoke.phase_multichip(dense_overrides=TOY_DENSE, dense_seq=64,
+                                     dense_rows=2, dense_steps=2, moe=moe,
+                                     moe_seq=64, moe_rows=4)
+    assert doc["dense"]["four_devices"]["mesh"] == {"fsdp": 2, "ulysses": 2}
+    assert doc["moe"]["four_devices"]["mesh"] == {"ep": 2, "fsdp": 2}
+    assert doc["moe"]["four_devices"]["collectives"]["all-to-all"] > 0
+    json.dumps(doc)

@@ -56,12 +56,54 @@ def test_flash_backward_matches_xla():
         )
 
 
-def test_flash_fallback_paths():
-    # sliding window and non-divisible seq fall back to XLA silently
-    q, k, v, seg = _inputs(s=100)
-    out = flash_attention(q, k, v, segment_ids=seg, causal=True)
-    ref = _attention_xla(q, k, v, segment_ids=seg, causal=True)
+@pytest.mark.parametrize("case", ["ragged_s", "cross", "window"])
+def test_flash_fallback_paths(case, monkeypatch):
+    # shapes/features the kernel doesn't cover go to XLA, and say so once
+    from veomni_tpu.ops.pallas import flash_attention as fa
+
+    seen = []
+    monkeypatch.setattr(
+        fa.logger, "info_once", lambda msg, *a: seen.append(msg % a)
+    )
+    q, k, v, seg = _inputs(s=100 if case == "ragged_s" else 256)
+    kwargs = dict(segment_ids=seg, causal=True)
+    if case == "cross":
+        k, v = k[:, :128], v[:, :128]
+        kwargs = dict(segment_ids=None, causal=False)
+    if case == "window":
+        kwargs["sliding_window"] = 64
+    out = flash_attention(q, k, v, **kwargs)
+    ref = _attention_xla(q, k, v, **kwargs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+    assert len(seen) == 1 and "pallas_flash hands" in seen[0], seen
+
+
+@pytest.mark.parametrize("batch,sharded", [(4, True), (2, False)],
+                         ids=["divisible", "indivisible"])
+def test_flash_under_gspmd_mesh(batch, sharded, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: on a multi-device mesh the
+    wrapper runs it in a shard_map over the batch axes, or, where the batch
+    does not divide, hands it to XLA and says so."""
+    from veomni_tpu.ops.pallas import flash_attention as fa
+    from veomni_tpu.parallel import init_parallel_state, use_parallel_state
+
+    seen = []
+    monkeypatch.setattr(
+        fa.logger, "info_once", lambda msg, *a: seen.append(msg % a)
+    )
+    q, k, v, seg = _inputs(b=batch)
+    ref = _attention_xla(q, k, v, segment_ids=seg, causal=True)
+    ps = init_parallel_state()  # fsdp=4: the batch is sharded four ways
+    fn = lambda q, k, v, seg: flash_attention(q, k, v, segment_ids=seg, causal=True)
+    with use_parallel_state(ps):
+        sh = ps.batch_sharding() if sharded else ps.replicated()
+        args = [jax.device_put(x, sh) for x in (q, k, v, seg)]
+        jaxpr = str(jax.make_jaxpr(fn)(*args))
+        got = jax.jit(fn)(*args)
+    assert ("shard_map" in jaxpr) is sharded
+    assert ("pallas_call" in jaxpr) is sharded
+    assert bool(seen) is not sharded, seen
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

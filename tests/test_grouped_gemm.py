@@ -52,3 +52,24 @@ def test_gmm_fallback_unaligned():
     ref = _group_gemm_ragged(lhs, rhs, gs)
     got = pallas_group_gemm(lhs, rhs, gs)  # falls back to ragged path
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
+
+
+def test_gmm_under_gspmd_mesh_goes_to_ragged(monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel, and expert-sorted rows have no
+    per-device split: outside shard_map on a multi-device mesh the wrapper
+    hands over to xla_ragged and says so."""
+    from veomni_tpu.ops.pallas import grouped_gemm as gg
+    from veomni_tpu.parallel import init_parallel_state, use_parallel_state
+
+    seen = []
+    monkeypatch.setattr(
+        gg.logger, "info_once", lambda msg, *a: seen.append(msg % a)
+    )
+    lhs, rhs, gs = _inputs(sizes=[100, 156, 0, 256])
+    ref = _group_gemm_ragged(lhs, rhs, gs)
+    with use_parallel_state(init_parallel_state()):
+        jaxpr = str(jax.make_jaxpr(pallas_group_gemm)(lhs, rhs, gs))
+        got = jax.jit(pallas_group_gemm)(lhs, rhs, gs)
+    assert "pallas_call" not in jaxpr
+    assert len(seen) == 1 and "GSPMD" in seen[0], seen
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)

@@ -16,14 +16,6 @@ import jax
 import numpy as np
 import pytest
 
-# jaxlib 0.4.x CPU rejects cross-process programs outright
-# ("Multiprocess computations aren't implemented on the CPU backend");
-# the capability this suite exercises only exists on newer jaxlib.
-pytestmark = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5, 0),
-    reason="multiprocess CPU computations unsupported by jaxlib < 0.5",
-)
-
 DRIVER = os.path.join(os.path.dirname(__file__), "tools", "multihost_train.py")
 
 

@@ -13,7 +13,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspa
 
 import jax
 
-from veomni_tpu.utils.jax_compat import set_virtual_cpu_devices
+from veomni_tpu.utils.testing import set_virtual_cpu_devices
 
 set_virtual_cpu_devices(8)
 jax.config.update("jax_cpu_enable_async_dispatch", False)

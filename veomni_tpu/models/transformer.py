@@ -40,8 +40,8 @@ def _remat_policy(cfg: TransformerConfig):
     """Map cfg.remat_policy to a jax.checkpoint policy (the TPU analogue of
     the reference's activation-offload contexts, ``offloading.py:32-74``).
 
-    Policies, by saved-activation footprint (measured on qwen3-0.6B,
-    seq 4096 x mb 8, 15.75G-HBM v5e — BENCH_NOTES r5):
+    Policies, by saved-activation footprint (qwen3-0.6B, seq 4096 x mb 8 on
+    a 15.75G-HBM v5e; pre-round builder note, not re-measured):
     - "dots": every no-batch-dim dot output (~22G — OOMs one v5e chip next
       to f32 optimizer state; the right default on pods where FSDP shards
       the state).

@@ -27,14 +27,8 @@ class KernelSpec:
     device_types: Tuple[str, ...] = ("any",)
     priority: int = 0  # higher wins
     name: str = ""
-    requires_pallas: bool = False
 
     def available(self) -> bool:
-        if self.requires_pallas:
-            from veomni_tpu.utils.device import supports_pallas
-
-            if not supports_pallas():
-                return False
         if "any" in self.device_types:
             return True
         return get_device_type() in self.device_types
@@ -52,12 +46,11 @@ class _KernelRegistry:
         *,
         device_types: Tuple[str, ...] = ("any",),
         priority: int = 0,
-        requires_pallas: bool = False,
     ):
         def _do(fn):
             self._ops.setdefault(op_name, {})[impl_name] = KernelSpec(
                 fn=fn, device_types=device_types, priority=priority,
-                name=impl_name, requires_pallas=requires_pallas,
+                name=impl_name,
             )
             return fn
 
@@ -90,6 +83,13 @@ class _KernelRegistry:
 
     @functools.lru_cache(maxsize=None)
     def resolve(self, op_name: str) -> Callable:
+        return self._select(op_name).fn
+
+    def resolved_name(self, op_name: str) -> str:
+        """Name of the impl :meth:`resolve` returns for ``op_name``."""
+        return self._select(op_name).name
+
+    def _select(self, op_name: str) -> KernelSpec:
         impls = self._ops.get(op_name)
         if not impls:
             raise KeyError(f"no kernels registered for op {op_name!r}")
@@ -97,15 +97,15 @@ class _KernelRegistry:
         if pin is not None:
             if pin not in impls:
                 raise KeyError(f"op {op_name!r} has no impl {pin!r}: {sorted(impls)}")
-            return impls[pin].fn
+            return impls[pin]
         if env_bool("VEOMNI_FORCE_EAGER_OPS") and "xla" in impls:
-            return impls["xla"].fn
+            return impls["xla"]
         candidates = [s for s in impls.values() if s.available()]
         if not candidates:
             raise RuntimeError(f"no available impl for op {op_name!r} on {get_device_type()}")
         best = max(candidates, key=lambda s: s.priority)
         logger.info_once("op %s -> impl %s", op_name, best.name)
-        return best.fn
+        return best
 
 
 KERNEL_REGISTRY = _KernelRegistry()

@@ -27,9 +27,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
-from veomni_tpu.utils.jax_compat import pallas_tpu_compiler_params
+from veomni_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -141,7 +144,7 @@ def _fwd(q, k, v, segment_ids, scale, causal, bq, bk):
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -288,7 +291,7 @@ def _bwd(scale, causal, bq, bk, residuals, g):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -312,7 +315,7 @@ def _bwd(scale, causal, bq, bk, residuals, g):
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, iq, jk: (bi, hi, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -342,8 +345,29 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ==========================================================================
 # Public op (registered)
 # ==========================================================================
+def _handoff_reason(q, k, v, sliding_window, sinks, bq, bk, pstate) -> Optional[str]:
+    """Why this call cannot take the kernel (None: it can)."""
+    b, s, hq, d = q.shape
+    if sliding_window is not None:
+        return "sliding_window"
+    if sinks is not None:
+        return "sinks"
+    if v.shape[-1] != d:
+        return "v head_dim != qk head_dim"
+    if k.shape[1] != s:
+        return "Sq != Sk"
+    # lane-aligned blocks that tile the sequence exactly
+    if s % bq or s % bk or bq % _LANES or bk % _LANES:
+        return f"S not a multiple of {_LANES}"
+    if hq % k.shape[2]:
+        return "q heads not a multiple of kv heads"
+    if pstate is not None and b % pstate.dp_size:
+        return f"batch not a multiple of the mesh's dp extent {pstate.dp_size}"
+    return None
+
+
 @KERNEL_REGISTRY.register(
-    "attention", "pallas_flash", device_types=("tpu",), priority=10, requires_pallas=True
+    "attention", "pallas_flash", device_types=("tpu",), priority=10
 )
 def flash_attention(
     q,
@@ -357,22 +381,26 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
 ):
-    """[B, S, H, D] facade-layout wrapper. Falls back to the XLA impl for
-    shapes/features the kernel doesn't cover (sliding window, sinks, MLA's
-    asymmetric v-dim, tiny/ragged S).
+    """[B, S, H, D] facade-layout wrapper. Shapes/features the kernel
+    doesn't cover (sliding window, sinks, MLA's asymmetric v-dim, cross
+    attention, tiny/ragged S) go to the XLA impl, with one log line naming
+    the reason. GSPMD cannot partition a Mosaic kernel, so under it on a
+    multi-device mesh the kernel runs in a shard_map over the batch (dp)
+    axes; inside the Ulysses/ring shard_map it is already per-device.
     """
+    from veomni_tpu.parallel.parallel_state import gspmd_parallel_state
+
     b, s, hq, d = q.shape
     bq, bk = min(block_q, s), min(block_k, s)
-    # kernel path needs lane-aligned blocks that tile the sequence exactly
-    if (
-        sliding_window is not None
-        or sinks is not None
-        or v.shape[-1] != d
-        or s % bq or s % bk or bq % 128 or bk % 128
-        or hq % k.shape[2]
-    ):
+    pstate = gspmd_parallel_state()
+    reason = _handoff_reason(q, k, v, sliding_window, sinks, bq, bk, pstate)
+    if reason is not None:
         from veomni_tpu.ops.attention import _attention_xla
 
+        logger.info_once(
+            "op attention: pallas_flash hands q%s kv%s to xla (%s)",
+            tuple(q.shape), tuple(k.shape), reason,
+        )
         return _attention_xla(
             q, k, v, segment_ids=segment_ids, causal=causal,
             softmax_scale=softmax_scale, sliding_window=sliding_window,
@@ -381,8 +409,19 @@ def flash_attention(
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
     if segment_ids is None:
         segment_ids = jnp.zeros((b, s), jnp.int32)
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _flash_bhsd(qt, kt, vt, segment_ids.astype(jnp.int32), scale, causal, bq, bk)
-    return jnp.swapaxes(out, 1, 2)
+
+    def kernel(q, k, v, seg):
+        out = _flash_bhsd(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+            seg.astype(jnp.int32), scale, causal, bq, bk,
+        )
+        return jnp.swapaxes(out, 1, 2)
+
+    if pstate is not None:
+        qkv_spec = P(pstate.dp_axes, None, None, None)
+        kernel = jax.shard_map(
+            kernel, mesh=pstate.mesh,
+            in_specs=(qkv_spec, qkv_spec, qkv_spec, P(pstate.dp_axes, None)),
+            out_specs=qkv_spec, check_vma=False,
+        )
+    return kernel(q, k, v, segment_ids)

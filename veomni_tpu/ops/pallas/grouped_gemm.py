@@ -8,7 +8,10 @@ group_sizes [E] -> out [M, N]. The grid runs (m_tile, n_tile, expert) with
 the expert dim sequential; group start offsets ride in scalar-prefetch SMEM,
 and a tile only does work for experts whose row range intersects it (rows
 outside the expert are masked to zero before the MXU dot, so boundary tiles
-stay correct without dynamic shapes).
+stay correct without dynamic shapes). Index maps may only load SCALARS from
+the prefetched refs (Mosaic: "Can only load scalars from SMEM"), so the
+per-tile [first, last] intersecting-expert table is computed in XLA outside
+the kernel and prefetched alongside the offsets.
 
 Backward (custom VJP):
   dlhs = gmm(g, rhs^T)            -- the same kernel, weights transposed
@@ -26,7 +29,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
-from veomni_tpu.utils.jax_compat import pallas_tpu_compiler_params
+from veomni_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
 
 
 def _interpret() -> bool:
@@ -34,7 +39,7 @@ def _interpret() -> bool:
 
 
 # ---------------------------------------------------------------- forward
-def _gmm_kernel(gs_ref, lhs_ref, rhs_ref, out_ref, acc_scr, *, bm, bn):
+def _gmm_kernel(gs_ref, tiles_ref, lhs_ref, rhs_ref, out_ref, acc_scr, *, bm, bn):
     i, e = pl.program_id(0), pl.program_id(2)
     ne = pl.num_programs(2)
 
@@ -61,19 +66,24 @@ def _gmm_kernel(gs_ref, lhs_ref, rhs_ref, out_ref, acc_scr, *, bm, bn):
         out_ref[...] = acc_scr[...].astype(out_ref.dtype)
 
 
-def _rhs_index_map(bm):
-    """Avoid redundant weight DMA: non-intersecting (tile, expert) steps map
-    to the tile's first intersecting expert, so the block index stays
-    constant across skipped steps and Pallas reuses the resident block."""
+def _tile_expert_range(group_starts, m: int, bm: int):
+    """[2, m // bm] int32: per m-tile, the first and last expert whose row
+    range can intersect the tile (clipped into [0, E-1], so padding tiles
+    past the last group point at a real weight block)."""
+    e = group_starts.shape[0] - 1
+    lo = jnp.arange(m // bm, dtype=jnp.int32) * bm
+    first = jnp.searchsorted(group_starts[1:], lo, side="right")
+    last = jnp.searchsorted(group_starts[:-1], lo + bm, side="left") - 1
+    first = jnp.clip(first, 0, e - 1)
+    return jnp.stack([first, jnp.clip(last, first, e - 1)]).astype(jnp.int32)
 
-    def index_map(i, j, e, gs):
-        lo = i * bm
-        intersects = jnp.logical_and(gs[e + 1] > lo, gs[e] < lo + bm)
-        first = jnp.sum((gs[1:] <= lo).astype(jnp.int32))
-        e_eff = jnp.where(intersects, e, jnp.minimum(first, gs.shape[0] - 2))
-        return (e_eff, 0, j)
 
-    return index_map
+def _effective_expert(i, e, tiles):
+    """Avoid redundant weight DMA: (tile, expert) steps outside the tile's
+    intersecting range clamp to its boundary expert, so the block index
+    stays constant across skipped steps and Pallas reuses the resident
+    block. Scalar loads only."""
+    return jnp.minimum(jnp.maximum(e, tiles[0, i]), tiles[1, i])
 
 
 def _gmm_raw(lhs, rhs, group_starts, bm: int, bn: int):
@@ -83,25 +93,28 @@ def _gmm_raw(lhs, rhs, group_starts, bm: int, bn: int):
     return pl.pallas_call(
         functools.partial(_gmm_kernel, bm=bm, bn=bn),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((bm, k), lambda i, j, e, gs: (i, 0)),
-                pl.BlockSpec((1, k, bn), _rhs_index_map(bm)),
+                pl.BlockSpec((bm, k), lambda i, j, e, gs, tiles: (i, 0)),
+                pl.BlockSpec(
+                    (1, k, bn),
+                    lambda i, j, e, gs, tiles: (_effective_expert(i, e, tiles), 0, j),
+                ),
             ],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, e, gs: (i, j)),
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, e, gs, tiles: (i, j)),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
-    )(group_starts, lhs, rhs)
+    )(group_starts, _tile_expert_range(group_starts, m, bm), lhs, rhs)
 
 
 # ---------------------------------------------------------------- dlhs
-def _gmm_dlhs_kernel(gs_ref, g_ref, rhs_ref, out_ref, acc_scr, *, bm):
+def _gmm_dlhs_kernel(gs_ref, tiles_ref, g_ref, rhs_ref, out_ref, acc_scr, *, bm):
     """dlhs tile [bm, bk] = sum_e mask_e(g) @ rhs[e]^T, contracting over N
     inside the kernel (no materialized weight transpose)."""
     i, e = pl.program_id(0), pl.program_id(2)
@@ -134,32 +147,27 @@ def _gmm_dlhs(g, rhs, group_starts, bm: int, bk: int):
     m, n = g.shape
     e, k, _ = rhs.shape
     grid = (m // bm, k // bk, e)
-
-    def rhs_map(i, j, e_, gs):
-        lo = i * bm
-        intersects = jnp.logical_and(gs[e_ + 1] > lo, gs[e_] < lo + bm)
-        first = jnp.sum((gs[1:] <= lo).astype(jnp.int32))
-        e_eff = jnp.where(intersects, e_, jnp.minimum(first, gs.shape[0] - 2))
-        return (e_eff, j, 0)
-
     return pl.pallas_call(
         functools.partial(_gmm_dlhs_kernel, bm=bm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((bm, n), lambda i, j, e_, gs: (i, 0)),
-                pl.BlockSpec((1, bk, n), rhs_map),
+                pl.BlockSpec((bm, n), lambda i, j, e_, gs, tiles: (i, 0)),
+                pl.BlockSpec(
+                    (1, bk, n),
+                    lambda i, j, e_, gs, tiles: (_effective_expert(i, e_, tiles), j, 0),
+                ),
             ],
-            out_specs=pl.BlockSpec((bm, bk), lambda i, j, e_, gs: (i, j)),
+            out_specs=pl.BlockSpec((bm, bk), lambda i, j, e_, gs, tiles: (i, j)),
             scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, k), g.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
-    )(group_starts, g, rhs)
+    )(group_starts, _tile_expert_range(group_starts, m, bm), g, rhs)
 
 
 # ------------------------------------------------------------- drhs kernel
@@ -208,7 +216,7 @@ def _gmm_transpose(lhs, g, group_starts, e: int, bm: int, bk: int, bn: int):
             scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, k, n), lhs.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -242,7 +250,6 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 @KERNEL_REGISTRY.register(
     "group_gemm", "pallas_gmm", device_types=("tpu",), priority=10,
-    requires_pallas=True,
 )
 def pallas_group_gemm(tokens, weights, group_sizes):
     return _pallas_group_gemm(tokens, weights, group_sizes)
@@ -251,21 +258,35 @@ def pallas_group_gemm(tokens, weights, group_sizes):
 # "pallas" alias matches the documented moe_implementation values
 KERNEL_REGISTRY.register(
     "group_gemm", "pallas", device_types=("tpu",), priority=10,
-    requires_pallas=True,
 )(pallas_group_gemm)
 
 
 def _pallas_group_gemm(tokens, weights, group_sizes):
     """tokens [M,K] sorted by expert; weights [E,K,N]; group_sizes [E].
 
-    Falls back to the XLA ragged path when shapes don't tile (M/K/N not
-    multiples of 128).
+    Goes to the XLA ragged path, with one log line saying so, for shapes
+    that don't tile (M/K/N not multiples of 128) and under GSPMD on a
+    multi-device mesh: GSPMD cannot partition a Mosaic kernel, and rows
+    sorted by expert across the whole mesh have no per-device split (the EP
+    dispatch calls this inside its shard_map, where it is per-device).
     """
+    from veomni_tpu.parallel.parallel_state import gspmd_parallel_state
+
     m, k = tokens.shape
     e, _, n = weights.shape
-    if m % _BM or n % _BN or k % _BK:
+    reason = (
+        f"M/K/N must be multiples of {_BM}" if m % _BM or n % _BN or k % _BK
+        else "under GSPMD on a multi-device mesh, outside shard_map"
+        if gspmd_parallel_state() is not None
+        else None
+    )
+    if reason is not None:
         from veomni_tpu.ops.group_gemm import _group_gemm_ragged
 
+        logger.info_once(
+            "op group_gemm: pallas_gmm hands M=%d K=%d N=%d E=%d to "
+            "xla_ragged (%s)", m, k, n, e, reason,
+        )
         return _group_gemm_ragged(tokens, weights, group_sizes)
     starts = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(group_sizes.astype(jnp.int32))]

@@ -42,8 +42,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.utils.jax_compat import shard_map
-
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
 from veomni_tpu.parallel.parallel_state import AXIS_CP, AXIS_ULYSSES, ParallelState
 from veomni_tpu.parallel.ring_attention import ring_attention_local
@@ -157,7 +155,7 @@ def async_ulysses_attention(
         return jnp.concatenate([outs, last], axis=2)  # original head order
 
     in_specs = (qkv_spec, qkv_spec, qkv_spec, seg_spec, sinks_spec)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=pstate.mesh,
         in_specs=in_specs,

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from veomni_tpu.utils.jax_compat import shard_map
-
 from veomni_tpu import ops
 from veomni_tpu.parallel.parallel_state import AXIS_EP, ParallelState
 
@@ -151,7 +149,7 @@ def ep_moe_mlp(x, lp, cfg, pstate: ParallelState):
         dropped = jax.lax.pmean(dropped, axis_name=pstate.mesh.axis_names)
         return out.reshape(bl, sl, h), dropped
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=pstate.mesh,
         in_specs=(x_spec, topk_spec, topk_spec, experts_specs),

@@ -296,6 +296,19 @@ def get_parallel_state_or_none() -> Optional[ParallelState]:
         return None
 
 
+def gspmd_parallel_state() -> Optional[ParallelState]:
+    """The ambient state when the caller is being traced under GSPMD on a
+    mesh of more than one device; None on one device, with no mesh, and
+    inside a ``shard_map`` region (whose body is already per-device). What a
+    Mosaic kernel asks before it runs: GSPMD cannot partition one."""
+    state = get_parallel_state_or_none()
+    if state is None or state.world_size == 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return state
+
+
 @contextlib.contextmanager
 def use_parallel_state(state_or_name):
     """Scope the ambient ParallelState (reference ``use_parallel_state``)."""

@@ -67,9 +67,7 @@ def ring_attention_local(
     contiguous chunk of the global sequence (chunk index = this rank's
     position along ``axis_name``). Returns [B, Sl, Hq, D].
     """
-    from veomni_tpu.utils.jax_compat import axis_size
-
-    cp = axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, sl, hq, d = q.shape
     hkv = k.shape[2]

@@ -38,8 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from veomni_tpu.utils.jax_compat import shard_map
-
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
 from veomni_tpu.parallel.parallel_state import AXIS_CP, AXIS_ULYSSES, ParallelState
 from veomni_tpu.parallel.ring_attention import ring_attention_local
@@ -210,7 +208,7 @@ def ulysses_monolithic(
         return out
 
     in_specs = (qkv_spec, qkv_spec, qkv_spec, seg_spec, sinks_spec)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=pstate.mesh,
         in_specs=in_specs,

@@ -16,8 +16,6 @@ ENV_DEFAULTS: Dict[str, Any] = {
     "VEOMNI_LOG_LEVEL": "INFO",
     # Force all kernel-registry ops to the eager XLA impl (skip Pallas).
     "VEOMNI_FORCE_EAGER_OPS": "0",
-    # Directory for JAX persistent compilation cache ("" disables).
-    "VEOMNI_COMPILE_CACHE": "",
     # Use donated buffers in the train step (disable when debugging).
     "VEOMNI_DONATE_STATE": "1",
     # Seq length above which the default XLA attention switches to the

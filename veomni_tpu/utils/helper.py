@@ -13,7 +13,7 @@ from typing import Any, Dict, Optional
 import jax
 
 from veomni_tpu.utils.count_flops import FlopsCounter
-from veomni_tpu.utils.device import get_device_peak_flops
+from veomni_tpu.utils.device import get_device_peak_flops, get_device_type
 from veomni_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -61,7 +61,10 @@ class EnvironMeter:
             "step_time_s": dt,
             "consumed_tokens": float(self.consumed_tokens),
         }
-        if self.flops_counter is not None and (tokens or self._step_extra_flops):
+        # achieved FLOP/s and MFU are device metrics: off the TPU they are
+        # left out, never computed against the CPU's nominal peak
+        if (self.flops_counter is not None and get_device_type() == "tpu"
+                and (tokens or self._step_extra_flops)):
             eff_seq = self._step_token_seq / tokens if tokens else 0.0
             achieved = self.flops_counter.batch_flops(tokens, eff_seq or tokens)
             achieved += 3.0 * self._step_extra_flops
@@ -111,8 +114,7 @@ class Watchdog:
     history; the path lands in :attr:`last_postmortem_path`), invokes
     ``on_stall(stack_dump)`` once per stall, and — unless ``exit_code`` is
     None — hard-exits the process
-    (``os._exit``; a wedged backend can't be timeout-killed politely, see
-    BENCH_NOTES.md). With ``exit_code=None`` the run is left alive: the stall
+    (``os._exit``; a wedged backend can't be timeout-killed politely). With ``exit_code=None`` the run is left alive: the stall
     may be a bounded hiccup (slow shared fs) the retry layer absorbs, and the
     dump is the observability artifact either way. Re-arms after firing, so a
     long stall produces periodic dumps rather than one.
