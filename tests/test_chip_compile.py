@@ -89,13 +89,29 @@ def test_libtpu_takes_the_perf_flags(v5e, extra, ok):
         assert "xla_tpu_no_such_flag" in proc.stdout + proc.stderr
 
 
-@pytest.fixture
-def on_chip_kernels(monkeypatch):
-    """Compile the kernels for Mosaic, not for the interpreter."""
+def _for_mosaic(monkeypatch):
     from veomni_tpu.ops.pallas import flash_attention, grouped_gemm
 
     monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
     monkeypatch.setattr(grouped_gemm, "_interpret", lambda: False)
+
+
+@pytest.fixture
+def on_chip_kernels(monkeypatch):
+    """Compile the kernels for Mosaic, not for the interpreter."""
+    _for_mosaic(monkeypatch)
+
+
+def _kernel_instructions(text):
+    """{kernel name: custom calls named after it} in a compiled text."""
+    import re
+
+    from veomni_tpu.observability.scopes import KERNEL_NAMES
+
+    found = re.findall(r"^\s*(?:ROOT\s+)?%?([a-z_]+)\.\d+ = .* custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text, re.MULTILINE)
+    assert set(found) <= set(KERNEL_NAMES), found
+    return {k: found.count(k) for k in set(found)}
 
 
 def _described(device, shape, dtype):
@@ -114,7 +130,11 @@ def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, direction, custom_
     seg = _described(v5e[0], (b, s), jnp.int32)
 
     def fwd(q, k, v, seg):
-        return flash_attention(q, k, v, segment_ids=seg, causal=True)
+        # under its scope, as the model calls it: a kernel's instruction is
+        # named after the kernel alone only below some named scope (bare
+        # under jax.grad it comes out as jvp_flash_fwd_)
+        with jax.named_scope("attn.flash"):
+            return flash_attention(q, k, v, segment_ids=seg, causal=True)
 
     def loss(q, k, v, seg):
         return fwd(q, k, v, seg).astype(jnp.float32).sum()
@@ -122,6 +142,11 @@ def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, direction, custom_
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
     text = jax.jit(fn).lower(q, kv, kv, seg).compile().as_text()
     assert text.count("tpu_custom_call") == custom_calls
+    # the kernels' own names are the custom calls' instruction names
+    # (observability/scopes.py::KERNEL_NAMES): a trace tells them apart
+    want = {"fwd": {"flash_fwd": 1}, "bwd": {"flash_fwd": 1, "flash_bwd_dkv": 1,
+                                             "flash_bwd_dq": 1}}[direction]
+    assert _kernel_instructions(text) == want
 
 
 def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
@@ -163,12 +188,20 @@ def test_grouped_gemm_lowers_for_v5e(v5e, on_chip_kernels, kernel, shape):
     }[kernel]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+    assert _kernel_instructions(text) == {
+        {"fwd": "gmm_fwd", "dlhs": "gmm_dlhs", "drhs": "gmm_drhs"}[kernel]: 1}
 
 
-def test_smoke_train_step_fits_one_v5e(v5e, on_chip_kernels):
+@pytest.fixture(scope="module")
+def smoke_step(v5e):
     """The train step of configs/text/qwen3_0p6b_v5e.yaml, as the trainer
-    builds it, compiled for one described chip: the attention kernel is in
-    it, and arguments + temporaries leave room in 16 GiB."""
+    builds it, compiled once for one described chip (several tests read it)."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        _for_mosaic(monkeypatch)
+        return _compile_smoke_step(v5e)
+
+
+def _compile_smoke_step(v5e):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from veomni_tpu.arguments import VeOmniArguments, parse_args
@@ -216,13 +249,89 @@ def test_smoke_train_step_fits_one_v5e(v5e, on_chip_kernels):
         batch = {k: jax.ShapeDtypeStruct(
             (1, t.micro_batch_size, args.data.max_seq_len), jnp.int32, sharding=batch_sh[k])
             for k in keys}
-        compiled = step.lower(state, batch).compile()
+        return step.lower(state, batch).compile()
+
+
+def test_smoke_train_step_fits_one_v5e(smoke_step):
+    """The attention kernel is in it, and arguments + temporaries leave room
+    in 16 GiB."""
+    compiled = smoke_step
     # fwd, then the recomputed fwd + dkv + dq of the backward
-    assert compiled.as_text().count("tpu_custom_call") == 4
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert _kernel_instructions(text) == {"flash_fwd": 2, "flash_bwd_dkv": 1, "flash_bwd_dq": 1}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 6 * GIB  # f32 params + AdamW moments
     # 1 GiB under the 16 GiB line for what the process holds besides
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * GIB
+
+
+# what carries no scope of the taxonomy in the compiled step, by the last
+# component of its op_name: lax.scan's own slicing and stacking of the
+# per-layer tensors, the remat wrapper's layout copies, the step's bf16 cast
+# of the parameters, the rope tables, buffers the compiler allocates
+UNSCOPED_PLUMBING = {"squeeze", "dynamic_slice", "dynamic_update_slice", "remat2",
+                     "convert_element_type", "mul", "broadcast_in_dim", "closed_call"}
+
+
+@pytest.fixture(scope="module")
+def smoke_scope_map(smoke_step):
+    """Through the census, as a reader gets it: the executable is noted at
+    compile time, the text is parsed when someone asks."""
+    from veomni_tpu.observability.cost import CostCensus
+    from veomni_tpu.observability.metrics import MetricsRegistry
+
+    census = CostCensus(registry=MetricsRegistry())
+    census.note_executable("smoke_step", smoke_step)
+    return census.scope_map("smoke_step")
+
+
+def _device_instructions(text):
+    """(name, opcode) of the fusions, convolutions and custom calls of a
+    compiled text: what a trace's device events are made of."""
+    import re
+
+    return re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \S+(?: \S+)*? "
+                      r"(fusion|convolution|custom-call)\(", text, re.MULTILINE)
+
+
+def test_smoke_train_step_scope_map_covers_the_device_work(smoke_step, smoke_scope_map):
+    from benchmark import scopes as sc
+
+    instructions = _device_instructions(smoke_step.as_text())
+    assert len(instructions) > 200
+    with_name = [(n, smoke_scope_map[n]) for n, _ in instructions if n in smoke_scope_map]
+    loose = [(n, op) for n, op in with_name if sc.scope_of(op) is None]
+    assert {op.rsplit("/", 1)[-1] for _, op in loose} <= UNSCOPED_PLUMBING, loose
+    # by count, most of what has a name has a scope, and most has a name
+    # (what has none is the compiler's own: layout copies, ConcatBitcast)
+    assert len(loose) < 0.2 * len(with_name)
+    assert len(with_name) > 0.6 * len(instructions)
+
+
+@pytest.mark.parametrize("scope", ["embed", "attn.qkv", "attn.flash", "attn.out", "mlp",
+                                   "lm_head_loss", "grad_clip", "optimizer"])
+def test_smoke_train_step_has_every_dense_scope(smoke_scope_map, scope):
+    from benchmark import scopes as sc
+
+    assert any(sc.scope_of(op) == scope for op in smoke_scope_map.values())
+
+
+def test_smoke_train_step_phases_under_remat_nothing(smoke_step, smoke_scope_map):
+    """The recomputed forward is told from the first and from the backward
+    by its op_name, the kernels by their names."""
+    from benchmark import scopes as sc
+
+    classes = {n: sc.classify(n, smoke_scope_map)
+               for n, _ in _device_instructions(smoke_step.as_text())}
+    fwd = sorted(n for n in classes if n.startswith("flash_fwd."))
+    assert sorted(classes[n] for n in fwd) == [("attn.flash", "forward"),
+                                               ("attn.flash", "recompute")]
+    assert {classes[n] for n in classes if n.startswith("flash_bwd_")} == {
+        ("attn.flash", "backward")}
+    phases = {p for s, p in classes.values() if s in ("mlp", "attn.qkv")}
+    assert phases == {"forward", "recompute", "backward"}
+    assert {p for s, p in classes.values() if s in ("optimizer", "grad_clip")} == {"optimizer"}
 
 
 # --------------------------------------------------------------------------

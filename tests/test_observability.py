@@ -233,20 +233,28 @@ def test_goodput_fractions_sum_to_one(spans_on):
             with span("ckpt.save"):
                 time.sleep(0.01)
             time.sleep(0.005)
-        time.sleep(0.02)  # unattributed (the sync fetch / device wait)
+        with span("step.backpressure"):
+            time.sleep(0.015)  # the loop's wait for the oldest in-flight step
+        time.sleep(0.005)  # unattributed (the sync fetch)
         w = tracker.end_window()
     finally:
         spans_mod.get_registry = prev
     fracs = {k: v for k, v in w.items() if k.endswith("_frac")}
     assert set(fracs) == {"data_wait_frac", "host_frac", "dispatch_frac",
-                          "checkpoint_frac", "other_frac"}
+                          "checkpoint_frac", "device_wait_frac", "other_frac"}
     assert sum(fracs.values()) == pytest.approx(1.0, abs=1e-6)
     assert w["data_wait_frac"] > 0.15  # the dominant injected stall
     assert w["checkpoint_frac"] > 0.05
+    assert w["device_wait_frac"] > 0.08 > w["other_frac"]  # no longer hidden
     # ckpt time nested in the callback hook must not be double counted
     assert w["host_frac"] < w["checkpoint_frac"] + 0.15
+    # the published percentage is what it was before the wait had a span:
+    # everything but the three host-side stalls
     assert w["goodput_pct"] == pytest.approx(
-        100.0 * (w["dispatch_frac"] + w["other_frac"]), abs=1e-6)
+        100.0 * (1.0 - w["data_wait_frac"] - w["host_frac"] - w["checkpoint_frac"]),
+        abs=1e-6)
+    assert w["goodput_pct"] == pytest.approx(
+        100.0 * (w["dispatch_frac"] + w["device_wait_frac"] + w["other_frac"]), abs=1e-6)
     # next window starts clean
     w2 = tracker.end_window()
     assert w2["data_wait_frac"] == pytest.approx(0.0, abs=1e-3)
@@ -349,7 +357,7 @@ def test_metrics_endpoint_serves_trainer_and_serving_metrics(tmp_path):
     assert rows and rows[-1]["step"] == 4
     assert "loss" in rows[-1] and "goodput_pct" in rows[-1]
     frac_keys = ("data_wait_frac", "host_frac", "dispatch_frac",
-                 "checkpoint_frac", "other_frac")
+                 "checkpoint_frac", "device_wait_frac", "other_frac")
     assert sum(rows[-1][k] for k in frac_keys) == pytest.approx(1.0, abs=1e-3)
 
     # serving metrics land in the same registry
@@ -460,3 +468,197 @@ def test_profile_callback_exception_safe_and_env_overrides(tmp_path, monkeypatch
     state.global_step = 9
     cb.on_step_end(None, state)
     assert calls["stop"] == 1  # double-stop guard held everywhere
+
+
+# ------------------------------------------- device-side names, loop, set-up
+@pytest.fixture(scope="module")
+def toy_run(tmp_path_factory):
+    """A toy TextTrainer built and run for 8 steps with spans on (the
+    default): the span ring's events of that run, and what it published."""
+    from veomni_tpu.observability import get_cost_census
+    from veomni_tpu.parallel.parallel_state import destroy_parallel_state
+    from veomni_tpu.trainer import TextTrainer
+
+    from tests.test_e2e_training import _make_args, _write_dummy_data
+
+    tmp_path = tmp_path_factory.mktemp("toy_run")
+    was = spans_mod.spans_enabled()
+    disable_spans()
+    destroy_parallel_state()
+    _write_dummy_data(tmp_path / "data.jsonl")
+    args = _make_args(tmp_path, train_steps=8, log_steps=100)
+    mark = len(spans_mod.live_span_events())
+    trainer = TextTrainer(args)
+    enabled_by_the_trainer = spans_mod.spans_enabled()
+    built = [e[0] for e in spans_mod.live_span_events()[mark:]]
+    ctl = trainer.train()
+    trainer.checkpointer.close()
+    destroy_parallel_state()
+    events = spans_mod.live_span_events()[mark:]
+    doc = {"events": events, "built": built, "steps": ctl.global_step, "metrics": ctl.metrics,
+           "enabled_by_the_trainer": enabled_by_the_trainer,
+           "gauge": get_registry().get("setup.launch_to_trainer_s"),
+           "scope_map": get_cost_census().scope_map("train_step")}
+    if not was:
+        disable_spans()
+    return doc
+
+
+def test_trainer_enables_spans_when_it_is_built(toy_run):
+    assert toy_run["enabled_by_the_trainer"] is True
+    # set-up spans close before train() is ever called
+    assert toy_run["built"].count("setup.build") == 1
+    assert toy_run["built"].index("setup.data") < toy_run["built"].index("setup.build")
+
+
+@pytest.mark.parametrize("name,count", [
+    ("setup.build", 1), ("setup.state", 1), ("setup.train_begin", 1),
+    ("setup.data", 2),      # dataset and loader in __init__, the prefetcher in train()
+    ("jit.compile", 1),     # the train step's one program
+])
+def test_set_up_spans_of_a_toy_run(toy_run, name, count):
+    names = [e[0] for e in toy_run["events"]]
+    assert names.count(name) == count
+    by = {e[0]: e for e in toy_run["events"]}
+    if name in ("setup.state", "setup.data"):
+        # inside setup.build (the first setup.data; the second is train()'s)
+        first = next(e for e in toy_run["events"] if e[0] == name)
+        build = by["setup.build"]
+        assert build[1] <= first[1] and first[1] + first[2] <= build[1] + build[2]
+
+
+def test_one_backpressure_span_a_step(toy_run):
+    names = [e[0] for e in toy_run["events"]]
+    assert toy_run["steps"] == 8
+    assert names.count("step.backpressure") == 8 == names.count("step.dispatch")
+    # and it is the loop's: between a step's dispatch and its callbacks
+    order = [n for n in names if n in ("step.dispatch", "step.backpressure")]
+    assert order == ["step.dispatch", "step.backpressure"] * 8
+
+
+def test_live_span_events_stay_four_tuples(toy_run):
+    assert toy_run["events"]
+    for ev in toy_run["events"]:
+        name, t0_ns, dur_ns, tid = ev  # benchmark/jobs/train_packed.py unpacks four
+        assert isinstance(name, str) and isinstance(t0_ns, int) and dur_ns >= 0
+        assert isinstance(tid, int)
+
+
+def test_launch_gauge_is_process_age_at_the_trainers_build(toy_run):
+    from veomni_tpu.trainer.base import _seconds_since_process_start
+
+    assert toy_run["gauge"] is not None
+    assert 0 < toy_run["gauge"].value <= _seconds_since_process_start()
+
+
+def test_goodput_of_a_toy_run_has_its_device_wait(toy_run):
+    m = toy_run["metrics"]
+    fracs = [m[k] for k in ("data_wait_frac", "host_frac", "dispatch_frac", "checkpoint_frac",
+                            "device_wait_frac", "other_frac")]
+    assert sum(fracs) == pytest.approx(1.0, abs=1e-3)
+    assert m["goodput_pct"] == pytest.approx(
+        100.0 * (1.0 - m["data_wait_frac"] - m["host_frac"] - m["checkpoint_frac"]), abs=1e-3)
+
+
+def test_scope_map_of_the_toy_train_step(toy_run):
+    """The census hands out {instruction: op_name} after the trainer is
+    gone, and the op_names carry the taxonomy."""
+    from veomni_tpu.observability.scopes import TRAIN_SCOPES
+
+    scope_map = toy_run["scope_map"]
+    assert scope_map and all(isinstance(v, str) for v in scope_map.values())
+    joined = "\n".join(scope_map.values())
+    dense = [s for s in TRAIN_SCOPES if not s.startswith("moe.")]
+    assert [s for s in dense if s not in joined] == []
+    assert "rematted_computation" in joined  # the toy config recomputes too
+
+
+def test_scope_map_is_parsed_only_on_request(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from veomni_tpu.observability import cost
+
+    parses = []
+    real = cost.parse_scope_map
+    monkeypatch.setattr(cost, "parse_scope_map", lambda text: parses.append(1) or real(text))
+    census = cost.CostCensus(registry=MetricsRegistry())
+
+    def f(x):
+        with jax.named_scope("mlp"):
+            return jnp.tanh(x) @ x
+
+    step = cost.InstrumentedJit("unit_site", jax.jit(f), census=census)
+    step(jnp.ones((8, 8)))
+    step(jnp.ones((8, 8)))
+    assert parses == [] and census.scope_map("other_site") is None
+    first = census.scope_map("unit_site")
+    assert parses == [1] and any("mlp" in v for v in first.values())
+    assert census.scope_map("unit_site") is first and parses == [1]  # kept
+    step(jnp.ones((4, 4)))  # a newer program of the site: parsed anew, on request
+    assert parses == [1]
+    assert census.scope_map("unit_site") is not first and parses == [1, 1]
+    census.reset()
+    assert census.scope_map("unit_site") is None
+
+
+def test_parse_scope_map_reads_names_and_op_names():
+    from veomni_tpu.observability.cost import parse_scope_map
+
+    text = """
+HloModule jit_step
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %multiply.3 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/mlp/mul" source_file="a.py" source_line=3}
+}
+ENTRY %main {
+  %fusion.7 = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp())/checkpoint/rematted_computation/mlp/mul"}
+  %flash_fwd.15 = (bf16[4,16]{1,0}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/attn.flash/flash_fwd/pallas_call"}
+  copy.2 = f32[8]{0} copy(%fusion.7)
+  ROOT %tuple.1 = (f32[8]{0}) tuple(%copy.2)
+}
+"""
+    assert parse_scope_map(text) == {
+        "multiply.3": "jit(step)/mlp/mul",
+        "fusion.7": "jit(step)/transpose(jvp())/checkpoint/rematted_computation/mlp/mul",
+        "flash_fwd.15": "jit(step)/attn.flash/flash_fwd/pallas_call",
+    }
+
+
+def test_spans_off_makes_no_span_object_and_no_scope_map(spans_off, monkeypatch):
+    """train.observability_spans=false: every span() of the loop is the one
+    shared null object, and nobody parses a scope map."""
+    from veomni_tpu.observability import cost
+    from veomni_tpu.resilience.supervisor import SupervisorPolicy, TrainSupervisor
+
+    made = []
+    monkeypatch.setattr(spans_mod, "_Span", lambda name: made.append(name))
+    monkeypatch.setattr(cost, "parse_scope_map", lambda text: made.append("parse") or {})
+    for name in ("step.backpressure", "setup.build", "setup.state", "setup.data",
+                 "setup.train_begin", "jit.compile", "data.wait"):
+        assert span(name) is spans_mod._NULL
+    sup = TrainSupervisor(SupervisorPolicy(inflight_depth=1))
+    before = len(spans_mod.live_span_events())
+    for step in range(1, 4):
+        assert sup.observe(step, {"loss": np.float32(1.0), "step_ok": np.bool_(True)}) == "ok"
+    assert made == [] and len(spans_mod.live_span_events()) == before
+
+
+def test_scope_names_in_the_code_are_the_taxonomy():
+    """Every jax.named_scope literal and every pallas_call name in the
+    program is in observability/scopes.py, and every name there is used."""
+    from veomni_tpu.observability.scopes import KERNEL_NAMES, SCOPES
+
+    root = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "veomni_tpu")
+    scope_lits, kernel_lits = set(), set()
+    for dp, _, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                src = open(os.path.join(dp, f)).read()
+                scope_lits.update(re.findall(r'named_scope\(\s*"([^"]+)"', src))
+                scope_lits.update(x for pair in re.findall(
+                    r'named_scope\(\s*"([^"]+)" if \w+ else "([^"]+)"', src) for x in pair)
+                if "pallas_call(" in src:
+                    kernel_lits.update(re.findall(r'\bname="([a-z_]+)"', src))
+    assert scope_lits == set(SCOPES)
+    assert kernel_lits == set(KERNEL_NAMES)

@@ -380,12 +380,11 @@ class Checkpointer:
         if not self._is_committed(step):
             return
         try:
-            with span("ckpt.manifest"):
-                write_manifest(
-                    step_dir,
-                    topology=self._step_topology.pop(step, self._topology),
-                    digests=self.verify_mode != "off",
-                )
+            write_manifest(
+                step_dir,
+                topology=self._step_topology.pop(step, self._topology),
+                digests=self.verify_mode != "off",
+            )
             # drill point: a corrupt-mode fault spec here damages the
             # just-committed generation AFTER its digests were recorded —
             # exactly the storage-rot timeline the verify gate exists for.

@@ -672,6 +672,7 @@ def _select_token(logits, rng, temperature: float, top_k: int,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sampler")  # observability/scopes.py
 def sample_tokens(logits, keys, temperature, top_k, top_p):
     """Per-slot sampling for the serving engine: every parameter is a traced
     per-row array, so one compiled program honors any mix of per-request

@@ -335,22 +335,27 @@ def _moe_mlp(x, lp, cfg: TransformerConfig):
     x: [T, H]. (Reference eager MoE semantics per dialect.)"""
     t, h = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    topk_idx, topk_w, aux = route_tokens(x, lp, cfg)
-    if ROUTER_CAPTURE is not None:
-        ROUTER_CAPTURE.append(jax.lax.stop_gradient(topk_idx))
-    topk_w = topk_w.astype(x.dtype)
+    with jax.named_scope("moe.route"):
+        topk_idx, topk_w, aux = route_tokens(x, lp, cfg)
+        if ROUTER_CAPTURE is not None:
+            ROUTER_CAPTURE.append(jax.lax.stop_gradient(topk_idx))
+        topk_w = topk_w.astype(x.dtype)
 
-    flat_expert = topk_idx.reshape(-1)  # [T*K]
-    sort_idx = jnp.argsort(flat_expert)  # stable
-    token_idx = sort_idx // k
-    xs = x[token_idx]  # [T*K, H] sorted by expert
-    group_sizes = jnp.bincount(flat_expert, length=e)
-    out = experts_apply_sorted(xs, lp["experts"], group_sizes, flat_expert[sort_idx], cfg)
+    with jax.named_scope("moe.dispatch"):
+        flat_expert = topk_idx.reshape(-1)  # [T*K]
+        sort_idx = jnp.argsort(flat_expert)  # stable
+        token_idx = sort_idx // k
+        xs = x[token_idx]  # [T*K, H] sorted by expert
+        group_sizes = jnp.bincount(flat_expert, length=e)
+    with jax.named_scope("moe.experts"):
+        out = experts_apply_sorted(
+            xs, lp["experts"], group_sizes, flat_expert[sort_idx], cfg)
 
-    weight = topk_w.reshape(-1)[sort_idx][:, None]
-    combined = jnp.zeros((t, h), out.dtype).at[token_idx].add(out * weight)
-    if cfg.n_shared_experts or cfg.shared_expert_intermediate_size:
-        combined = combined + _shared_experts_out(x, lp, cfg)
+    with jax.named_scope("moe.combine"):
+        weight = topk_w.reshape(-1)[sort_idx][:, None]
+        combined = jnp.zeros((t, h), out.dtype).at[token_idx].add(out * weight)
+        if cfg.n_shared_experts or cfg.shared_expert_intermediate_size:
+            combined = combined + _shared_experts_out(x, lp, cfg)
     return combined, aux
 
 
@@ -373,41 +378,44 @@ def _norm(x, w, cfg: TransformerConfig):
 
 def _standard_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, window, sinks):
     b, s, _ = x.shape
-    q = jnp.dot(x, lp["q_proj"])
-    kk = jnp.dot(x, lp["k_proj"])
-    v = jnp.dot(x, lp["v_proj"])
-    if cfg.attention_bias:
-        q = q + lp["q_bias"]
-        kk = kk + lp["k_bias"]
-        v = v + lp["v_bias"]
-    q = q.reshape(b, s, cfg.num_attention_heads, cfg.head_dim)
-    kk = kk.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
-    v = v.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        q = _norm(q, lp["q_norm"], cfg)
-        kk = _norm(kk, lp["k_norm"], cfg)
-    rot_dim = cos.shape[-1]
-    if rot_dim < cfg.head_dim:
-        # partial rotary (glm4_moe): rope covers the leading dims only
-        q_rot, kk_rot = ops.apply_rotary(q[..., :rot_dim], kk[..., :rot_dim], cos, sin)
-        q = jnp.concatenate([q_rot, q[..., rot_dim:]], axis=-1)
-        kk = jnp.concatenate([kk_rot, kk[..., rot_dim:]], axis=-1)
-    else:
-        q, kk = ops.apply_rotary(q, kk, cos, sin)
+    with jax.named_scope("attn.qkv"):
+        q = jnp.dot(x, lp["q_proj"])
+        kk = jnp.dot(x, lp["k_proj"])
+        v = jnp.dot(x, lp["v_proj"])
+        if cfg.attention_bias:
+            q = q + lp["q_bias"]
+            kk = kk + lp["k_bias"]
+            v = v + lp["v_bias"]
+        q = q.reshape(b, s, cfg.num_attention_heads, cfg.head_dim)
+        kk = kk.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
+        v = v.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            q = _norm(q, lp["q_norm"], cfg)
+            kk = _norm(kk, lp["k_norm"], cfg)
+        rot_dim = cos.shape[-1]
+        if rot_dim < cfg.head_dim:
+            # partial rotary (glm4_moe): rope covers the leading dims only
+            q_rot, kk_rot = ops.apply_rotary(q[..., :rot_dim], kk[..., :rot_dim], cos, sin)
+            q = jnp.concatenate([q_rot, q[..., rot_dim:]], axis=-1)
+            kk = jnp.concatenate([kk_rot, kk[..., rot_dim:]], axis=-1)
+        else:
+            q, kk = ops.apply_rotary(q, kk, cos, sin)
     scale = (
         cfg.query_pre_attn_scalar ** -0.5 if cfg.query_pre_attn_scalar
         else cfg.head_dim ** -0.5
     )
-    attn = ops.attention(
-        q, kk, v, segment_ids=segment_ids, causal=True,
-        softmax_scale=scale, sliding_window=window, sinks=sinks,
-        # 0 = defer to registry/env; >=1 forces a path (see models/config.py)
-        ulysses_async_chunks=cfg.ulysses_async_chunks or None,
-    )
+    with jax.named_scope("attn.flash"):
+        attn = ops.attention(
+            q, kk, v, segment_ids=segment_ids, causal=True,
+            softmax_scale=scale, sliding_window=window, sinks=sinks,
+            # 0 = defer to registry/env; >=1 forces a path (see models/config.py)
+            ulysses_async_chunks=cfg.ulysses_async_chunks or None,
+        )
     attn = checkpoint_name(attn, "attn_ctx")
-    out = jnp.dot(attn.reshape(b, s, cfg.q_dim), lp["o_proj"])
-    if "o_bias" in lp:
-        out = out + lp["o_bias"]
+    with jax.named_scope("attn.out"):
+        out = jnp.dot(attn.reshape(b, s, cfg.q_dim), lp["o_proj"])
+        if "o_bias" in lp:
+            out = out + lp["o_bias"]
     return out
 
 
@@ -465,25 +473,27 @@ def _mla_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, window,
     nh = cfg.num_attention_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
 
-    if cfg.q_lora_rank:
-        q = jnp.dot(_norm(jnp.dot(x, lp["q_a_proj"]), lp["q_a_layernorm"], cfg), lp["q_b_proj"])
-    else:
-        q = jnp.dot(x, lp["q_proj"])
-    q = q.reshape(b, s, nh, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
+    with jax.named_scope("attn.qkv"):
+        if cfg.q_lora_rank:
+            q = jnp.dot(_norm(jnp.dot(x, lp["q_a_proj"]), lp["q_a_layernorm"], cfg),
+                        lp["q_b_proj"])
+        else:
+            q = jnp.dot(x, lp["q_proj"])
+        q = q.reshape(b, s, nh, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
 
-    kv_a = jnp.dot(x, lp["kv_a_proj_with_mqa"])  # [B,S, kvlr + dr]
-    c_kv, k_rope = kv_a[..., : cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
-    kv = jnp.dot(_norm(c_kv, lp["kv_a_layernorm"], cfg), lp["kv_b_proj"])
-    kv = kv.reshape(b, s, nh, dn + dv)
-    k_nope, v = kv[..., :dn], kv[..., dn:]
+        kv_a = jnp.dot(x, lp["kv_a_proj_with_mqa"])  # [B,S, kvlr + dr]
+        c_kv, k_rope = kv_a[..., : cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
+        kv = jnp.dot(_norm(c_kv, lp["kv_a_layernorm"], cfg), lp["kv_b_proj"])
+        kv = kv.reshape(b, s, nh, dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
 
-    q_rope, k_rope = ops.apply_rotary(
-        q_rope, k_rope.reshape(b, s, 1, dr), cos, sin,
-        interleaved=cfg.rope_interleave,
-    )
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, nh, dr))], axis=-1)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        q_rope, k_rope = ops.apply_rotary(
+            q_rope, k_rope.reshape(b, s, 1, dr), cos, sin,
+            interleaved=cfg.rope_interleave,
+        )
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, nh, dr))], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
     from veomni_tpu.ops.rotary import yarn_attention_factor
 
     scale = (dn + dr) ** -0.5 * yarn_attention_factor(cfg.rope_scaling, dr)
@@ -500,19 +510,22 @@ def _mla_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, window,
         # the boolean keep mask rides the mask_mod hook, so long sequences
         # take the blockwise online-softmax path instead of materializing
         # a dense [B,H,S,S] score tensor
-        attn = _attention_xla(
-            q, k, v, segment_ids=segment_ids, causal=True,
-            softmax_scale=scale, sliding_window=window,
-            mask_mod=lambda qi, ki: dsa_bias[:, qi, ki],
-        )
+        with jax.named_scope("attn.flash"):
+            attn = _attention_xla(
+                q, k, v, segment_ids=segment_ids, causal=True,
+                softmax_scale=scale, sliding_window=window,
+                mask_mod=lambda qi, ki: dsa_bias[:, qi, ki],
+            )
     else:
-        attn = ops.attention(
-            q, k, v, segment_ids=segment_ids, causal=True,
-            softmax_scale=scale, sliding_window=window,
-            ulysses_async_chunks=cfg.ulysses_async_chunks or None,
-        )
+        with jax.named_scope("attn.flash"):
+            attn = ops.attention(
+                q, k, v, segment_ids=segment_ids, causal=True,
+                softmax_scale=scale, sliding_window=window,
+                ulysses_async_chunks=cfg.ulysses_async_chunks or None,
+            )
     attn = checkpoint_name(attn, "attn_ctx")
-    return jnp.dot(attn.reshape(b, s, nh * dv), lp["o_proj"])
+    with jax.named_scope("attn.out"):
+        return jnp.dot(attn.reshape(b, s, nh * dv), lp["o_proj"])
 
 
 def _decoder_layer(
@@ -523,7 +536,8 @@ def _decoder_layer(
     is_moe = cfg.is_moe if is_moe_segment is None else is_moe_segment
     constrain = _activation_constraint()
     hidden = constrain(hidden)
-    x = _norm(hidden, lp["input_layernorm"], cfg)
+    with jax.named_scope("attn.qkv"):
+        x = _norm(hidden, lp["input_layernorm"], cfg)
     dsa_bias = None
     if cfg.use_dsa:
         # "shared" layers reuse the previous layer's top-k selection
@@ -545,16 +559,20 @@ def _decoder_layer(
         attn_out = _standard_attention(
             x, lp, cfg, cos, sin, segment_ids, window, lp.get("sinks")
         )
-    if cfg.sandwich_norms:
-        attn_out = _norm(attn_out, lp["post_attention_layernorm"], cfg)
-    hidden = hidden + attn_out
+    with jax.named_scope("attn.out"):
+        if cfg.sandwich_norms:
+            attn_out = _norm(attn_out, lp["post_attention_layernorm"], cfg)
+        hidden = hidden + attn_out
 
     hidden = constrain(hidden)
     pre_norm = (
         lp["pre_feedforward_layernorm"] if cfg.sandwich_norms
         else lp["post_attention_layernorm"]
     )
-    x = _norm(hidden, pre_norm, cfg)
+    # the feed-forward's own norm and residual add go with its first and
+    # last stage: "mlp" when dense, "moe.route" / "moe.combine" when sparse
+    with jax.named_scope("moe.route" if is_moe else "mlp"):
+        x = _norm(hidden, pre_norm, cfg)
     dropped = jnp.float32(0.0)
     if is_moe:
         from veomni_tpu.parallel.parallel_state import get_parallel_state_or_none
@@ -585,21 +603,24 @@ def _decoder_layer(
             # round down to the largest divisor of s so chunking engages
             # instead of silently no-op'ing on non-multiple lengths
             c = next((d for d in range(c, 1, -1) if s % d == 0), 0)
-        if c and 1 < c < s:
-            # ChunkMBS (reference chunk_mbs.py:145): bound the [B,S,inter]
-            # intermediate to [B,c,inter]; lax.map serializes the chunks and
-            # jax.checkpoint keeps the bwd recompute chunked too.
-            xs = jnp.moveaxis(x.reshape(b, s // c, c, h), 1, 0)
-            out = jax.lax.map(jax.checkpoint(dense_mlp), xs)
-            out = jnp.moveaxis(out, 0, 1).reshape(b, s, h)
-        else:
-            out = dense_mlp(x)
+        with jax.named_scope("mlp"):
+            if c and 1 < c < s:
+                # ChunkMBS (reference chunk_mbs.py:145): bound the [B,S,inter]
+                # intermediate to [B,c,inter]; lax.map serializes the chunks and
+                # jax.checkpoint keeps the bwd recompute chunked too.
+                xs = jnp.moveaxis(x.reshape(b, s // c, c, h), 1, 0)
+                out = jax.lax.map(jax.checkpoint(dense_mlp), xs)
+                out = jnp.moveaxis(out, 0, 1).reshape(b, s, h)
+            else:
+                out = dense_mlp(x)
         aux = jnp.float32(0.0)
-    if cfg.sandwich_norms:
-        out = _norm(out, lp["post_feedforward_layernorm"], cfg)
+    with jax.named_scope("moe.combine" if is_moe else "mlp"):
+        if cfg.sandwich_norms:
+            out = _norm(out, lp["post_feedforward_layernorm"], cfg)
+        hidden = constrain(hidden + out)
     if dsa_prev is not None:  # carry mode (configs with "shared" layers)
-        return constrain(hidden + out), (aux, dropped), dsa_bias
-    return constrain(hidden + out), (aux, dropped)
+        return hidden, (aux, dropped), dsa_bias
+    return hidden, (aux, dropped)
 
 
 def forward_hidden(
@@ -626,9 +647,10 @@ def forward_hidden(
     if inputs_embeds is not None:
         hidden = inputs_embeds.astype(cfg.dtype)
     else:
-        hidden = compute["embed_tokens"][input_ids]
-        if cfg.embed_scale:
-            hidden = hidden * jnp.asarray(cfg.embed_scale, cfg.dtype)
+        with jax.named_scope("embed"):
+            hidden = compute["embed_tokens"][input_ids]
+            if cfg.embed_scale:
+                hidden = hidden * jnp.asarray(cfg.embed_scale, cfg.dtype)
 
     rope_dim = (
         cfg.qk_rope_head_dim if cfg.use_mla
@@ -739,7 +761,8 @@ def forward_hidden(
             if g < K_inject:
                 hidden = hidden + post_layer_residuals[g].astype(hidden.dtype)
             start += n
-    hidden = _norm(hidden, compute["norm"], cfg)
+    with jax.named_scope("lm_head_loss"):
+        hidden = _norm(hidden, compute["norm"], cfg)
     # mean dropped-assignment fraction over the MoE layers (diagnostic)
     n_moe = (L - k_dense) if cfg.is_moe else 0
     return hidden, auxes_total, drops_total / max(n_moe, 1)
@@ -786,11 +809,12 @@ def head_loss(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """lm-head + CE in token-sum space, shared by text/VLM/omni loss fns."""
     b, s, h = hidden.shape
-    kernel = lm_head_kernel(params, cfg).astype(cfg.dtype)
-    loss_sum, ntokens = ops.fused_linear_cross_entropy(
-        hidden.reshape(b * s, h), kernel, labels.reshape(b * s),
-        logit_softcap=cfg.final_logit_softcap or None,
-    )
+    with jax.named_scope("lm_head_loss"):
+        kernel = lm_head_kernel(params, cfg).astype(cfg.dtype)
+        loss_sum, ntokens = ops.fused_linear_cross_entropy(
+            hidden.reshape(b * s, h), kernel, labels.reshape(b * s),
+            logit_softcap=cfg.final_logit_softcap or None,
+        )
     metrics = {"loss_sum": loss_sum, "ntokens": ntokens, "moe_aux_loss": moe_aux}
     if moe_dropped is not None:
         metrics["moe_dropped_frac"] = moe_dropped

@@ -34,6 +34,11 @@ full profiler:
                  records XLA ``cost_analysis``/``memory_analysis`` + compile
                  wall-time per bucket, feeding continuous per-window MFU /
                  bandwidth-utilization gauges and ``/debug/cost``.
+* ``scopes``   — the names the device's work carries out of the program:
+                 Pallas kernel names and the ``jax.named_scope`` taxonomy of
+                 the train and engine steps; ``CostCensus.scope_map(site)``
+                 hands out ``{instruction: op_name}`` to join a profiler
+                 trace's device events to them.
 * ``devmem``   — live HBM accounting: ``jax.live_arrays()`` buffer census,
                  high-watermark tracking with a CPU fallback, KV-pool
                  capacity stats, and the OOM post-mortem payload

@@ -25,10 +25,7 @@ from veomni_tpu.observability.goodput import (
     update_memory_gauges,
 )
 from veomni_tpu.observability.metrics import get_registry
-from veomni_tpu.observability.spans import (
-    dump_chrome_trace,
-    enable_spans,
-)
+from veomni_tpu.observability.spans import dump_chrome_trace
 from veomni_tpu.trainer.callbacks import Callback
 from veomni_tpu.utils.helper import host_floats
 from veomni_tpu.utils.logging import get_logger
@@ -57,8 +54,8 @@ class ObservabilityCallback(Callback):
     def on_train_begin(self, trainer, state):
         t = trainer.args.train
         self.registry = get_registry()
-        if t.observability_spans:
-            enable_spans()
+        # (spans were switched on when the trainer was built, so that its
+        # set-up is inside them: trainer/base.py::BaseTrainer.__init__)
         # (the flight recorder's ring size + dump dir are wired in train()'s
         # prologue, BEFORE any callback can raise — not here)
         if t.observability_jsonl:

@@ -46,12 +46,14 @@ census plus a scrape-to-scrape live MFU window.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from veomni_tpu.observability.metrics import MetricsRegistry, get_registry
+from veomni_tpu.observability.spans import span
 from veomni_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -272,6 +274,23 @@ class ProgramCost:
         }
 
 
+# one instruction of ``Compiled.as_text()``: its name (with or without the
+# ``%`` sigil, ``ROOT`` or not) and the ``op_name`` of its metadata, which
+# carries the ``jax.named_scope`` path (observability/scopes.py)
+_INSTRUCTION_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bmetadata=\{[^}]*\bop_name="([^"]*)"',
+    re.MULTILINE,
+)
+
+
+def parse_scope_map(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` of an optimized HLO module's text.
+    Instruction names are unique within a module, fused computations'
+    included, so one flat dict serves; an instruction without metadata
+    (most copies, tuples, loop plumbing) is simply absent."""
+    return dict(_INSTRUCTION_OP_NAME.findall(hlo_text))
+
+
 def _first_dict(analysis) -> Dict[str, float]:
     """``Compiled.cost_analysis()`` returns a dict on some jax versions and
     a one-element list of dicts on others; normalize."""
@@ -329,6 +348,10 @@ class CostCensus:
         self._programs: Dict[Tuple[str, str], ProgramCost] = {}
         self._registry = registry
         self._stamp = 0  # bumped per record(); recency for latest()
+        # site -> [newest compiled executable, its parsed scope map or None].
+        # Held HERE, not by the InstrumentedJit that dies with its trainer or
+        # engine: a reader (the benchmark's reducers) asks after they are gone
+        self._executables: Dict[str, list] = {}
 
     def _reg(self) -> MetricsRegistry:
         return self._registry or get_registry()
@@ -390,6 +413,34 @@ class CostCensus:
         if counter is not None:
             counter.inc()
 
+    def note_executable(self, site: str, compiled) -> None:
+        """Keep the site's newest compiled executable for :meth:`scope_map`
+        (a reference: nothing is read from it until someone asks)."""
+        with self._lock:
+            self._executables[site] = [compiled, None]
+
+    def scope_map(self, site: str) -> Optional[Dict[str, str]]:
+        """``{instruction name: op_name}`` of the site's newest compiled
+        program: what joins a profiler trace's device events, which carry
+        the instruction's name, to the ``jax.named_scope`` taxonomy of
+        ``observability/scopes.py``. Parsed on the first request and kept; a
+        run that never asks pays neither the ``as_text()`` nor the parse.
+        None for a site that has compiled nothing (or whose text cannot be
+        had)."""
+        with self._lock:
+            held = self._executables.get(site)
+        if held is None:
+            return None
+        if held[1] is None:
+            try:
+                from veomni_tpu.observability.comm import _compiled_text
+
+                held[1] = parse_scope_map(_compiled_text(held[0]))
+            except Exception as e:
+                logger.debug("scope map unavailable for %s: %s", site, e)
+                return None
+        return held[1]
+
     # ---------------------------------------------------------------- queries
     def get(self, site: str, bucket: str) -> Optional[ProgramCost]:
         with self._lock:
@@ -434,6 +485,7 @@ class CostCensus:
     def reset(self) -> None:
         with self._lock:
             self._programs.clear()
+            self._executables.clear()
 
 
 _GLOBAL: Optional[CostCensus] = None
@@ -668,17 +720,21 @@ class InstrumentedJit:
                     import jax
 
                     try:
-                        t0 = time.perf_counter()
-                        traced = None
-                        try:
-                            # trace -> lower -> compile keeps the jaxpr in
-                            # hand for the scan-trip-count correction
-                            traced = self._fn.trace(*args)
-                            lowered = traced.lower()
-                        except AttributeError:  # older jax: no .trace
-                            lowered = self._fn.lower(*args)
-                        compiled = lowered.compile()
-                        dt = time.perf_counter() - t0
+                        # the span covers what compile_time_s times: a
+                        # compile (or a cache load) shows on the host
+                        # timeline that names the device's idle gaps
+                        with span("jit.compile"):
+                            t0 = time.perf_counter()
+                            traced = None
+                            try:
+                                # trace -> lower -> compile keeps the jaxpr
+                                # in hand for the scan-trip-count correction
+                                traced = self._fn.trace(*args)
+                                lowered = traced.lower()
+                            except AttributeError:  # older jax: no .trace
+                                lowered = self._fn.lower(*args)
+                            compiled = lowered.compile()
+                            dt = time.perf_counter() - t0
                     except Exception as e:
                         self._disable("lower/compile", e)
                         return self._fn(*args)
@@ -712,6 +768,7 @@ class InstrumentedJit:
                         num_devices=ndev,
                         **fields,
                     )
+                    self._census.note_executable(self._site, compiled)
                     entry = (compiled, (self._site, bucket))
                     self._compiled[key] = entry
         compiled, site_bucket = entry

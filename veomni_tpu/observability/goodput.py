@@ -11,13 +11,17 @@ by delta-ing the cumulative sums of the span histograms the trainer feeds
                    batch shipping (``step.dispatch``, ``data.ship``)
 * ``checkpoint`` — save/restore/wait (``ckpt.save``, ``ckpt.wait``,
                    ``ckpt.restore``)
-* ``other``      — the residual; on the async loop this is dominated by the
-                   sync-step device fetch, i.e. time the device was the
-                   bottleneck — which is exactly where a training run
-                   *wants* to spend its time.
+* ``device_wait`` — the loop blocked on the oldest in-flight step, the
+                   dispatch-depth bound (``step.backpressure``): time the
+                   device was the bottleneck — which is exactly where a
+                   training run *wants* to spend its time.
+* ``other``      — the residual: the sync-step device fetch and whatever no
+                   span covers.
 
-``goodput_pct`` is therefore ``100 * (dispatch + other)`` fractions: the
-share of wall time not attributable to a known host-side stall. The TPUv4
+``goodput_pct`` is therefore ``100 * (dispatch + device_wait + other)``
+fractions: the share of wall time not attributable to a known host-side
+stall (``device_wait`` was inside ``other`` before it had a span of its
+own, so the percentage reads as it always did). The TPUv4
 pjit paper's goodput accounting and T3's step-time tracking (PAPERS.md)
 motivate making this a first-class per-window metric rather than a
 profiler-session artifact.
@@ -42,7 +46,10 @@ CATEGORY_SPANS: Dict[str, Tuple[str, ...]] = {
     "host": ("host.callbacks",),
     "dispatch": ("step.dispatch", "data.ship"),
     "checkpoint": ("ckpt.save", "ckpt.wait", "ckpt.restore"),
+    "device_wait": ("step.backpressure",),
 }
+# what goodput_pct counts besides the residual ``other``
+_GOODPUT_CATEGORIES = ("dispatch", "device_wait")
 # checkpoint saves run inside the on_step_end callback hook, so their time
 # is nested inside the host category's span and must be subtracted once
 _NESTED_IN_HOST = "checkpoint"
@@ -92,7 +99,8 @@ class GoodputTracker:
             known = 1.0
         fracs["other"] = 1.0 - known
         out = {f"{c}_frac": f for c, f in fracs.items()}
-        out["goodput_pct"] = 100.0 * (fracs["dispatch"] + fracs["other"])
+        out["goodput_pct"] = 100.0 * (
+            fracs["other"] + sum(fracs.get(c, 0.0) for c in _GOODPUT_CATEGORIES))
         out["window_wall_s"] = wall
         self._t0, self._base = now, cur
         return out

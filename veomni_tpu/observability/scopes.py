@@ -1,0 +1,44 @@
+"""Names the device's work carries out of the program: an interface.
+
+Two kinds, both metadata only (neither changes a compiled instruction):
+
+* **Kernel names**: the ``name=`` of every ``pl.pallas_call``. The optimized
+  HLO names the custom call after it (``%flash_fwd.13 = ... custom-call``),
+  and the profiler names a device event by that instruction's text, so a
+  trace tells the kernels apart without counting wrappers.
+* **Scopes**: ``jax.named_scope`` around the stages of the train step and of
+  the engine's step. Each compiled instruction's ``metadata={op_name=...}``
+  carries the scope path; ``observability/cost.py::CostCensus.scope_map``
+  hands out ``{instruction name: op_name}`` per jit site, which is what joins
+  a profiler trace's device events to these names (``benchmark/scopes.py``).
+
+Call sites write the names as literals; ``tests/test_observability.py``
+holds every literal in the code to these tuples and every name here to a call
+site. ``docs/observability.md`` lists what each covers.
+"""
+
+TRAIN_SCOPES = (
+    "embed",         # token embedding lookup (+ embed scale)
+    "attn.qkv",      # input norm, q/k/v projections, qk-norm, rope
+    "attn.flash",    # the attention op, whichever impl the registry resolves
+    "attn.out",      # output projection and the residual add
+    "mlp",           # post-attention norm, dense gated MLP, residual add
+    "moe.route",     # router logits, top-k, balancing loss
+    "moe.dispatch",  # sort / bucket tokens by expert, EP all-to-all out
+    "moe.experts",   # the grouped-GEMM expert MLP
+    "moe.combine",   # EP all-to-all back, weighted scatter-add, shared experts
+    "lm_head_loss",  # final norm, lm head and cross entropy
+    "grad_clip",     # token normalisation, global norm, clip scale
+    "optimizer",     # optimizer update and apply
+)
+SERVE_SCOPES = (
+    "paged.gather",  # per-slot KV context gathered through the block table
+    "paged.attend",  # masked dense softmax over the gathered context
+    "sampler",       # temperature / top-k / top-p / greedy token choice
+)
+SCOPES = TRAIN_SCOPES + SERVE_SCOPES
+
+KERNEL_NAMES = (
+    "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",   # ops/pallas/flash_attention.py
+    "gmm_fwd", "gmm_dlhs", "gmm_drhs",              # ops/pallas/grouped_gemm.py
+)

@@ -20,6 +20,7 @@ the serving engine.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -101,6 +102,15 @@ def gather_block_kv_q8(k_pool, v_pool, block_tables, dtype):
     return one(k_pool), one(v_pool)
 
 
+def _gather_then_attend(gather, q, k_pool, v_pool, block_tables, valid_mask, **attend):
+    """The two stages every ``xla_gather*`` impl has, under the scope names
+    a trace tells them apart by (observability/scopes.py)."""
+    with jax.named_scope("paged.gather"):
+        k_ctx, v_ctx = gather(k_pool, v_pool, block_tables)
+    with jax.named_scope("paged.attend"):
+        return cache_attend(q, k_ctx, v_ctx, valid_mask, **attend)
+
+
 @KERNEL_REGISTRY.register("paged_attention", "xla_gather")
 def _paged_attend_xla(
     q,
@@ -113,9 +123,9 @@ def _paged_attend_xla(
     scale: float,
     sinks: Optional[jax.Array] = None,
 ):
-    k_ctx, v_ctx = gather_block_kv(k_pool, v_pool, block_tables)
-    return cache_attend(
-        q, k_ctx, v_ctx, valid_mask, num_rep=num_rep, scale=scale, sinks=sinks
+    return _gather_then_attend(
+        gather_block_kv, q, k_pool, v_pool, block_tables, valid_mask,
+        num_rep=num_rep, scale=scale, sinks=sinks,
     )
 
 
@@ -134,9 +144,10 @@ def _paged_attend_xla_q8(
     """int8-KV decode/verify attention: gathered-dequantize, then the SAME
     ``cache_attend`` softmax as ``xla_gather`` — the only non-bit-exactness
     is the int8 rounding on the cache rows themselves."""
-    k_ctx, v_ctx = gather_block_kv_q8(k_pool, v_pool, block_tables, q.dtype)
-    return cache_attend(
-        q, k_ctx, v_ctx, valid_mask, num_rep=num_rep, scale=scale, sinks=sinks
+    return _gather_then_attend(
+        functools.partial(gather_block_kv_q8, dtype=q.dtype),
+        q, k_pool, v_pool, block_tables, valid_mask,
+        num_rep=num_rep, scale=scale, sinks=sinks,
     )
 
 
@@ -179,9 +190,9 @@ def _paged_prefill_attend_xla(
     scale: float,
     sinks: Optional[jax.Array] = None,
 ):
-    k_ctx, v_ctx = gather_block_kv(k_pool, v_pool, block_tables)
-    return cache_attend(
-        q, k_ctx, v_ctx, valid_mask, num_rep=num_rep, scale=scale, sinks=sinks
+    return _gather_then_attend(
+        gather_block_kv, q, k_pool, v_pool, block_tables, valid_mask,
+        num_rep=num_rep, scale=scale, sinks=sinks,
     )
 
 
@@ -201,9 +212,10 @@ def _paged_prefill_attend_xla_q8(
     dequantized gathered context — including the chunk's OWN rows, which
     were quantized on the scatter that preceded this attend, so chunked and
     monolithic prefill see the identical (rounded) cache."""
-    k_ctx, v_ctx = gather_block_kv_q8(k_pool, v_pool, block_tables, q.dtype)
-    return cache_attend(
-        q, k_ctx, v_ctx, valid_mask, num_rep=num_rep, scale=scale, sinks=sinks
+    return _gather_then_attend(
+        functools.partial(gather_block_kv_q8, dtype=q.dtype),
+        q, k_pool, v_pool, block_tables, valid_mask,
+        num_rep=num_rep, scale=scale, sinks=sinks,
     )
 
 

@@ -148,6 +148,7 @@ def _fwd(q, k, v, segment_ids, scale, causal, bq, bk):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="flash_fwd",  # observability/scopes.py::KERNEL_NAMES
     )(segment_ids[:, None, :], segment_ids[:, None, :], q, k, v)
     return out, lse
 
@@ -295,6 +296,7 @@ def _bwd(scale, causal, bq, bk, residuals, g):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(seg3, seg3, q, k, v, do, lse, delta)
 
     # GQA: fold the q-head group into the kv head grad
@@ -319,6 +321,7 @@ def _bwd(scale, causal, bq, bk, residuals, g):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(seg3, seg3, q, k, v, do, lse, delta)
 
     return dq, dk, dv, None

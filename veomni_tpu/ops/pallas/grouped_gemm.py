@@ -110,6 +110,7 @@ def _gmm_raw(lhs, rhs, group_starts, bm: int, bn: int):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="gmm_fwd",  # observability/scopes.py::KERNEL_NAMES
     )(group_starts, _tile_expert_range(group_starts, m, bm), lhs, rhs)
 
 
@@ -167,6 +168,7 @@ def _gmm_dlhs(g, rhs, group_starts, bm: int, bk: int):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="gmm_dlhs",
     )(group_starts, _tile_expert_range(group_starts, m, bm), g, rhs)
 
 
@@ -220,6 +222,7 @@ def _gmm_transpose(lhs, g, group_starts, e: int, bm: int, bk: int, bn: int):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name="gmm_drhs",
     )(group_starts, lhs, g)
 
 

@@ -43,61 +43,64 @@ def _dispatch_combine(x2d, topk_idx, topk_probs, experts_local, *, cfg,
     k = topk_idx.shape[-1]
     n_assign = t * k
 
-    flat_e = topk_idx.reshape(-1)                       # [T*K] global expert id
-    flat_w = topk_probs.reshape(-1).astype(dtype)
-    dest = flat_e // e_loc                              # destination ep rank
-    order = jnp.argsort(dest, stable=True)              # assignments grouped by dest
-    dest_s = dest[order]
-    tok_s = order // k                                  # source token per assignment
-    le_s = (flat_e % e_loc)[order]                      # local expert id at dest
-    w_s = flat_w[order]
+    with jax.named_scope("moe.dispatch"):
+        flat_e = topk_idx.reshape(-1)                       # [T*K] global expert id
+        flat_w = topk_probs.reshape(-1).astype(dtype)
+        dest = flat_e // e_loc                              # destination ep rank
+        order = jnp.argsort(dest, stable=True)              # assignments grouped by dest
+        dest_s = dest[order]
+        tok_s = order // k                                  # source token per assignment
+        le_s = (flat_e % e_loc)[order]                      # local expert id at dest
+        w_s = flat_w[order]
 
-    # slot within destination bucket (rank among same-dest assignments)
-    onehot = jax.nn.one_hot(dest_s, ep, dtype=jnp.int32)         # [T*K, ep]
-    slot = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1     # [T*K]
-    keep = slot < capacity
+        # slot within destination bucket (rank among same-dest assignments)
+        onehot = jax.nn.one_hot(dest_s, ep, dtype=jnp.int32)         # [T*K, ep]
+        slot = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1     # [T*K]
+        keep = slot < capacity
 
-    dropped_frac = 1.0 - keep.astype(jnp.float32).mean()
+        dropped_frac = 1.0 - keep.astype(jnp.float32).mean()
 
-    send_x = jnp.zeros((ep, capacity, h), dtype)
-    send_le = jnp.full((ep, capacity), -1, jnp.int32)
-    # dropped assignments get an out-of-bounds destination -> mode="drop"
-    # discards them without clobbering live slots
-    d_idx = jnp.where(keep, dest_s, ep)
-    s_idx = jnp.where(keep, slot, 0)
-    send_x = send_x.at[d_idx, s_idx].set(x2d[tok_s], mode="drop")
-    send_le = send_le.at[d_idx, s_idx].set(le_s, mode="drop")
+        send_x = jnp.zeros((ep, capacity, h), dtype)
+        send_le = jnp.full((ep, capacity), -1, jnp.int32)
+        # dropped assignments get an out-of-bounds destination -> mode="drop"
+        # discards them without clobbering live slots
+        d_idx = jnp.where(keep, dest_s, ep)
+        s_idx = jnp.where(keep, slot, 0)
+        send_x = send_x.at[d_idx, s_idx].set(x2d[tok_s], mode="drop")
+        send_le = send_le.at[d_idx, s_idx].set(le_s, mode="drop")
 
-    a2a = partial(jax.lax.all_to_all, axis_name=AXIS_EP,
-                  split_axis=0, concat_axis=0, tiled=True)
-    recv_x = a2a(send_x)                                # [ep*C? -> [ep, C, H]]
-    recv_le = a2a(send_le[..., None])[..., 0]
+        a2a = partial(jax.lax.all_to_all, axis_name=AXIS_EP,
+                      split_axis=0, concat_axis=0, tiled=True)
+        recv_x = a2a(send_x)                                # [ep*C? -> [ep, C, H]]
+        recv_le = a2a(send_le[..., None])[..., 0]
 
-    # local expert compute over [ep*C] slots
-    rx = recv_x.reshape(ep * capacity, h)
-    rle = recv_le.reshape(ep * capacity)
-    valid = rle >= 0
-    rle_safe = jnp.where(valid, rle, e_loc - 1)
-    rx = jnp.where(valid[:, None], rx, 0.0)
-    sort_idx = jnp.argsort(rle_safe, stable=True)
-    xs = rx[sort_idx]
-    group_sizes = jnp.bincount(rle_safe, length=e_loc)
+        # local expert compute over [ep*C] slots
+        rx = recv_x.reshape(ep * capacity, h)
+        rle = recv_le.reshape(ep * capacity)
+        valid = rle >= 0
+        rle_safe = jnp.where(valid, rle, e_loc - 1)
+        rx = jnp.where(valid[:, None], rx, 0.0)
+        sort_idx = jnp.argsort(rle_safe, stable=True)
+        xs = rx[sort_idx]
+        group_sizes = jnp.bincount(rle_safe, length=e_loc)
 
     from veomni_tpu.models.transformer import experts_apply_sorted
 
-    out_s = experts_apply_sorted(
-        xs, experts_local, group_sizes, rle_safe[sort_idx], cfg
-    )
+    with jax.named_scope("moe.experts"):
+        out_s = experts_apply_sorted(
+            xs, experts_local, group_sizes, rle_safe[sort_idx], cfg
+        )
 
-    out = jnp.zeros_like(rx).at[sort_idx].set(out_s)
-    out = out.reshape(ep, capacity, h)
-    back = a2a(out)                                     # [ep, C, H] on src side
+    with jax.named_scope("moe.combine"):
+        out = jnp.zeros_like(rx).at[sort_idx].set(out_s)
+        out = out.reshape(ep, capacity, h)
+        back = a2a(out)                                     # [ep, C, H] on src side
 
-    # combine: weighted scatter-add into source tokens (OOB gather yields
-    # clamped values but `keep` zeroes those lanes)
-    flat_back = back[jnp.where(keep, dest_s, 0), jnp.where(keep, slot, 0)]
-    contrib = jnp.where(keep[:, None], flat_back * w_s[:, None], 0.0)
-    combined = jnp.zeros((t, h), dtype).at[tok_s].add(contrib)
+        # combine: weighted scatter-add into source tokens (OOB gather yields
+        # clamped values but `keep` zeroes those lanes)
+        flat_back = back[jnp.where(keep, dest_s, 0), jnp.where(keep, slot, 0)]
+        contrib = jnp.where(keep[:, None], flat_back * w_s[:, None], 0.0)
+        combined = jnp.zeros((t, h), dtype).at[tok_s].add(contrib)
     return combined, dropped_frac
 
 
@@ -116,9 +119,10 @@ def ep_moe_mlp(x, lp, cfg, pstate: ParallelState):
     # shared with the single-device path so every dialect matches
     from veomni_tpu.models.transformer import route_tokens
 
-    topk_idx, topk_probs, aux = route_tokens(x.reshape(b * s, h), lp, cfg)
-    topk_idx = topk_idx.reshape(b, s, k)
-    topk_probs = topk_probs.reshape(b, s, k)
+    with jax.named_scope("moe.route"):
+        topk_idx, topk_probs, aux = route_tokens(x.reshape(b * s, h), lp, cfg)
+        topk_idx = topk_idx.reshape(b, s, k)
+        topk_probs = topk_probs.reshape(b, s, k)
 
     # ---- dispatch/compute/combine inside shard_map
     dp, spx = pstate.dp_axes, pstate.sp_axes
@@ -160,5 +164,6 @@ def ep_moe_mlp(x, lp, cfg, pstate: ParallelState):
     if cfg.n_shared_experts or cfg.shared_expert_intermediate_size:
         from veomni_tpu.models.transformer import _shared_experts_out
 
-        out = out + _shared_experts_out(x, lp, cfg)
+        with jax.named_scope("moe.combine"):
+            out = out + _shared_experts_out(x, lp, cfg)
     return out, aux, dropped

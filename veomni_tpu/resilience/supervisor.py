@@ -39,6 +39,7 @@ import numpy as np
 
 from veomni_tpu.observability.flight_recorder import record as flight_record
 from veomni_tpu.observability.metrics import get_registry
+from veomni_tpu.observability.spans import span
 from veomni_tpu.resilience.faults import fault_point
 from veomni_tpu.utils.logging import get_logger
 
@@ -131,10 +132,15 @@ class TrainSupervisor:
             (step, metrics.get("loss"), metrics.get("step_ok"), injected)
         )
         verdict = "ok"
-        while len(self._inflight) > self.policy.inflight_depth:
-            verdict = worse_verdict(verdict, self._check(self._inflight.popleft()))
-            if _SEVERITY[verdict] >= _SEVERITY["rollback"]:
-                break  # the rest of the queue belongs to a doomed trajectory
+        # the loop's wait for the device: checking the oldest in-flight step
+        # blocks until the device has produced its loss. One span a step
+        # (empty while the queue fills), GoodputTracker's ``device_wait``
+        with span("step.backpressure"):
+            while len(self._inflight) > self.policy.inflight_depth:
+                verdict = worse_verdict(
+                    verdict, self._check(self._inflight.popleft()))
+                if _SEVERITY[verdict] >= _SEVERITY["rollback"]:
+                    break  # the rest of the queue belongs to a doomed trajectory
         return verdict
 
     def drain(self) -> str:

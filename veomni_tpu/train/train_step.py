@@ -173,19 +173,22 @@ def build_train_step(
             for k, x in extras_stacked.items()
         }
         denom = jnp.maximum(ntokens, 1).astype(jnp.float32)
-        grads = jax.tree.map(lambda g: g / denom, grads)
-        if grad_mask is not None:
-            grads = jax.tree.map(lambda g, m: g * m, grads, grad_mask)
-        grad_norm = optax.global_norm(grads)
-        # numerics observatory reads the token-normalized, mask-applied,
-        # PRE-clip gradients: the clip would hide exactly the blow-up
-        # magnitude the health summary exists to see
-        health_grads = grads
-        if max_grad_norm:
-            scale = jnp.minimum(1.0, max_grad_norm / (grad_norm + 1e-6))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-        updates, new_opt = optimizer.update(grads, state.opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        # scope names: observability/scopes.py (metadata only)
+        with jax.named_scope("grad_clip"):
+            grads = jax.tree.map(lambda g: g / denom, grads)
+            if grad_mask is not None:
+                grads = jax.tree.map(lambda g, m: g * m, grads, grad_mask)
+            grad_norm = optax.global_norm(grads)
+            # numerics observatory reads the token-normalized, mask-applied,
+            # PRE-clip gradients: the clip would hide exactly the blow-up
+            # magnitude the health summary exists to see
+            health_grads = grads
+            if max_grad_norm:
+                scale = jnp.minimum(1.0, max_grad_norm / (grad_norm + 1e-6))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         health = None
         if numerics_spec is not None:
             health = tree_health(
@@ -196,12 +199,15 @@ def build_train_step(
         # squares propagates), so loss+grad_norm finiteness covers the tree
         step_ok = jnp.isfinite(loss_sum) & jnp.isfinite(grad_norm)
         if skip_nonfinite:
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(step_ok, n, o), new_params, params
-            )
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(step_ok, n, o), new_opt, state.opt_state
-            )
+            # the gate fuses with the update it guards: same scope, or the
+            # fused AdamW would carry the select's name and no scope at all
+            with jax.named_scope("optimizer"):
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(step_ok, n, o), new_params, params
+                )
+                new_opt = jax.tree.map(
+                    lambda n, o: jnp.where(step_ok, n, o), new_opt, state.opt_state
+                )
         new_state = TrainState(params=new_params, opt_state=new_opt, step=state.step + 1)
         metrics = {
             "loss": loss_sum / denom,

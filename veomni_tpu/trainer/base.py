@@ -30,7 +30,8 @@ from veomni_tpu.observability.flight_recorder import (
     dump_postmortem,
     record as flight_record,
 )
-from veomni_tpu.observability.spans import span
+from veomni_tpu.observability.metrics import get_registry
+from veomni_tpu.observability.spans import enable_spans, span
 from veomni_tpu.optim import build_lr_scheduler, build_optimizer
 from veomni_tpu.parallel import init_parallel_state, use_parallel_state
 from veomni_tpu.train import build_train_state, build_train_step
@@ -88,19 +89,46 @@ def maybe_initialize_distributed() -> None:
         )
 
 
+def _seconds_since_process_start() -> Optional[float]:
+    """Wall seconds this process has lived, from one read of its
+    ``/proc/self/stat`` start tick against ``/proc/uptime``; None where
+    there is no such file."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command name (field 2) may hold spaces: count from its ")"
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
 class BaseTrainer:
     def __init__(self, args: VeOmniArguments):
         self.args = args
         self.current_batch: Optional[Dict[str, np.ndarray]] = None
         self.meter: Optional[EnvironMeter] = None
-        self._setup()
-        with use_parallel_state(self.parallel_state):
-            self._build_model()
-            self._build_data_transform()
-            self._build_dataset()
-            self._build_dataloader()
-            self._build_parallelized_state()
-            self._init_callbacks()
+        # spans from the first line on, so the program's own set-up is
+        # inside its own tracing (setup.build > setup.data, setup.state)
+        if args.train.observability_spans:
+            enable_spans()
+        # what the process spent before any trainer existed: the
+        # interpreter, every import, whatever the entry point did first
+        launched = _seconds_since_process_start()
+        if launched is not None:
+            get_registry().gauge("setup.launch_to_trainer_s").set(launched)
+        with span("setup.build"):
+            self._setup()
+            with use_parallel_state(self.parallel_state):
+                self._build_model()
+                with span("setup.data"):
+                    self._build_data_transform()
+                    self._build_dataset()
+                    self._build_dataloader()
+                with span("setup.state"):
+                    self._build_parallelized_state()
+                self._init_callbacks()
 
     # ------------------------------------------------------------------ setup
     def _setup(self):
@@ -701,18 +729,17 @@ class BaseTrainer:
         if self._numerics is None:
             return
         try:
-            with span("numerics.diagnose"):
-                _state, _metrics, health = self._get_numerics_step()(
-                    self.train_state, batch
-                )
-                # last_anomaly_injected, NOT last_injected: the dispatch-
-                # depth queue drains an entry steps after it was observed,
-                # so the anomalous entry behind this verdict is older than
-                # the current observe() call's injection flag
-                doc = self._numerics.diagnose(
-                    ctl.global_step, health,
-                    injected=self._supervisor.last_anomaly_injected,
-                )
+            _state, _metrics, health = self._get_numerics_step()(
+                self.train_state, batch
+            )
+            # last_anomaly_injected, NOT last_injected: the dispatch-
+            # depth queue drains an entry steps after it was observed,
+            # so the anomalous entry behind this verdict is older than
+            # the current observe() call's injection flag
+            doc = self._numerics.diagnose(
+                ctl.global_step, health,
+                injected=self._supervisor.last_anomaly_injected,
+            )
             del _state, _metrics
             first = doc.get("first_nonfinite")
             ctl.resilience = {**ctl.resilience,
@@ -813,13 +840,15 @@ class BaseTrainer:
             set_active_monitor(self._numerics)
         with use_parallel_state(self.parallel_state):
             try:
-                self._fire("on_train_begin", ctl)
+                with span("setup.train_begin"):
+                    self._fire("on_train_begin", ctl)
                 flight_record("train.begin", cid=str(ctl.global_step),
                               train_steps=self.train_steps)
                 # prefetcher construction AFTER on_train_begin: auto-resume
                 # restores the dataloader cursor there, and the thread starts
                 # pulling at construction
-                data_iter = self._start_data_iter()
+                with span("setup.data"):
+                    data_iter = self._start_data_iter()
             except BaseException as e:
                 # startup failures (auto-resume hitting all-generations-
                 # corrupt, a dead data path) must produce a post-mortem too
@@ -944,10 +973,7 @@ class BaseTrainer:
                                     )
                             ctl.global_step += 1
                             if health is not None:
-                                with span("numerics.observe"):
-                                    self._numerics.observe(
-                                        ctl.global_step, health
-                                    )
+                                self._numerics.observe(ctl.global_step, health)
                             verdict = sup.observe(ctl.global_step, metrics)
                             if sup.last_injected:
                                 # a host-injected step.loss drill marks THIS
