@@ -120,21 +120,36 @@ def _described(device, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(device))
 
 
+# the shapes flash attention's callers hand it: the dense preset's (the
+# benchmark cell's: this is what catches a tile choice that does not fit VMEM
+# without a chip), a vision tower's (non-causal, packed images, D 64), one
+# long row, a sequence that only 128 divides, and a DiT's (no mask at all)
+FLASH_CALLS = {
+    "cell": dict(chip_smoke.FLASH_SHAPE, causal=True, segments=True),
+    "vision": dict(b=2, s=2048, hq=16, hkv=16, d=64, causal=False, segments=True),
+    "long": dict(b=1, s=32768, hq=16, hkv=8, d=128, causal=True, segments=True),
+    "s384": dict(b=2, s=384, hq=4, hkv=2, d=128, causal=True, segments=True),
+    "dit": dict(b=2, s=1024, hq=8, hkv=8, d=128, causal=False, segments=False),
+}
+
+
 @pytest.mark.parametrize("direction,custom_calls", [("fwd", 1), ("bwd", 3)])
-def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, direction, custom_calls):
+@pytest.mark.parametrize("call", list(FLASH_CALLS))
+def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, call, direction, custom_calls):
     from veomni_tpu.ops.pallas.flash_attention import flash_attention
 
-    b, s, hq, hkv, d = (chip_smoke.FLASH_SHAPE[k] for k in ("b", "s", "hq", "hkv", "d"))
+    c = FLASH_CALLS[call]
+    b, s, hq, hkv, d = (c[k] for k in ("b", "s", "hq", "hkv", "d"))
     q = _described(v5e[0], (b, s, hq, d), jnp.bfloat16)
     kv = _described(v5e[0], (b, s, hkv, d), jnp.bfloat16)
-    seg = _described(v5e[0], (b, s), jnp.int32)
+    seg = _described(v5e[0], (b, s), jnp.int32) if c["segments"] else None
 
     def fwd(q, k, v, seg):
         # under its scope, as the model calls it: a kernel's instruction is
         # named after the kernel alone only below some named scope (bare
         # under jax.grad it comes out as jvp_flash_fwd_)
         with jax.named_scope("attn.flash"):
-            return flash_attention(q, k, v, segment_ids=seg, causal=True)
+            return flash_attention(q, k, v, segment_ids=seg, causal=c["causal"])
 
     def loss(q, k, v, seg):
         return fwd(q, k, v, seg).astype(jnp.float32).sum()

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from veomni_tpu.ops.attention import _attention_xla
+from veomni_tpu.ops.attention import _attention_dense, _attention_xla
+from veomni_tpu.ops.pallas import flash_attention as fa
 from veomni_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -54,6 +55,214 @@ def test_flash_backward_matches_xla():
             np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4,
             err_msg=f"grad d{name} mismatch",
         )
+
+
+# ---------------------------------------------------------------------------
+# The tile schedule: small tiles through the internal entry, so that a call
+# has several tile pairs: dead ones, partly live ones and full ones.
+# ---------------------------------------------------------------------------
+def _segments(kind, b, s, seed=0):
+    """[b, s] int32 ids of the kind, or None."""
+    rng = np.random.default_rng(seed)
+    if kind == "none":
+        return None
+    if kind == "unsorted":  # ids that come back: nothing a range test can order
+        return rng.integers(0, 3, (b, s)).astype(np.int32)
+    seg = np.zeros((b, s), np.int32)
+    for row in seg:
+        end = s - (int(rng.integers(5, s // 3)) if kind == "packed_pad" else 0)
+        cuts = np.sort(rng.choice(np.arange(1, end), size=3, replace=False))
+        for i, part in enumerate(np.split(np.arange(end), cuts)):
+            row[part] = i + 1
+    return seg
+
+
+SEG_KINDS = ["none", "packed", "packed_pad", "unsorted"]
+
+
+def _bhsd_inputs(b, s, hq, hkv, d, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, hq, d), jnp.float32),
+            jax.random.normal(ks[1], (b, s, hkv, d), jnp.float32),
+            jax.random.normal(ks[2], (b, s, hkv, d), jnp.float32),
+            jax.random.normal(ks[3], (b, s, hq, d), jnp.float32))
+
+
+def _tiled_flash(q, k, v, seg, causal, tiles):
+    out = fa._flash_bhsd(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+        None if seg is None else jnp.asarray(seg), q.shape[-1] ** -0.5, causal, tiles)
+    return jnp.swapaxes(out, 1, 2)
+
+
+def _check_parity(q, k, v, w, seg, causal, tiles):
+    ref_seg = None if seg is None else jnp.asarray(seg)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) * w).sum()
+
+    ref_fn = lambda q, k, v: _attention_dense(q, k, v, segment_ids=ref_seg, causal=causal)
+    got_fn = lambda q, k, v: _tiled_flash(q, k, v, seg, causal, tiles)
+    np.testing.assert_allclose(np.asarray(got_fn(q, k, v)), np.asarray(ref_fn(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    g_ref = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
+    g_got = jax.grad(loss(got_fn), argnums=(0, 1, 2))(q, k, v)
+    for a, b_, name in zip(g_got, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4,
+                                   err_msg=f"grad d{name} mismatch")
+
+
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("kind", SEG_KINDS)
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_tiled_flash_matches_dense(causal, kind, group):
+    """Forward and backward at 4 x 4 tiles of 128 against the dense impl."""
+    b, s, hkv, d = 2, 512, 1, 64
+    q, k, v, w = _bhsd_inputs(b, s, hkv * group, hkv, d)
+    seg = _segments(kind, b, s, seed=3)
+    t = (128, 128)
+    if kind in ("packed", "packed_pad"):
+        live = fa.tile_liveness(seg, s, 128, 128, causal)
+        assert 0 < live.sum() < live.size  # some tile pairs are dead, some are not
+    _check_parity(q, k, v, w, seg, causal, fa.Tiles(t, t, t))
+
+
+@pytest.mark.parametrize("tiles", [
+    fa.Tiles((256, 128), (128, 256), (256, 128)),
+    fa.Tiles((128, 256), (256, 128), (128, 256)),
+    fa.Tiles((256, 256), (256, 256), (256, 256)),
+], ids=["tall", "wide", "square"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_tiled_flash_tiles_that_differ_by_kernel(causal, tiles):
+    """q and kv tiles of different sizes, and other tiles in the backward's
+    kernels than in the forward (the chooser gives each kernel its own)."""
+    b, s, hq, hkv, d = 1, 512, 2, 1, 64
+    q, k, v, w = _bhsd_inputs(b, s, hq, hkv, d, seed=1)
+    _check_parity(q, k, v, w, _segments("packed_pad", b, s, seed=5), causal, tiles)
+
+
+def test_tiled_flash_without_a_table(monkeypatch):
+    """A table too large for SMEM: the call goes without one (the causal
+    skip by arithmetic, every copy made), and answers the same."""
+    monkeypatch.setattr(fa, "_TABLE_WORDS", 4)
+    b, s, hq, hkv, d = 1, 512, 2, 2, 64
+    q, k, v, w = _bhsd_inputs(b, s, hq, hkv, d, seed=2)
+    t = (128, 128)
+    assert fa._fetch_table(fa.tile_liveness(None, s, 128, 128, True)) is None
+    _check_parity(q, k, v, w, _segments("packed", b, s), True, fa.Tiles(t, t, t))
+
+
+def _dense_liveness(seg, s, bq, bk, causal):
+    admitted = seg[:, :, None] == seg[:, None, :]
+    if causal:
+        admitted &= np.tri(s, dtype=bool)[None]
+    return admitted.reshape(len(seg), s // bq, bq, s // bk, bk).any(axis=(2, 4))
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("kind", ["random", "runs", "packed", "packed_pad"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_liveness_is_never_optimistic_and_exact_for_sorted_ids(causal, kind, seed):
+    rng = np.random.default_rng(seed)
+    b, s = 3, 512
+    bq, bk = [(64, 64), (128, 64), (64, 128)][seed % 3]
+    if kind == "random":
+        seg = rng.integers(-2, 6, (b, s)).astype(np.int32)
+    elif kind == "runs":  # runs of any id in any order
+        seg = np.repeat(rng.integers(0, 5, (b, s // 32)), 32, axis=1).astype(np.int32)
+    else:
+        seg = _segments(kind, b, s, seed=seed)
+    want = _dense_liveness(seg, s, bq, bk, causal)
+    for ids in (seg, jnp.asarray(seg)):  # numpy on the host, jax in the wrapper
+        live = np.asarray(fa.tile_liveness(ids, s, bq, bk, causal))
+        assert live.shape == want.shape
+        assert not (want & ~live).any(), "a tile that holds an admitted pair was called dead"
+        if kind in ("packed", "packed_pad"):
+            np.testing.assert_array_equal(live, want)
+    assert isinstance(fa.tile_liveness(seg, s, bq, bk, causal), np.ndarray)
+
+
+def test_liveness_without_segments_is_the_causal_triangle():
+    live = fa.tile_liveness(None, 512, 256, 128, True)
+    assert live.shape == (1, 2, 4)
+    np.testing.assert_array_equal(live[0], [[1, 1, 0, 0], [1, 1, 1, 1]])
+    assert fa.tile_liveness(None, 512, 128, 128, False).all()
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["fwd_dq", "dkv"])
+def test_a_dead_step_names_the_last_live_block(transposed):
+    """The index maps read the table: a live step names its own block, a
+    dead one the block of the last live step before it in its row (before
+    the row's first live step: that one's), so nothing is copied for it."""
+    b, s, t = 2, 1024, 128
+    seg = _segments("packed_pad", b, s, seed=7)
+    live = fa.tile_liveness(seg, s, t, t, True)
+    if transposed:
+        live = np.swapaxes(live, 1, 2)
+    n = s // t
+    table = np.asarray(fa._fetch_table(jnp.asarray(live)))
+    assert table.dtype == np.int32 and table.shape == (b * n * n,)
+    where = dict(n_outer=n, n_inner=n, per_batch=True)
+    # the BlockSpecs the kernels are built with: the inner axis' side reads
+    # the table, the outer axis' side is the step's own tile
+    specs = fa._block_specs(t, t, 64, 2, True, not transposed, where)
+    inner_side, outer_side = ("q", "kv") if transposed else ("kv", "q")
+    dead_seen = 0
+    for bi in range(b):
+        for outer in range(n):
+            alive = np.flatnonzero(live[bi, outer])
+            assert len(alive)  # the tile on the diagonal always is
+            for inner in range(n):
+                named = fa._inner_block((table,), bi, outer, inner, **where)
+                assert specs[inner_side].index_map(bi, 2, outer, inner, table)[2] == named
+                assert specs[outer_side].index_map(bi, 2, outer, inner, table)[2] == outer
+                assert specs["segs"][1].index_map(bi, 2, outer, inner, table) == (bi, 0, named)
+                if live[bi, outer, inner]:
+                    assert named == inner
+                    continue
+                dead_seen += 1
+                before = alive[alive < inner]
+                assert named == (before[-1] if len(before) else alive[0])
+                assert named != inner  # which is how the kernel tells
+    assert dead_seen > n
+    # without a table an index map names the step's own block
+    assert fa._inner_block((), 1, 2, 3, **where) == 3
+
+
+@pytest.mark.parametrize("s,d,dtype", [
+    (4096, 128, jnp.bfloat16), (32768, 128, jnp.bfloat16), (1024, 64, jnp.bfloat16),
+    (4096, 128, jnp.float32), (2048, 256, jnp.bfloat16), (512, 64, jnp.float32),
+    (384, 128, jnp.bfloat16), (3968, 128, jnp.bfloat16), (128, 64, jnp.bfloat16),
+    (1536, 72, jnp.bfloat16),
+])
+def test_tile_chooser(s, d, dtype):
+    tiles = fa.choose_tiles(s, d, dtype, True)
+    assert tiles == fa.choose_tiles(s, d, dtype, True)  # equal shapes, equal tiles
+    itemsize = jnp.dtype(dtype).itemsize
+    for kernel, (bq, bk) in zip(("fwd", "dkv", "dq"), tiles):
+        assert s % bq == 0 and s % bk == 0
+        assert bq in fa._TILE_SIZES and bk in fa._TILE_SIZES
+        if (bq, bk) != (128, 128):
+            assert fa._vmem_bytes(kernel, bq, bk, d, itemsize) <= fa._VMEM_BUDGET
+        if s % 256:
+            assert (bq, bk) == (128, 128)  # nothing larger divides: as before
+        if s % 256 == 0 and s >= 256:
+            assert bq * bk > 128 * 128  # and a shape that can, does better
+
+
+def test_host_census_counts_the_wrappers_table():
+    """What the trainer loop counts on the host is the table the kernel
+    wrapper builds on the device for the same batch."""
+    s, d = 4096, 64
+    seg = _segments("packed_pad", 4, s, seed=11)
+    pairs, live_pairs = fa.tile_census(seg.reshape(2, 2, s), d, jnp.float32)
+    bq, bk = fa.choose_tiles(s, d, jnp.float32, True).fwd
+    nq, nk = s // bq, s // bk
+    table = np.asarray(fa._fetch_table(fa.tile_liveness(jnp.asarray(seg), s, bq, bk, True)))
+    is_live = table.reshape(4, nq, nk) == np.arange(nk)
+    assert pairs == 4 * nq * nk
+    assert live_pairs == int(is_live.sum()) and 0 < live_pairs < pairs
+    assert fa.tile_census(seg[:, :100], d, jnp.float32) == (0, 0)  # not the kernel's
 
 
 @pytest.mark.parametrize("case", ["ragged_s", "cross", "window"])

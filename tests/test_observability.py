@@ -662,3 +662,53 @@ def test_scope_names_in_the_code_are_the_taxonomy():
                     kernel_lits.update(re.findall(r'\bname="([a-z_]+)"', src))
     assert scope_lits == set(SCOPES)
     assert kernel_lits == set(KERNEL_NAMES)
+
+
+# ------------------------------------------------- flash attention's tiles
+@pytest.mark.parametrize("impl,counted", [("pallas_flash", True), ("auto", False)])
+def test_flash_tile_counters_follow_the_resolved_attention(tmp_path, impl, counted):
+    """attn.flash.tile_pairs[_live] and their ratio are counted on the host,
+    once a step, from the host batch's segment ids, and only when attention
+    resolves to the kernel whose tiles they count."""
+    from veomni_tpu.observability.metrics import MetricsRegistry, set_registry
+    from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
+    from veomni_tpu.ops.pallas.flash_attention import tile_census
+    from veomni_tpu.parallel.parallel_state import destroy_parallel_state
+    from veomni_tpu.trainer import TextTrainer
+    from veomni_tpu.trainer.callbacks import Callback
+
+    from tests.test_e2e_training import TOY, _make_args, _write_dummy_data
+
+    destroy_parallel_state()
+    _write_dummy_data(tmp_path / "data.jsonl")
+    args = _make_args(tmp_path, train_steps=3)
+    args.data.max_seq_len = 256
+    args.model.attn_implementation = impl
+    seen = []
+
+    class Seen(Callback):
+        def on_step_begin(self, trainer, state):
+            seen.append(tile_census(trainer.current_batch["segment_ids"], TOY["head_dim"],
+                                    trainer.model.config.dtype))
+
+    old = set_registry(MetricsRegistry())
+    try:
+        trainer = TextTrainer(args)
+        trainer.callbacks.append(Seen())
+        trainer.train()
+        trainer.checkpointer.close()
+        reg = get_registry()
+        got = {n: reg.get(n) for n in ("attn.flash.tile_pairs", "attn.flash.tile_pairs_live",
+                                       "attn.flash.tiles_live_share")}
+    finally:
+        set_registry(old)
+        KERNEL_REGISTRY.clear_pins()
+        destroy_parallel_state()
+    assert len(seen) == 3
+    if not counted:
+        assert all(v is None for v in got.values())
+        return
+    pairs, live = (sum(x) for x in zip(*seen))
+    assert got["attn.flash.tile_pairs"].value == pairs > 0
+    assert got["attn.flash.tile_pairs_live"].value == live > 0
+    assert got["attn.flash.tiles_live_share"].value == pytest.approx(live / pairs)

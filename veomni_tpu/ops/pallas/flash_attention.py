@@ -3,16 +3,23 @@
 Reference capability: ``veomni/ops/kernels/attention/flash.py`` (adapter over
 external flash-attn CUDA wheels, varlen via cu_seqlens). TPU-native design:
 
-* packing is expressed with **segment ids** (cu_seqlens equivalent): tokens
-  attend only within equal segment id; padding uses a sentinel that matches
-  nothing.
-* layout [B, H, S, D]; grid (batch, q_head, q_block, k_block) with the
-  k_block axis sequential ("arbitrary") carrying the online-softmax state in
-  VMEM scratch; causal k-blocks above the diagonal are skipped via pl.when.
+* packing is expressed with **segment ids** (cu_seqlens equivalent): a token
+  attends to the tokens of equal id, and to nothing else. Padding is one more
+  id (the collator's 0): padding positions attend to each other, and their
+  rows are dropped by the loss, not by the kernel.
+* layout [B, H, S, D]; three kernels, ``flash_fwd``, ``flash_bwd_dkv`` and
+  ``flash_bwd_dq`` (the flash-v2 recomputation split, from the saved LSE),
+  each over a grid (batch, q_head, outer tile, inner tile) whose inner axis is
+  sequential and carries its accumulators in VMEM scratch.
+* **tile schedule.** The tile sizes come from the call's shape
+  (:func:`choose_tiles`: the largest that divide S and fit a VMEM budget, one
+  pair per kernel). Which (q-tile, kv-tile) pairs hold any admitted
+  (query, key) pair is worked out once a call in XLA from the segment ids and
+  the causal mask (:func:`tile_liveness`) and handed to the kernels as a
+  scalar-prefetched table: a dead step runs no body, and its index maps name
+  the block the last live step named, so the pipeline copies nothing for it.
 * GQA: the kv BlockSpec index-maps q-head -> q_head // group, so no
-  materialized head repeat.
-* backward: two kernels (dkv per q-head then XLA group-sum; dq) using the
-  saved LSE — the standard flash-v2 recomputation split.
+  materialized head repeat; dK/dV come out per q head and XLA sums the group.
 
 Numerics: scores/softmax in f32 (MXU preferred_element_type), output cast
 back to the input dtype.
@@ -21,10 +28,11 @@ back to the input dtype.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -34,12 +42,17 @@ from veomni_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
-_LANES = 128  # scratch lane width (TPU min tile)
-_ROWS = 8     # lane width for row-stat (lse/delta) tensors: block lane dim
-              # equal to the array dim satisfies the Mosaic tiling rule
+_LANES = 128  # TPU lane width: the smallest tile, and what a narrow block pads to
+_ROWS = 8     # lane width of the column-form row stats (lse, delta): a block
+              # lane dim equal to the array dim satisfies the Mosaic tiling rule
+_TILE_SIZES = (1024, 512, 256, 128)
+_VMEM_BUDGET = 24 * 2 ** 20   # what a kernel's blocks, scratch and score-sized
+                              # temporaries may come to (v5e: 128 MiB of VMEM)
+_TABLE_WORDS = 64 * 1024      # the liveness table lives in SMEM: past this
+                              # many entries a call goes without one
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
 def _interpret() -> bool:
@@ -47,16 +60,221 @@ def _interpret() -> bool:
 
 
 # ==========================================================================
+# The schedule: tile sizes, liveness, the prefetched table
+# ==========================================================================
+class Tiles(NamedTuple):
+    """(q tile, kv tile) of each kernel."""
+
+    fwd: Tuple[int, int]
+    dkv: Tuple[int, int]
+    dq: Tuple[int, int]
+
+
+def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, itemsize: int) -> int:
+    """VMEM a kernel needs at these tiles: its blocks twice (the pipeline
+    double-buffers them), its scratch, and its score-sized f32 temporaries."""
+    col = lambda n: n * _LANES * 4           # an [n, 1] or [n, _ROWS] block pads to 128 lanes
+    row = lambda n: 8 * n * 4                # a [1, n] block pads to 8 sublanes
+    q_side, kv_side = bq * d * itemsize, bk * d * itemsize
+    if kernel == "fwd":
+        blocks = 2 * q_side + 2 * kv_side + 2 * col(bq) + row(bk)
+        scratch = 2 * col(bq) + bq * d * 4
+        scores = 4 * bq * bk * 4
+    elif kernel == "dq":
+        blocks = 3 * q_side + 2 * kv_side + 3 * col(bq) + row(bk)
+        scratch = bq * d * 4
+        scores = 6 * bq * bk * 4
+    else:  # dkv
+        blocks = 2 * q_side + 2 * kv_side + 2 * bk * d * 4 + 3 * row(bq) + col(bk)
+        scratch = 2 * bk * d * 4
+        scores = 6 * bq * bk * 4
+    return 2 * blocks + scratch + scores
+
+
+@functools.lru_cache(maxsize=None)
+def choose_tiles(s: int, d: int, dtype, causal: bool) -> Tiles:
+    """Tile sizes for a call of sequence length ``s`` and head dim ``d``:
+    per kernel the pair of largest area among {1024, 512, 256, 128}^2 that
+    divides ``s`` and fits the VMEM budget (the backward's kernels hold more
+    score-sized temporaries than the forward, so theirs come out smaller); of
+    equal areas the one with the longer kv tile. 128 where nothing larger
+    divides ``s``. ``causal`` does not move the choice: the same tiles serve
+    both, and a causal call's table skips what lies above the diagonal.
+    (On a v5e the largest tile that fits won at every shape read, though it
+    covers a packing more loosely: PERF.md, PR 28.)"""
+    itemsize = jnp.dtype(dtype).itemsize
+    sizes = [t for t in _TILE_SIZES if s % t == 0] or [s]
+
+    def best(kernel):
+        fits = [(bq, bk) for bq in sizes for bk in sizes
+                if _vmem_bytes(kernel, bq, bk, d, itemsize) <= _VMEM_BUDGET]
+        return max(fits or [(sizes[-1], sizes[-1])], key=lambda t: (t[0] * t[1], t[1]))
+
+    return Tiles(fwd=best("fwd"), dkv=best("dkv"), dq=best("dq"))
+
+
+def tile_liveness(segment_ids, s: int, bq: int, bk: int, causal: bool):
+    """``[B, s/bq, s/bk]`` bool (``[1, ...]`` without segment ids): may the
+    (q-tile, kv-tile) pair hold a (query, key) pair that the segment mask and
+    the causal mask admit? Never says no to a tile that holds one.
+
+    Two range tests: a pair is live if the tiles' [min, max] of the ids
+    overlap, and those of the ids less one (unsigned, so that 0 goes last)
+    overlap too. Each is a necessary condition for an equal id on both sides,
+    and is exact where the ids do not decrease along the row under its
+    ordering: the first for sorted ids, the second for what the packing
+    collator emits (documents 1, 2, ... then padding 0). Takes numpy or jax
+    arrays, and gives back the same kind.
+    """
+    nq, nk = s // bq, s // bk
+    live = np.ones((1, nq, nk), bool)
+    if causal:
+        iq, jk = np.arange(nq)[:, None], np.arange(nk)[None, :]
+        live = (jk * bk <= iq * bq + bq - 1)[None]
+    if segment_ids is None:
+        return live
+    xp = np if isinstance(segment_ids, np.ndarray) else jnp
+    b = segment_ids.shape[0]
+    ids = segment_ids.astype(xp.uint32)
+    for key in (ids, ids - xp.uint32(1)):
+        tq, tk = key.reshape(b, nq, bq), key.reshape(b, nk, bk)
+        lo_q, hi_q = tq.min(-1)[:, :, None], tq.max(-1)[:, :, None]
+        lo_k, hi_k = tk.min(-1)[:, None, :], tk.max(-1)[:, None, :]
+        live = live & (lo_q <= hi_k) & (lo_k <= hi_q)
+    return live
+
+
+def tile_census(segment_ids, head_dim: int, dtype, causal: bool = True) -> Tuple[int, int]:
+    """(tile pairs, live tile pairs) of the forward kernel over a host batch's
+    rows ``[..., S]``, by the functions the kernel wrapper itself calls: what
+    the trainer loop counts into ``attn.flash.tile_pairs[_live]``. (0, 0)
+    where the kernel would not take the shape."""
+    seg = np.asarray(segment_ids)
+    s = seg.shape[-1]
+    if s % _LANES:
+        return 0, 0
+    bq, bk = choose_tiles(s, head_dim, dtype, causal).fwd
+    live = tile_liveness(seg.reshape(-1, s), s, bq, bk, causal)
+    return int(live.size), int(live.sum())
+
+
+def _fetch_table(live):
+    """The prefetched table of one kernel, ``[B * n_outer * n_inner]`` int32
+    from ``live [B, n_outer, n_inner]``: the inner-axis block each step names.
+    A live step names its own (so ``table[step] == inner`` says live); a dead
+    one the last live step's before it in its row, or, before the row's first
+    live step, that first one's. None where the table would not fit SMEM."""
+    if live.size > _TABLE_WORDS:
+        return None
+    inner = jnp.arange(live.shape[-1], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(live, inner, -1), axis=2)
+    first = jnp.argmax(live, axis=-1).astype(jnp.int32)[..., None]
+    return jnp.where(last >= 0, last, first).reshape(-1)
+
+
+def _table_index(bi, outer, inner, n_outer: int, n_inner: int, per_batch: bool):
+    return ((bi if per_batch else 0) * n_outer + outer) * n_inner + inner
+
+
+def _inner_block(tbl, bi, outer, inner, **where):
+    """What the index maps name on the inner axis: the table's entry, or the
+    step's own block where the call has no table (``tbl`` is the tuple of
+    scalar-prefetch refs an index map is handed)."""
+    return tbl[0][_table_index(bi, outer, inner, **where)] if tbl else inner
+
+
+def _step_is_live(tbl_ref, bi, iq, jk, *, q_outer: bool, causal: bool, bq: int, bk: int, where):
+    """Kernel half of the schedule: does this grid step compute? By the table
+    (a live step's entry is its own inner tile), or without one by the causal
+    mask alone."""
+    if tbl_ref is not None:
+        outer, inner = (iq, jk) if q_outer else (jk, iq)
+        return tbl_ref[_table_index(bi, outer, inner, **where)] == inner
+    return (jk * bk <= iq * bq + bq - 1) if causal else True
+
+
+def _split_refs(refs, table: bool, segmented: bool):
+    refs = list(refs)
+    tbl_ref = refs.pop(0) if table else None
+    seg_col_ref, seg_row_ref = (refs.pop(0), refs.pop(0)) if segmented else (None, None)
+    return tbl_ref, seg_col_ref, seg_row_ref, refs
+
+
+def _schedule(segment_ids, s: int, bq: int, bk: int, causal: bool, q_outer: bool):
+    """(table or None, where) of a kernel whose outer grid axis walks the q
+    tiles (forward, dQ) or the kv tiles (dKV); ``where`` is what
+    :func:`_table_index` needs to find a step's entry."""
+    n_outer, n_inner = (s // bq, s // bk) if q_outer else (s // bk, s // bq)
+    where = dict(n_outer=n_outer, n_inner=n_inner, per_batch=segment_ids is not None)
+    if segment_ids is None and not causal:
+        return None, where  # every pair is live: no table, and no cost
+    live = tile_liveness(segment_ids, s, bq, bk, causal)
+    return _fetch_table(live if q_outer else jnp.swapaxes(live, 1, 2)), where
+
+
+def _block_specs(bq: int, bk: int, d: int, group: int, segmented: bool, q_outer: bool, where):
+    """BlockSpecs over a grid (batch, q head, outer tile, inner tile): q-side
+    blocks [bq, d], kv-side blocks [bk, d], the q-side row stats as columns
+    [bq, _ROWS] and as rows [1, bq], and the two segment-id blocks (the ids
+    along the score block's rows as a column, those along its columns as a
+    row). The inner tile is read through the table."""
+
+    def tiles(bi, outer, inner, tbl):  # (q tile, kv tile) a grid step names
+        named = _inner_block(tbl, bi, outer, inner, **where)
+        return (outer, named) if q_outer else (named, outer)
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda bi, hi, o, i, *t: index(bi, hi, *tiles(bi, o, i, t)))
+
+    seg_specs = []
+    if segmented and q_outer:
+        seg_specs = [spec((None, bq, 1), lambda bi, hi, tq, tk: (bi, tq, 0)),
+                     spec((None, 1, bk), lambda bi, hi, tq, tk: (bi, 0, tk))]
+    elif segmented:
+        seg_specs = [spec((None, bk, 1), lambda bi, hi, tq, tk: (bi, tk, 0)),
+                     spec((None, 1, bq), lambda bi, hi, tq, tk: (bi, 0, tq))]
+    return dict(
+        q=spec((1, 1, bq, d), lambda bi, hi, tq, tk: (bi, hi, tq, 0)),
+        kv=spec((1, 1, bk, d), lambda bi, hi, tq, tk: (bi, hi // group, tk, 0)),
+        q_cols=spec((1, 1, bq, _ROWS), lambda bi, hi, tq, tk: (bi, hi, tq, 0)),
+        q_rows=spec((1, 1, 1, bq), lambda bi, hi, tq, tk: (bi, hi, 0, tq)),
+        segs=seg_specs,
+    )
+
+
+def _admitted(seg_col, seg_row, q0, k0, shape, causal: bool, q_on_rows: bool):
+    """The mask of a score block ``shape`` whose queries start at ``q0`` and
+    keys at ``k0`` (None: everything is admitted). ``seg_col`` [n, 1] and
+    ``seg_row`` [1, m] are the ids along the block's rows and columns."""
+    mask = None
+    if seg_col is not None:
+        mask = seg_col == seg_row
+    if causal:
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        # query position >= key position
+        tri = (rows - cols >= k0 - q0) if q_on_rows else (cols - rows >= k0 - q0)
+        mask = tri if mask is None else mask & tri
+    return mask
+
+
+def _compiler_params(kernel: str, bq: int, bk: int, d: int, dtype):
+    need = _vmem_bytes(kernel, bq, bk, d, jnp.dtype(dtype).itemsize)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        # twice the counted footprint: the compiler's own temporaries are not
+        # all counted, and the limit only has to be one it can stay under
+        vmem_limit_bytes=int(min(max(2 * need, 16 * 2 ** 20), 100 * 2 ** 20)),
+    )
+
+
+# ==========================================================================
 # Forward
 # ==========================================================================
-def _fwd_kernel(
-    seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref,
-    o_ref, lse_ref,
-    m_scr, l_scr, acc_scr,
-    *, scale: float, causal: bool, bq: int, bk: int,
-):
-    iq, jk = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+def _fwd_kernel(*refs, scale, causal, bq, bk, table, segmented, where):
+    tbl_ref, seg_q_ref, seg_k_ref, refs = _split_refs(refs, table, segmented)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    bi, iq, jk = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(jk == 0)
     def _init():
@@ -64,282 +282,252 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: skip blocks strictly above the diagonal
-    work = True if not causal else (jk * bk <= iq * bq + bq - 1)
-
-    @pl.when(work)
+    @pl.when(_step_is_live(tbl_ref, bi, iq, jk, q_outer=True, causal=causal, bq=bq, bk=bk,
+                           where=where))
     def _block():
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-
-        seg_q = seg_q_ref[0, :]  # [bq]
-        seg_k = seg_k_ref[0, :]  # [bk]
-        mask = seg_q[:, None] == seg_k[None, :]
-        if causal:
-            rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = mask & (rows >= cols)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scr[:, 0]
-        l_prev = l_scr[:, 0]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
+        mask = _admitted(
+            seg_q_ref[...] if segmented else None, seg_k_ref[...] if segmented else None,
+            iq * bq, jk * bk, s.shape, causal, True)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_scr[...]  # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
-    @pl.when(jk == nk - 1)
+    @pl.when(jk == pl.num_programs(3) - 1)
     def _finish():
-        l = l_scr[:, 0]
+        l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
-        m = m_scr[:, 0]
-        lse = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(l_safe))
-        lse_ref[0, 0, :, :] = jnp.broadcast_to(lse[:, None], (lse.shape[0], _ROWS))
+        o_ref[0, 0, :, :] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        lse = jnp.where(l == 0.0, _NEG_INF, m_scr[...] + jnp.log(l_safe))
+        lse_ref[0, 0, :, :] = jnp.broadcast_to(lse, (bq, _ROWS))
 
 
-def _fwd(q, k, v, segment_ids, scale, causal, bq, bk):
+def _seg_forms(segment_ids):
+    """Segment ids as a column ``[B, S, 1]`` and a row ``[B, 1, S]`` (nothing
+    without ids): a kernel compares the ids along its score block's rows with
+    those along its columns, and takes each in the layout it is used in (a
+    unit dim equal to the array's satisfies Mosaic's (8, 128) tiling rule)."""
+    if segment_ids is None:
+        return ()
+    return segment_ids[:, :, None], segment_ids[:, None, :]
+
+
+def _prefetch(tbl):
+    return () if tbl is None else (tbl,)
+
+
+def _fwd(q, k, v, segment_ids, scale, causal, tiles):
     b, hq, s, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    nq, nk = s // bq, s // bk
-
-    grid = (b, hq, nq, nk)
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, iq, jk: (bi, hi // group, jk, 0))
+    bq, bk = tiles.fwd
+    segmented = segment_ids is not None
+    tbl, where = _schedule(segment_ids, s, bq, bk, causal, True)
+    specs = _block_specs(bq, bk, d, hq // k.shape[1], segmented, True, where)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk),
-        grid=grid,
-        in_specs=[
-            # segment ids ride as [B, 1, S]: a squeezed-batch rank-2 block
-            # (1, bq) would violate Mosaic's (8, 128) tiling rule; with the
-            # unit middle dim the block's last-two dims are (1, bq) where
-            # 1 == the array dim, which Mosaic accepts.
-            pl.BlockSpec((None, 1, bq), lambda bi, hi, iq, jk: (bi, 0, iq)),
-            pl.BlockSpec((None, 1, bk), lambda bi, hi, iq, jk: (bi, 0, jk)),
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, iq, jk: (bi, hi, iq, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, iq, jk: (bi, hi, iq, 0)),
-            pl.BlockSpec((1, 1, bq, _ROWS), lambda bi, hi, iq, jk: (bi, hi, iq, 0)),
-        ],
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+                          table=tbl is not None, segmented=segmented, where=where),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(tbl is not None),
+            grid=(b, hq, s // bq, s // bk),
+            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["kv"]],
+            out_specs=[specs["q"], specs["q_cols"]],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, hq, s, _ROWS), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params("fwd", bq, bk, d, q.dtype),
         interpret=_interpret(),
         name="flash_fwd",  # observability/scopes.py::KERNEL_NAMES
-    )(segment_ids[:, None, :], segment_ids[:, None, :], q, k, v)
+    )(*_prefetch(tbl), *_seg_forms(segment_ids), q, k, v)
     return out, lse
 
 
 # ==========================================================================
 # Backward
 # ==========================================================================
-def _bwd_dkv_kernel(
-    seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref,
-    dk_scr, dv_scr,
-    *, scale: float, causal: bool, bq: int, bk: int,
-):
-    jk, iq = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
+def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, table, segmented, where):
+    """dK, dV of one kv tile, summed over the q tiles (the inner axis). The
+    scores are computed transposed, [bk, bq]: p^T and ds^T are then the left
+    operands of plain matmuls, and lse and delta ride as rows."""
+    tbl_ref, seg_k_ref, seg_q_ref, refs = _split_refs(refs, table, segmented)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    bi, jk, iq = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(iq == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    work = True if not causal else (iq * bq + bq - 1 >= jk * bk)
-
-    @pl.when(work)
+    @pl.when(_step_is_live(tbl_ref, bi, iq, jk, q_outer=False, causal=causal, bq=bq, bk=bk,
+                           where=where))
     def _block():
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
         do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-        mask = seg_q_ref[0, :][:, None] == seg_k_ref[0, :][None, :]
-        if causal:
-            rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = mask & (rows >= cols)
+        lse = lse_ref[0, 0, :, :]       # [1, bq]
+        delta = delta_ref[0, 0, :, :]
+        st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
         lse_safe = jnp.where(lse <= _NEG_INF / 2, 0.0, lse)
-        p = jnp.where(mask, jnp.exp(s - lse_safe[:, None]), 0.0)  # [bq, bk]
-
+        pt = jnp.exp(st - lse_safe)     # [bk, bq]
+        mask = _admitted(
+            seg_k_ref[...] if segmented else None, seg_q_ref[...] if segmented else None,
+            iq * bq, jk * bk, st.shape, causal, False)
+        if mask is not None:
+            pt = jnp.where(mask, pt, 0.0)
+        # p and ds go to the MXU as they are: at default precision Mosaic
+        # rounds an f32 operand to bf16 itself, bit for bit what a cast to
+        # the inputs' dtype gives, and here the cast only cost time (in the
+        # forward it saves some: PERF.md, PR 28)
         dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # p^T @ do -> [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
-        ds = p * (dp - delta[:, None]) * scale
+            pt, do, _NN, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta) * scale
         dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # ds^T @ q -> [bk, d]
+            dst, q, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(iq == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[0, 0, :, :] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(
-    seg_q_ref, seg_k_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref,
-    dq_scr,
-    *, scale: float, causal: bool, bq: int, bk: int,
-):
-    iq, jk = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+def _bwd_dq_kernel(*refs, scale, causal, bq, bk, table, segmented, where):
+    tbl_ref, seg_q_ref, seg_k_ref, refs = _split_refs(refs, table, segmented)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
+    bi, iq, jk = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(jk == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    work = True if not causal else (jk * bk <= iq * bq + bq - 1)
-
-    @pl.when(work)
+    @pl.when(_step_is_live(tbl_ref, bi, iq, jk, q_outer=True, causal=causal, bq=bq, bk=bk,
+                           where=where))
     def _block():
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
         do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        mask = seg_q_ref[0, :][:, None] == seg_k_ref[0, :][None, :]
-        if causal:
-            rows = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = mask & (rows >= cols)
+        lse = lse_ref[0, 0, :, 0:1]     # [bq, 1]
+        delta = delta_ref[0, 0, :, 0:1]
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
         lse_safe = jnp.where(lse <= _NEG_INF / 2, 0.0, lse)
-        p = jnp.where(mask, jnp.exp(s - lse_safe[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[:, None]) * scale
+        p = jnp.exp(s - lse_safe)
+        mask = _admitted(
+            seg_q_ref[...] if segmented else None, seg_k_ref[...] if segmented else None,
+            iq * bq, jk * bk, s.shape, causal, True)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * scale
         dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, d]
+            ds, k, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(jk == nk - 1)
+    @pl.when(jk == pl.num_programs(3) - 1)
     def _finish():
         dq_ref[0, 0, :, :] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _bwd(scale, causal, bq, bk, residuals, g):
+def _bwd(scale, causal, tiles, residuals, g):
     q, k, v, segment_ids, out, lse = residuals
     do = g[0] if isinstance(g, (tuple, list)) else g
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv
-    nq, nk = s // bq, s // bk
+    segmented = segment_ids is not None
+    segs = _seg_forms(segment_ids)
 
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], delta.shape + (_ROWS,))  # [B,H,S,_ROWS]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,S]
+    # dQ takes the row stats as columns [B,H,S,_ROWS], dKV as rows [B,H,1,S]
+    delta_cols = jnp.broadcast_to(delta[..., None], delta.shape + (_ROWS,))
+    lse_rows, delta_rows = lse[..., 0][:, :, None, :], delta[:, :, None, :]
 
-    seg3 = segment_ids[:, None, :]  # [B, 1, S] — see fwd in_specs comment
-    seg_specs = [
-        pl.BlockSpec((None, 1, bq), lambda bi, hi, jk, iq: (bi, 0, iq)),
-        pl.BlockSpec((None, 1, bk), lambda bi, hi, jk, iq: (bi, 0, jk)),
-    ]
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, jk, iq: (bi, hi, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq: (bi, hi // group, jk, 0))
-    row_spec = pl.BlockSpec((1, 1, bq, _ROWS), lambda bi, hi, jk, iq: (bi, hi, iq, 0))
-
+    # ---- dK, dV: grid (b, h, kv tile, q tile)
+    bq, bk = tiles.dkv
+    tbl, where = _schedule(segment_ids, s, bq, bk, causal, False)
+    specs = _block_specs(bq, bk, d, group, segmented, False, where)
+    dkv_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))
     dk_per_head, dv_per_head = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk),
-        grid=(b, hq, nk, nq),
-        in_specs=[*seg_specs, q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq: (bi, hi, jk, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq: (bi, hi, jk, 0)),
-        ],
+        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+                          table=tbl is not None, segmented=segmented, where=where),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(tbl is not None),
+            grid=(b, hq, s // bk, s // bq),
+            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["kv"], specs["q"],
+                      specs["q_rows"], specs["q_rows"]],
+            out_specs=[dkv_spec, dkv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s, d), jnp.float32),
             jax.ShapeDtypeStruct((b, hq, s, d), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params("dkv", bq, bk, d, q.dtype),
         interpret=_interpret(),
         name="flash_bwd_dkv",
-    )(seg3, seg3, q, k, v, do, lse, delta)
+    )(*_prefetch(tbl), *segs, q, k, v, do, lse_rows, delta_rows)
 
     # GQA: fold the q-head group into the kv head grad
     dk = dk_per_head.reshape(b, hkv, group, s, d).sum(axis=2).astype(k.dtype)
     dv = dv_per_head.reshape(b, hkv, group, s, d).sum(axis=2).astype(v.dtype)
 
-    q_spec2 = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, iq, jk: (bi, hi, iq, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, iq, jk: (bi, hi // group, jk, 0))
-    row_spec2 = pl.BlockSpec((1, 1, bq, _ROWS), lambda bi, hi, iq, jk: (bi, hi, iq, 0))
-    seg_specs2 = [
-        pl.BlockSpec((None, 1, bq), lambda bi, hi, iq, jk: (bi, 0, iq)),
-        pl.BlockSpec((None, 1, bk), lambda bi, hi, iq, jk: (bi, 0, jk)),
-    ]
+    # ---- dQ: grid (b, h, q tile, kv tile)
+    bq, bk = tiles.dq
+    tbl, where = _schedule(segment_ids, s, bq, bk, causal, True)
+    specs = _block_specs(bq, bk, d, group, segmented, True, where)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk),
-        grid=(b, hq, nq, nk),
-        in_specs=[*seg_specs2, q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, iq, jk: (bi, hi, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
+                          table=tbl is not None, segmented=segmented, where=where),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(tbl is not None),
+            grid=(b, hq, s // bq, s // bk),
+            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["kv"], specs["q"],
+                      specs["q_cols"], specs["q_cols"]],
+            out_specs=specs["q"],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
+        out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
+        compiler_params=_compiler_params("dq", bq, bk, d, q.dtype),
         interpret=_interpret(),
         name="flash_bwd_dq",
-    )(seg3, seg3, q, k, v, do, lse, delta)
+    )(*_prefetch(tbl), *segs, q, k, v, do, lse, delta_cols)
 
     return dq, dk, dv, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_bhsd(q, k, v, segment_ids, scale, causal, bq, bk):
-    out, _ = _fwd(q, k, v, segment_ids, scale, causal, bq, bk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_bhsd(q, k, v, segment_ids, scale, causal, tiles):
+    """The kernels on [B, H, S, D]; ``segment_ids`` [B, S] int32 or None,
+    ``tiles`` a static :class:`Tiles`."""
+    out, _ = _fwd(q, k, v, segment_ids, scale, causal, tiles)
     return out
 
 
-def _flash_fwd_rule(q, k, v, segment_ids, scale, causal, bq, bk):
-    out, lse = _fwd(q, k, v, segment_ids, scale, causal, bq, bk)
+def _flash_fwd_rule(q, k, v, segment_ids, scale, causal, tiles):
+    out, lse = _fwd(q, k, v, segment_ids, scale, causal, tiles)
     return out, (q, k, v, segment_ids, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, bq, bk, residuals, g):
-    return _bwd(scale, causal, bq, bk, residuals, g)
+def _flash_bwd_rule(scale, causal, tiles, residuals, g):
+    return _bwd(scale, causal, tiles, residuals, g)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -348,7 +536,7 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ==========================================================================
 # Public op (registered)
 # ==========================================================================
-def _handoff_reason(q, k, v, sliding_window, sinks, bq, bk, pstate) -> Optional[str]:
+def _handoff_reason(q, k, v, sliding_window, sinks, pstate) -> Optional[str]:
     """Why this call cannot take the kernel (None: it can)."""
     b, s, hq, d = q.shape
     if sliding_window is not None:
@@ -359,8 +547,8 @@ def _handoff_reason(q, k, v, sliding_window, sinks, bq, bk, pstate) -> Optional[
         return "v head_dim != qk head_dim"
     if k.shape[1] != s:
         return "Sq != Sk"
-    # lane-aligned blocks that tile the sequence exactly
-    if s % bq or s % bk or bq % _LANES or bk % _LANES:
+    # lane-aligned tiles that tile the sequence exactly
+    if s % _LANES:
         return f"S not a multiple of {_LANES}"
     if hq % k.shape[2]:
         return "q heads not a multiple of kv heads"
@@ -381,8 +569,6 @@ def flash_attention(
     softmax_scale: Optional[float] = None,
     sliding_window=None,
     sinks: Optional[jax.Array] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
 ):
     """[B, S, H, D] facade-layout wrapper. Shapes/features the kernel
     doesn't cover (sliding window, sinks, MLA's asymmetric v-dim, cross
@@ -394,9 +580,8 @@ def flash_attention(
     from veomni_tpu.parallel.parallel_state import gspmd_parallel_state
 
     b, s, hq, d = q.shape
-    bq, bk = min(block_q, s), min(block_k, s)
     pstate = gspmd_parallel_state()
-    reason = _handoff_reason(q, k, v, sliding_window, sinks, bq, bk, pstate)
+    reason = _handoff_reason(q, k, v, sliding_window, sinks, pstate)
     if reason is not None:
         from veomni_tpu.ops.attention import _attention_xla
 
@@ -410,21 +595,21 @@ def flash_attention(
             sinks=sinks,
         )
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    if segment_ids is None:
-        segment_ids = jnp.zeros((b, s), jnp.int32)
+    tiles = choose_tiles(s, d, q.dtype, causal)
 
-    def kernel(q, k, v, seg):
+    def kernel(q, k, v, *seg):
         out = _flash_bhsd(
             jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-            seg.astype(jnp.int32), scale, causal, bq, bk,
+            seg[0].astype(jnp.int32) if seg else None, scale, causal, tiles,
         )
         return jnp.swapaxes(out, 1, 2)
 
+    seg = () if segment_ids is None else (segment_ids,)
     if pstate is not None:
         qkv_spec = P(pstate.dp_axes, None, None, None)
         kernel = jax.shard_map(
             kernel, mesh=pstate.mesh,
-            in_specs=(qkv_spec, qkv_spec, qkv_spec, P(pstate.dp_axes, None)),
+            in_specs=(qkv_spec, qkv_spec, qkv_spec) + (P(pstate.dp_axes, None),) * len(seg),
             out_specs=qkv_spec, check_vma=False,
         )
-    return kernel(q, k, v, segment_ids)
+    return kernel(q, k, v, *seg)

@@ -122,6 +122,7 @@ class BaseTrainer:
             self._setup()
             with use_parallel_state(self.parallel_state):
                 self._build_model()
+                self._flash_tile_counters = self._build_flash_tile_counters()
                 with span("setup.data"):
                     self._build_data_transform()
                     self._build_dataset()
@@ -214,6 +215,40 @@ class BaseTrainer:
         self.tokenizer = None
         if m.tokenizer_path and needs_tokenizer:
             self.tokenizer = build_tokenizer(m.tokenizer_path)
+
+    def _build_flash_tile_counters(self):
+        """``attn.flash.tile_pairs[_live]`` and their ratio: how many of the
+        flash kernel's (q-tile, kv-tile) pairs the packing leaves alive,
+        counted once a step ON THE HOST from the host batch's segment ids by
+        the functions the kernel wrapper calls on the device (no sync, no
+        callback in the jitted step). None unless attention resolves to
+        ``pallas_flash`` in a causal text decoder (ring CP hands the kernel
+        chunks the host does not see)."""
+        from veomni_tpu.models.config import TransformerConfig
+        from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
+        from veomni_tpu.ops.pallas.flash_attention import tile_census
+
+        cfg = getattr(self.model, "config", None)
+        if (KERNEL_REGISTRY.resolved_name("attention") != "pallas_flash"
+                or not isinstance(cfg, TransformerConfig)
+                or self.parallel_state.cp_size > 1):
+            return None
+        head_dim, dtype = cfg.head_dim, cfg.dtype
+        reg = get_registry()
+        pairs, live = reg.counter("attn.flash.tile_pairs"), reg.counter("attn.flash.tile_pairs_live")
+        share = reg.gauge("attn.flash.tiles_live_share")
+
+        def count(batch_np):
+            seg = batch_np.get("segment_ids")
+            if seg is None:
+                return
+            n, n_live = tile_census(seg, head_dim, dtype)
+            if n:
+                pairs.inc(n)
+                live.inc(n_live)
+                share.set(live.value / pairs.value)
+
+        return count
 
     def _toy_config(self, overrides):
         from veomni_tpu.models.auto import build_config
@@ -899,6 +934,8 @@ class BaseTrainer:
                                     break  # prefetcher closed by the handler
                                 raise
                             self.current_batch = batch_np
+                            if self._flash_tile_counters is not None:
+                                self._flash_tile_counters(batch_np)
                             # straggler drill point (fleet observatory): a
                             # `delay`-mode fault here slows THIS rank's loop
                             # deterministically, so the skew exchange +
