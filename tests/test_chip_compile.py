@@ -123,8 +123,11 @@ def _described(device, shape, dtype):
 # the shapes flash attention's callers hand it: the dense preset's (the
 # benchmark cell's: this is what catches a tile choice that does not fit VMEM
 # without a chip), a vision tower's (non-causal, packed images, D 64), one
-# long row, a sequence that only 128 divides, and a DiT's (no mask at all)
+# long row, a sequence that only 128 divides, a DiT's (no mask at all), and
+# MLA's training form at JoyAI-LLM-Flash's widths (q, k of 128 nope + 64
+# rope, v of 128: the joyai_llm_flash.train_packed_8k cell's call)
 FLASH_CALLS = {
+    "mla": dict(b=2, s=8192, hq=32, hkv=32, d=192, dv=128, causal=True, segments=True),
     "cell": dict(chip_smoke.FLASH_SHAPE, causal=True, segments=True),
     "vision": dict(b=2, s=2048, hq=16, hkv=16, d=64, causal=False, segments=True),
     "long": dict(b=1, s=32768, hq=16, hkv=8, d=128, causal=True, segments=True),
@@ -142,6 +145,7 @@ def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, call, direction, c
     b, s, hq, hkv, d = (c[k] for k in ("b", "s", "hq", "hkv", "d"))
     q = _described(v5e[0], (b, s, hq, d), jnp.bfloat16)
     kv = _described(v5e[0], (b, s, hkv, d), jnp.bfloat16)
+    v = _described(v5e[0], (b, s, hkv, c.get("dv", d)), jnp.bfloat16)
     seg = _described(v5e[0], (b, s), jnp.int32) if c["segments"] else None
 
     def fwd(q, k, v, seg):
@@ -155,7 +159,7 @@ def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, call, direction, c
         return fwd(q, k, v, seg).astype(jnp.float32).sum()
 
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
-    text = jax.jit(fn).lower(q, kv, kv, seg).compile().as_text()
+    text = jax.jit(fn).lower(q, kv, v, seg).compile().as_text()
     assert text.count("tpu_custom_call") == custom_calls
     # the kernels' own names are the custom calls' instruction names
     # (observability/scopes.py::KERNEL_NAMES): a trace tells them apart
@@ -184,8 +188,13 @@ def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
     assert text.count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("shape", chip_smoke.GMM_SHAPES,
-                         ids=lambda s: f"n{s['n']}e{s['e']}")
+# the smoke's shapes, and the held experts' buffer of the
+# joyai_llm_flash.train_packed_8k cell (16 of 256 experts, 8,192 rows)
+GMM_SHAPES = chip_smoke.GMM_SHAPES + (dict(m=8192, k=2048, n=768, e=16),)
+
+
+@pytest.mark.parametrize("shape", GMM_SHAPES,
+                         ids=lambda s: f"m{s['m']}n{s['n']}e{s['e']}")
 @pytest.mark.parametrize("kernel", ["fwd", "dlhs", "drhs"])
 def test_grouped_gemm_lowers_for_v5e(v5e, on_chip_kernels, kernel, shape):
     from veomni_tpu.ops.pallas import grouped_gemm as gg

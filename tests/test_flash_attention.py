@@ -141,6 +141,31 @@ def test_tiled_flash_tiles_that_differ_by_kernel(causal, tiles):
     _check_parity(q, k, v, w, _segments("packed_pad", b, s, seed=5), causal, tiles)
 
 
+@pytest.mark.parametrize("kind", SEG_KINDS)
+def test_tiled_flash_with_v_narrower_than_qk(kind):
+    """MLA's training form (q, k of nope + rope, v of nope alone): forward
+    and the three gradients at 4 x 4 tiles against the dense impl, causal,
+    scores scaled by the q/k width; dQ, dK come out as wide as q, dV as v."""
+    b, s, h, d, dv = 2, 512, 2, 96, 64
+    q, k, _, _ = _bhsd_inputs(b, s, h, h, d, seed=4)
+    _, _, v, w = _bhsd_inputs(b, s, h, h, dv, seed=5)
+    t = (128, 128)
+    _check_parity(q, k, v, w, _segments(kind, b, s, seed=3), True, fa.Tiles(t, t, t))
+
+
+def test_flash_facade_keeps_mla_widths_on_the_kernels(monkeypatch):
+    """The facade no longer hands a call whose v is narrower than q, k to
+    XLA: no hand-off line, the XLA path's answer, o as wide as v."""
+    seen = []
+    monkeypatch.setattr(fa.logger, "info_once", lambda msg, *a: seen.append(msg % a))
+    q, k, v, seg = _inputs(d=96)
+    v = v[..., :64]
+    got = flash_attention(q, k, v, segment_ids=seg, causal=True)
+    ref = _attention_xla(q, k, v, segment_ids=seg, causal=True)
+    assert got.shape == q.shape[:-1] + (64,) and not seen, seen
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
 def test_tiled_flash_without_a_table(monkeypatch):
     """A table too large for SMEM: the call goes without one (the causal
     skip by arithmetic, every copy made), and answers the same."""
@@ -248,6 +273,28 @@ def test_tile_chooser(s, d, dtype):
             assert (bq, bk) == (128, 128)  # nothing larger divides: as before
         if s % 256 == 0 and s >= 256:
             assert bq * bk > 128 * 128  # and a shape that can, does better
+
+
+def test_tile_chooser_counts_both_widths():
+    """q, k of 192 and v of 128 at the MLA cell's S: every kernel's tiles fit
+    the budget with 192 counted as the 256 lanes it fills, lie between the
+    choices for 128 and for 256 all round, and equal widths are counted as
+    they were before the two were told apart."""
+    s, itemsize = 8192, 2
+    mla = fa.choose_tiles(s, 192, jnp.bfloat16, True, 128)
+    narrow, wide = (fa.choose_tiles(s, d, jnp.bfloat16, True) for d in (128, 256))
+    for kernel, (bq, bk), lo, hi in zip(("fwd", "dkv", "dq"), mla, wide, narrow):
+        assert fa._vmem_bytes(kernel, bq, bk, 192, itemsize, 128) <= fa._VMEM_BUDGET
+        assert lo[0] * lo[1] <= bq * bk <= hi[0] * hi[1]
+        assert fa._vmem_bytes(kernel, bq, bk, 128, itemsize) \
+            < fa._vmem_bytes(kernel, bq, bk, 192, itemsize, 128) \
+            < fa._vmem_bytes(kernel, bq, bk, 256, itemsize)
+        assert fa._vmem_bytes(kernel, bq, bk, 128, itemsize, 128) \
+            == fa._vmem_bytes(kernel, bq, bk, 128, itemsize)
+    assert fa._other_width(128, 128) is None and fa._other_width(192, 128) == 128
+    seg = _segments("packed_pad", 2, s, seed=11)
+    assert fa.tile_census(seg, 192, jnp.bfloat16, v_head_dim=128)[0] \
+        == 2 * (s // mla.fwd[0]) * (s // mla.fwd[1])
 
 
 def test_host_census_counts_the_wrappers_table():

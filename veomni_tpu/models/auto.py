@@ -49,15 +49,15 @@ class ModelFamily:
         rules: Dict[str, tuple] = {}
         if getattr(cfg, "is_moe", False):
             # experts [L, E, in, out]: expert dim over ep, features over fsdp
-            rules[r"layers\.experts\..*"] = ("ep", "ep_fsdp", None)
-            rules[r"layers\.router$"] = ()
+            rules[r"(layers|mtp)\.experts\..*"] = ("ep", "ep_fsdp", None)
+            rules[r"(layers|mtp)\.router$"] = ()
         return ParallelPlan(rules=rules)
 
 
 for _mt in (
     "llama", "qwen2", "qwen3", "qwen3_moe",
     "gemma3", "gemma3_text",
-    "deepseek_v2", "deepseek_v3",
+    "deepseek_v2", "deepseek_v3", "joyai_llm_flash",
     "gpt_oss", "seed_oss", "glm_moe", "glm4_moe", "glm_moe_dsa",
 ):
     MODEL_REGISTRY.register(_mt, ModelFamily(model_type=_mt))
@@ -404,6 +404,9 @@ def build_config(model_type: str = "", **overrides):
         text = dict(overrides.pop("text", {}) or {})
         text.update(overrides)
         return VLMConfig(model_type=model_type, text=text, **vlm_kw)
+    if model_type in TransformerConfig._DEEPSEEK_V3_DIALECT:
+        for key, value in TransformerConfig.deepseek_defaults(model_type).items():
+            overrides.setdefault(key, value)
     return TransformerConfig(model_type=model_type or "llama", **overrides)
 
 

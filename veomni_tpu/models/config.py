@@ -89,6 +89,15 @@ class TransformerConfig:
     shared_expert_intermediate_size: int = 0
     shared_expert_gated: bool = False
     first_k_dense_replace: int = 0      # leading dense layers (deepseek)
+    # the chip's share of the routed experts (one expert-parallel rank's view
+    # on a single chip): the router stays ``num_experts`` wide, the layer holds
+    # and computes experts [first, first + held). 0 = all of them
+    moe_experts_held: int = 0
+    moe_experts_held_first: int = 0
+    # multi-token prediction (deepseek_v3): modules after the last layer, each
+    # one decoder layer predicting one token further; lambda of their loss
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # qwen3_next hybrid GatedDeltaNet (reference models/transformers/qwen3_5/,
     # ops/kernels/gated_delta_rule/): periodic linear-attention layers with a
     # full-attention layer every `full_attention_interval` layers
@@ -139,6 +148,11 @@ class TransformerConfig:
         return self.num_experts > 0
 
     @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this model holds."""
+        return self.moe_experts_held or self.num_experts
+
+    @property
     def use_mla(self) -> bool:
         return self.kv_lora_rank > 0
 
@@ -178,8 +192,21 @@ class TransformerConfig:
         "rope_local_base_freq q_lora_rank kv_lora_rank qk_nope_head_dim "
         "qk_rope_head_dim v_head_dim routed_scaling_factor n_group "
         "topk_group n_shared_experts first_k_dense_replace scoring_func "
-        "mlp_bias attention_bias partial_rotary_factor"
+        "mlp_bias attention_bias partial_rotary_factor "
+        "num_nextn_predict_layers"
     ).split()
+    # model types whose config keys are deepseek_v3's
+    _DEEPSEEK_V3_DIALECT = ("deepseek_v3", "joyai_llm_flash")
+
+    @staticmethod
+    def deepseek_defaults(model_type: str) -> Dict[str, Any]:
+        """What the deepseek dialects mean where their config.json has no key
+        (``from_hf_config``, and ``build_config`` for the v3 dialect): v3
+        routes on sigmoid scores + correction bias (noaux-tc), v2 on plain
+        softmax scores; both train bias-update balancing, not an aux loss term."""
+        return dict(
+            scoring_func="softmax" if model_type == "deepseek_v2" else "sigmoid",
+            norm_topk_prob=True, router_aux_loss_coef=0.0, rope_interleave=True)
 
     @classmethod
     def from_hf_config(cls, hf: Dict[str, Any], **overrides) -> "TransformerConfig":
@@ -225,16 +252,15 @@ class TransformerConfig:
             kw.update(attention_sinks=True, attention_bias=True, o_bias=True,
                       mlp_bias=True, hidden_act="gpt_oss_glu", router_bias=True,
                       num_experts=hf.get("num_local_experts", 0))
-        if mt in ("deepseek_v3", "deepseek_v2"):
-            # v3 routes on sigmoid scores + correction bias (noaux-tc); v2
-            # uses plain softmax scores with greedy / max-per-group topk
-            kw["scoring_func"] = hf.get(
-                "scoring_func", "softmax" if mt == "deepseek_v2" else "sigmoid"
-            )
-            kw["norm_topk_prob"] = hf.get("norm_topk_prob", True)
-            # deepseek trains bias-update (noaux-tc), not an aux loss term
-            kw["router_aux_loss_coef"] = hf.get("aux_loss_alpha", 0.0)
-            kw["rope_interleave"] = hf.get("rope_interleave", True)
+        for key in ("moe_experts_held", "moe_experts_held_first"):
+            if hf.get(key):
+                kw[key] = hf[key]
+        if mt in cls._DEEPSEEK_V3_DIALECT + ("deepseek_v2",):
+            d = cls.deepseek_defaults(mt)
+            kw["scoring_func"] = hf.get("scoring_func", d["scoring_func"])
+            kw["norm_topk_prob"] = hf.get("norm_topk_prob", d["norm_topk_prob"])
+            kw["router_aux_loss_coef"] = hf.get("aux_loss_alpha", d["router_aux_loss_coef"])
+            kw["rope_interleave"] = hf.get("rope_interleave", d["rope_interleave"])
         if mt == "seed_oss":
             kw["attention_bias"] = hf.get("attention_bias", True)
             kw["o_bias"] = hf.get("attention_out_bias", False)
@@ -314,6 +340,7 @@ class TransformerConfig:
     _HF_EXPERT_KEY = {
         "deepseek_v2": "n_routed_experts",
         "deepseek_v3": "n_routed_experts",
+        "joyai_llm_flash": "n_routed_experts",
         "gpt_oss": "num_local_experts",
         "mixtral": "num_local_experts",
     }
@@ -330,12 +357,16 @@ class TransformerConfig:
         hf["hidden_act"] = self._HF_ACT_SPELLING.get(self.hidden_act, self.hidden_act)
         if self.is_moe:
             hf[self._HF_EXPERT_KEY.get(self.model_type, "num_experts")] = self.num_experts
+        if self.experts_held != self.num_experts:
+            # not an HF key: a checkpoint of one chip's share says which
+            hf["moe_experts_held"] = self.moe_experts_held
+            hf["moe_experts_held_first"] = self.moe_experts_held_first
         if self.model_type in ("gemma3", "gemma3_text"):
             hf["hidden_activation"] = hf.pop("hidden_act")
             hf["query_pre_attn_scalar"] = self.query_pre_attn_scalar
             if self.final_logit_softcap:
                 hf["final_logit_softcapping"] = self.final_logit_softcap
-        if self.model_type in ("deepseek_v2", "deepseek_v3"):
+        if self.model_type in self._DEEPSEEK_V3_DIALECT + ("deepseek_v2",):
             hf["aux_loss_alpha"] = hf.pop("router_aux_loss_coef")
         if self.use_dsa:
             hf.update(

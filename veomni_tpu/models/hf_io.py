@@ -77,6 +77,17 @@ _EXPERT_MAP: List[Tuple[str, str]] = [
     ("experts.up_proj", "mlp.experts.{e}.up_proj.weight"),
     ("experts.down_proj", "mlp.experts.{e}.down_proj.weight"),
 ]
+# multi-token-prediction modules (deepseek_v3): ``model.layers.{L + d}.*`` holds
+# the module's decoder layer under the names above, these four, and copies of
+# the model's embedding and head (``embed_tokens``, ``shared_head.head``),
+# which we share with the main model and neither keep nor write
+_MTP_MAP: List[Tuple[str, str, bool]] = [
+    ("enorm", "enorm.weight", False),
+    ("hnorm", "hnorm.weight", False),
+    ("eh_proj", "eh_proj.weight", True),
+    ("norm", "shared_head.norm.weight", False),
+]
+_MTP_SHARED = ("embed_tokens.weight", "shared_head.head.weight")
 # gpt_oss stores experts as fused 3-D tensors (gate/up interleaved on the
 # last dim); handled explicitly in the load/save segment functions below
 # (reference counterpart: checkpoint_tensor_loading.py fused maps).
@@ -291,8 +302,9 @@ def hf_to_params(
         """[count, E, in, out] from per-expert HF [out, in] tensors — the
         EP-sliced read path: a callback for an ep-sharded target touches only
         its (layer, expert) block."""
-        e_total = cfg.num_experts
-        names = [[alias[f"layers.{offset + i}.{hf_tmpl.format(e=e)}"]
+        # the experts this model holds (all, or one chip's share of them)
+        e_total, e0 = cfg.experts_held, cfg.moe_experts_held_first
+        names = [[alias[f"layers.{offset + i}.{hf_tmpl.format(e=e0 + e)}"]
                   for e in range(e_total)] for i in range(count)]
         for row in names:
             for real in row:
@@ -417,6 +429,15 @@ def hf_to_params(
     if k_dense:
         params["dense_layers"] = load_segment("dense_layers", 0, k_dense, False)
     params["layers"] = load_segment("layers", k_dense, L - k_dense, True)
+    if cfg.num_nextn_predict_layers:
+        depth = cfg.num_nextn_predict_layers
+        params["mtp"] = load_segment("mtp", L, depth, True)
+        for ours, hf_suffix, transpose in _MTP_MAP:
+            params["mtp"][ours] = stacked(f"mtp.{ours}", hf_suffix, L, depth, transpose)
+        for d in range(depth):
+            for shared in _MTP_SHARED:
+                if has(f"layers.{L + d}.{shared}"):
+                    lazy.mark_consumed(alias[f"layers.{L + d}.{shared}"])
     if not cfg.tie_word_embeddings:
         if has("lm_head.weight"):
             params["lm_head"] = single("lm_head", "lm_head.weight", True)
@@ -525,14 +546,23 @@ def params_to_hf(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, np
                 for ours, hf_tmpl in _EXPERT_MAP:
                     b = ours.split(".")[1]
                     for i in range(count):
-                        for e in range(cfg.num_experts):
-                            out[f"model.layers.{offset + i}.{hf_tmpl.format(e=e)}"] = (
-                                ex[b][i, e].T
-                            )
+                        for e in range(cfg.experts_held):
+                            name = hf_tmpl.format(e=cfg.moe_experts_held_first + e)
+                            out[f"model.layers.{offset + i}.{name}"] = ex[b][i, e].T
 
     if k_dense:
         dump_segment(host["dense_layers"], 0, k_dense, False)
     dump_segment(host["layers"], k_dense, L - k_dense, True)
+    if "mtp" in host:
+        depth = cfg.num_nextn_predict_layers
+        # the module's decoder layer under the layer map's names, its own four
+        # leaves under theirs
+        own = {ours for ours, _, _ in _MTP_MAP}
+        dump_segment({k: v for k, v in host["mtp"].items() if k not in own}, L, depth, True)
+        for ours, hf_suffix, transpose in _MTP_MAP:
+            for d in range(depth):
+                x = host["mtp"][ours][d]
+                out[f"model.layers.{L + d}.{hf_suffix}"] = x.T if transpose else x
     return out
 
 

@@ -151,22 +151,24 @@ def _moe_params(keys, cfg: TransformerConfig, L: int, pd) -> Params:
     h = cfg.hidden_size
     s = cfg.initializer_range
     im = cfg.moe_intermediate_size or cfg.intermediate_size
-    e = cfg.num_experts
+    # the router is as wide as the model has experts; the expert tensors hold
+    # this chip's share of them (all, unless cfg.moe_experts_held says fewer)
+    e, held = cfg.num_experts, cfg.experts_held
     p: Params = {
         "router": _dense_init(next(keys), (L, h, e), pd, s),
         **({"router_bias": jnp.zeros((L, e), pd)} if cfg.router_bias else {}),
         "experts": {
-            "gate_proj": _dense_init(next(keys), (L, e, h, im), pd, s),
-            "up_proj": _dense_init(next(keys), (L, e, h, im), pd, s),
-            "down_proj": _dense_init(next(keys), (L, e, im, h), pd, s),
+            "gate_proj": _dense_init(next(keys), (L, held, h, im), pd, s),
+            "up_proj": _dense_init(next(keys), (L, held, h, im), pd, s),
+            "down_proj": _dense_init(next(keys), (L, held, im, h), pd, s),
         },
     }
     if cfg.scoring_func == "sigmoid":
         p["e_score_correction_bias"] = jnp.zeros((L, e), pd)
     if cfg.mlp_bias:
-        p["experts"]["gate_bias"] = jnp.zeros((L, e, im), pd)
-        p["experts"]["up_bias"] = jnp.zeros((L, e, im), pd)
-        p["experts"]["down_bias"] = jnp.zeros((L, e, h), pd)
+        p["experts"]["gate_bias"] = jnp.zeros((L, held, im), pd)
+        p["experts"]["up_bias"] = jnp.zeros((L, held, im), pd)
+        p["experts"]["down_bias"] = jnp.zeros((L, held, h), pd)
     if cfg.n_shared_experts or cfg.shared_expert_intermediate_size:
         si = cfg.shared_expert_intermediate_size or im * cfg.n_shared_experts
         p["shared_experts"] = {
@@ -180,12 +182,31 @@ def _moe_params(keys, cfg: TransformerConfig, L: int, pd) -> Params:
     return p
 
 
+def _mtp_params(keys, cfg: TransformerConfig, D: int, pd) -> Params:
+    """The multi-token-prediction modules, stacked over their depth like a
+    segment of layers: the two input norms, the projection of
+    [embedding ; hidden] back to hidden, one decoder layer of the model's last
+    kind, and the norm before the (shared) head. Checkpoint names:
+    ``model.layers.{L + d}.{enorm,hnorm,eh_proj,shared_head.norm,...}``."""
+    h = cfg.hidden_size
+    return {
+        "enorm": jnp.ones((D, h), pd),
+        "hnorm": jnp.ones((D, h), pd),
+        "eh_proj": _dense_init(next(keys), (D, 2 * h, h), pd, cfg.initializer_range),
+        **_attn_params(keys, cfg, D, pd),
+        **(_moe_params(keys, cfg, D, pd) if cfg.is_moe
+           else _dense_mlp_params(keys, cfg, D, pd)),
+        "norm": jnp.ones((D, h), pd),
+    }
+
+
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     """Random init with HF-compatible structure (stacked layer dim first).
 
     With ``first_k_dense_replace`` (deepseek), the leading dense layers live
     in a separate stacked subtree ``dense_layers`` so both segments scan
-    homogeneously.
+    homogeneously; with ``num_nextn_predict_layers`` the MTP modules are a
+    third stacked subtree, ``mtp``.
     """
     h = cfg.hidden_size
     pd = cfg.param_dtype
@@ -208,6 +229,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         **(_moe_params(keys, cfg, main_L, pd) if cfg.is_moe
            else _dense_mlp_params(keys, cfg, main_L, pd)),
     }
+    if cfg.num_nextn_predict_layers:
+        params["mtp"] = _mtp_params(keys, cfg, cfg.num_nextn_predict_layers, pd)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = _dense_init(
             next(keys), (h, cfg.vocab_size), pd, cfg.initializer_range
@@ -330,11 +353,33 @@ def _shared_experts_out(x, lp, cfg):
 ROUTER_CAPTURE: Optional[list] = None
 
 
-def _moe_mlp(x, lp, cfg: TransformerConfig):
+def held_rows(cfg: TransformerConfig, t: int) -> int:
+    """Rows of the expert-sorted buffer when the layer holds a share of the
+    experts: this rank's capacity, ``cfg.moe_capacity_factor`` times what an
+    even routing would send it (the factor's one meaning, here as in
+    ``parallel/moe.py``), in whole 128-row tiles (the grouped GEMM's); every
+    assignment (``t * k``) where the factor is <= 0 (dropless) or gives more."""
+    total = t * cfg.num_experts_per_tok
+    if not cfg.moe_capacity_factor or cfg.moe_capacity_factor <= 0:
+        return total
+    rows = math.ceil(cfg.moe_capacity_factor * total * cfg.experts_held / cfg.num_experts)
+    return min(-(-rows // 128) * 128, total)
+
+
+def moe_mlp_with_stats(x, lp, cfg: TransformerConfig):
     """Single-device MoE: route -> sort by expert -> grouped GEMM -> unsort.
-    x: [T, H]. (Reference eager MoE semantics per dialect.)"""
+    x: [T, H]. (Reference eager MoE semantics per dialect.)
+
+    Where the layer holds a share of the experts (``cfg.moe_experts_held``),
+    routing is over all ``num_experts`` and only the assignments to held
+    experts are gathered, sorted and multiplied: the layer gives its own
+    experts' part of the result (what expert parallelism asks of one rank,
+    without the exchange). Returns (out [T, H], aux, stats) with stats =
+    (dropped fraction of the held assignments, held assignments, the busiest
+    held expert's rows over the mean)."""
     t, h = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
+    n_held = cfg.experts_held
     with jax.named_scope("moe.route"):
         topk_idx, topk_w, aux = route_tokens(x, lp, cfg)
         if ROUTER_CAPTURE is not None:
@@ -343,20 +388,47 @@ def _moe_mlp(x, lp, cfg: TransformerConfig):
 
     with jax.named_scope("moe.dispatch"):
         flat_expert = topk_idx.reshape(-1)  # [T*K]
-        sort_idx = jnp.argsort(flat_expert)  # stable
+        if n_held == e:
+            sort_idx = jnp.argsort(flat_expert)  # stable
+            expert_of_row = flat_expert[sort_idx]
+            counts = group_sizes = jnp.bincount(flat_expert, length=e)
+            dropped = jnp.float32(0.0)
+        else:
+            local = flat_expert - cfg.moe_experts_held_first
+            mine = (local >= 0) & (local < n_held)
+            counts = jnp.bincount(jnp.where(mine, local, n_held), length=n_held + 1)[:n_held]
+            # the rank's capacity fills in the order the positions come (as
+            # parallel/moe.py's does): a held assignment past it is dropped
+            rows = held_rows(cfg, t)
+            kept = mine & (jnp.cumsum(mine) <= rows)
+            # what is not kept sorts last, and past the buffer
+            local = jnp.where(kept, local, n_held)
+            sort_idx = jnp.argsort(local)[:rows]  # stable
+            expert_of_row = local[sort_idx]
+            group_sizes = jnp.bincount(local, length=n_held + 1)[:n_held]
+            dropped = (counts.sum() - group_sizes.sum()).astype(jnp.float32)
         token_idx = sort_idx // k
-        xs = x[token_idx]  # [T*K, H] sorted by expert
-        group_sizes = jnp.bincount(flat_expert, length=e)
+        xs = x[token_idx]  # [rows, H] sorted by expert
+        held = counts.sum().astype(jnp.float32)
+        load = counts.max() * (counts.shape[0] / jnp.maximum(held, 1.0))
     with jax.named_scope("moe.experts"):
-        out = experts_apply_sorted(
-            xs, lp["experts"], group_sizes, flat_expert[sort_idx], cfg)
+        out = experts_apply_sorted(xs, lp["experts"], group_sizes, expert_of_row, cfg)
 
     with jax.named_scope("moe.combine"):
         weight = topk_w.reshape(-1)[sort_idx][:, None]
+        if n_held != e:
+            # rows past the last group belong to no held expert
+            keep = (jnp.arange(out.shape[0]) < group_sizes.sum())[:, None]
+            out, weight = jnp.where(keep, out, 0), jnp.where(keep, weight, 0)
         combined = jnp.zeros((t, h), out.dtype).at[token_idx].add(out * weight)
         if cfg.n_shared_experts or cfg.shared_expert_intermediate_size:
             combined = combined + _shared_experts_out(x, lp, cfg)
-    return combined, aux
+    return combined, aux, (dropped, held, load)
+
+
+def _moe_mlp(x, lp, cfg: TransformerConfig):
+    out, aux, _ = moe_mlp_with_stats(x, lp, cfg)
+    return out, aux
 
 
 def _activation_constraint():
@@ -419,6 +491,16 @@ def _standard_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, wi
     return out
 
 
+def mla_rope_interleaved(cfg: TransformerConfig) -> bool:
+    """Does MLA rotate adjacent pairs (x[2i], x[2i+1]) by the i-th frequency
+    (deepseek's ``rope_interleave``)? The tables then repeat every frequency
+    twice, side by side, which is the layout ``apply_rotary(interleaved=True)``
+    multiplies with. (With the half-rotation tables the two members of a pair
+    were turned by two different angles: nothing at seeded weights showed it
+    until a reference with the published rope was held against the gradients.)"""
+    return bool(cfg.use_mla and cfg.rope_interleave)
+
+
 def _dsa_bias(x, lp, cfg: TransformerConfig, cos, sin, segment_ids):
     """DSA lightning-indexer top-k KEEP mask [B,S,S] bool (glm_moe_dsa;
     reference ``GlmMoeDsaIndexer`` at ``glm_moe_dsa/generated/...:123``).
@@ -438,6 +520,9 @@ def _dsa_bias(x, lp, cfg: TransformerConfig, cos, sin, segment_ids):
         kf.var(-1, keepdims=True) + 1e-6
     )
     k = (kf * idx["k_norm_w"] + idx["k_norm_b"]).astype(x.dtype)
+    if mla_rope_interleaved(cfg):
+        # the indexer's half-rotation wants each frequency once per half
+        cos, sin = (jnp.concatenate([t[..., ::2], t[..., ::2]], axis=-1) for t in (cos, sin))
     q_pe, k_pe = ops.apply_rotary(
         q[..., :dr], k[..., :dr].reshape(b, s, 1, dr), cos, sin, interleaved=False
     )
@@ -573,7 +658,8 @@ def _decoder_layer(
     # last stage: "mlp" when dense, "moe.route" / "moe.combine" when sparse
     with jax.named_scope("moe.route" if is_moe else "mlp"):
         x = _norm(hidden, pre_norm, cfg)
-    dropped = jnp.float32(0.0)
+    # (dropped assignments, held assignments, busiest expert's rows over the mean)
+    moe_stats = (jnp.float32(0.0),) * 3
     if is_moe:
         from veomni_tpu.parallel.parallel_state import get_parallel_state_or_none
 
@@ -581,9 +667,16 @@ def _decoder_layer(
         if ps is not None and ps.ep_enabled:
             from veomni_tpu.parallel.moe import ep_moe_mlp
 
-            out, aux, dropped = ep_moe_mlp(x, lp, cfg, ps)
+            if cfg.experts_held != cfg.num_experts:
+                raise ValueError(
+                    "moe_experts_held describes one chip's share without the "
+                    "exchange; with expert_parallel_size > 1 the mesh holds "
+                    "every expert: leave it at 0")
+            out, aux, dropped, load = ep_moe_mlp(x, lp, cfg, ps, with_load=True)
+            assigned = jnp.float32(b * s * cfg.num_experts_per_tok)
+            moe_stats = (dropped * assigned, assigned, load)  # the mesh holds them all
         else:
-            out, aux = _moe_mlp(x.reshape(b * s, h), lp, cfg)
+            out, aux, moe_stats = moe_mlp_with_stats(x.reshape(b * s, h), lp, cfg)
             out = out.reshape(b, s, h)
     else:
 
@@ -618,9 +711,18 @@ def _decoder_layer(
         if cfg.sandwich_norms:
             out = _norm(out, lp["post_feedforward_layernorm"], cfg)
         hidden = constrain(hidden + out)
+    stats = jnp.stack([aux, *moe_stats]).astype(jnp.float32)
     if dsa_prev is not None:  # carry mode (configs with "shared" layers)
-        return hidden, (aux, dropped), dsa_bias
-    return hidden, (aux, dropped)
+        return hidden, stats, dsa_bias
+    return hidden, stats
+
+
+def _add_layer_stats(total, stats):
+    """Fold layers' ``[n, 4]`` stats (aux, dropped, held, load) into the
+    running ``[4]``: sums, and the largest load."""
+    with jax.named_scope("moe.route"):
+        return jnp.concatenate([total[:3] + stats[:, :3].sum(0),
+                                jnp.maximum(total[3:], stats[:, 3:].max(0))])
 
 
 def forward_hidden(
@@ -633,7 +735,31 @@ def forward_hidden(
     post_layer_residuals: Optional[jax.Array] = None,  # [K,B,S,H]
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (final_hidden [B,S,H] in cfg.dtype, moe_aux_loss scalar,
-    moe_dropped_frac scalar — mean EP capacity-drop fraction, 0 when dropless).
+    moe_dropped_frac scalar — the share of the held assignments a capacity
+    bound dropped, 0 when dropless). See :func:`forward_layers`."""
+    out = forward_layers(params, cfg, input_ids, position_ids, segment_ids,
+                         inputs_embeds, post_layer_residuals)
+    return out["hidden"], out["moe_aux"], out["moe_dropped_frac"]
+
+
+def forward_layers(
+    params: Params,
+    cfg: TransformerConfig,
+    input_ids: jax.Array,
+    position_ids: jax.Array,
+    segment_ids: Optional[jax.Array] = None,
+    inputs_embeds: Optional[jax.Array] = None,
+    post_layer_residuals: Optional[jax.Array] = None,
+    with_mtp: bool = False,
+) -> Dict[str, Any]:
+    """The decoder stack. Returns ``hidden`` (final normed [B,S,H] in
+    cfg.dtype), ``moe_aux``, ``moe_dropped_frac`` (dropped over held) and, for
+    a MoE model, ``moe_assignment_counts`` (a vector, so that the train step
+    SUMS it over micro-steps: token-expert assignments routed, those to
+    experts this model holds, and those of them a rank capacity dropped, summed
+    over the MoE layers), ``moe_load_max_over_mean`` (largest over the layers); with
+    ``with_mtp``, ``mtp_hidden``: one final normed hidden per multi-token-
+    prediction module (:func:`_mtp_hidden`), whose MoE layers count too.
 
     ``inputs_embeds`` lets composite models (VLM/omni) inject merged
     multimodal embeddings while sharing the decoder stack.
@@ -657,7 +783,8 @@ def forward_hidden(
         else int(cfg.head_dim * cfg.partial_rotary_factor)
     )
     cos_g, sin_g = ops.rotary_tables(
-        position_ids, rope_dim, cfg.rope_theta, rope_scaling=cfg.rope_scaling
+        position_ids, rope_dim, cfg.rope_theta, rope_scaling=cfg.rope_scaling,
+        interleaved=mla_rope_interleaved(cfg),
     )
     cos_g, sin_g = cos_g.astype(cfg.dtype), sin_g.astype(cfg.dtype)
     dual_rope = bool(cfg.rope_local_base_freq)
@@ -684,8 +811,7 @@ def forward_hidden(
             else:
                 runs.append([i, 1, *sig])
 
-        aux_total = jnp.float32(0.0)
-        drop_total = jnp.float32(0.0)
+        total = jnp.zeros((4,), jnp.float32)
         for start, n, window, local in runs:
             sub = (
                 layer_tree if n == count
@@ -707,22 +833,21 @@ def forward_hidden(
 
                 def scan_body(carry, xs_):
                     lp, fl = xs_
-                    h2, aux_drop, new_bias = body(carry[0], lp, carry[1], fl)
-                    return (h2, new_bias), aux_drop
+                    h2, stats, new_bias = body(carry[0], lp, carry[1], fl)
+                    return (h2, new_bias), stats
 
-                (hidden, dsa_carry), (auxes, drops) = jax.lax.scan(
+                (hidden, dsa_carry), stats = jax.lax.scan(
                     scan_body, (hidden, dsa_carry), (sub, flags)
                 )
             else:
-                hidden, (auxes, drops) = jax.lax.scan(
+                hidden, stats = jax.lax.scan(
                     lambda c, lp: body(c, lp), hidden, sub
                 )
-            aux_total = aux_total + auxes.sum()
-            drop_total = drop_total + drops.sum()
-        return hidden, aux_total, drop_total, dsa_carry
+            if is_moe_seg:  # a dense segment's are zeros: nothing to fold
+                total = _add_layer_stats(total, stats)
+        return hidden, total, dsa_carry
 
-    auxes_total = jnp.float32(0.0)
-    drops_total = jnp.float32(0.0)
+    stats_total = jnp.zeros((4,), jnp.float32)
     K_inject = 0 if post_layer_residuals is None else post_layer_residuals.shape[0]
     # DSA "shared" layers reuse the previous layer's selection; the [B,S,S]
     # carry (threaded across run/segment boundaries, zeros before the first
@@ -753,19 +878,85 @@ def forward_hidden(
                 tree if (start == 0 and n == count)
                 else jax.tree.map(lambda t: t[start:start + n], tree)
             )
-            hidden, auxes, drops, dsa_carry = run_segment(
+            hidden, stats, dsa_carry = run_segment(
                 hidden, sub, g, n, is_moe_seg, dsa_carry
             )
-            auxes_total = auxes_total + auxes
-            drops_total = drops_total + drops
+            if is_moe_seg:
+                stats_total = _add_layer_stats(stats_total, stats[None])
             if g < K_inject:
                 hidden = hidden + post_layer_residuals[g].astype(hidden.dtype)
             start += n
+    n_moe = (L - k_dense) if cfg.is_moe else 0
+    out: Dict[str, Any] = {}
+    if with_mtp and cfg.num_nextn_predict_layers:
+        if inputs_embeds is not None:
+            raise NotImplementedError("multi-token prediction over inputs_embeds")
+        with jax.named_scope("mtp"):
+            out["mtp_hidden"], stats = _mtp_hidden(
+                compute, cfg, hidden, input_ids, cos_g, sin_g, segment_ids)
+        if cfg.is_moe:
+            stats_total = _add_layer_stats(stats_total, stats)
+            n_moe += cfg.num_nextn_predict_layers
     with jax.named_scope("lm_head_loss"):
         hidden = _norm(hidden, compute["norm"], cfg)
-    # mean dropped-assignment fraction over the MoE layers (diagnostic)
-    n_moe = (L - k_dense) if cfg.is_moe else 0
-    return hidden, auxes_total, drops_total / max(n_moe, 1)
+    out.update(
+        hidden=hidden, moe_aux=stats_total[0],
+        # dropped over held assignments, all MoE layers together (diagnostic)
+        moe_dropped_frac=stats_total[1] / jnp.maximum(stats_total[2], 1.0),
+    )
+    if cfg.is_moe:
+        routed = n_moe * hidden.shape[0] * hidden.shape[1] * cfg.num_experts_per_tok
+        with jax.named_scope("moe.route"):
+            out["moe_assignment_counts"] = jnp.stack(
+                [jnp.float32(routed), stats_total[2], stats_total[1]])
+        out["moe_load_max_over_mean"] = stats_total[3]
+    return out
+
+
+def _shift_left(x, n: int, fill):
+    """``x [B, S]`` moved ``n`` positions towards the row's start."""
+    return jnp.concatenate([x[:, n:], jnp.full_like(x[:, :n], fill)], axis=1)
+
+
+def _mtp_hidden(compute: Params, cfg: TransformerConfig, hidden, input_ids, cos, sin,
+                segment_ids):
+    """DeepSeek-V3's multi-token prediction, module after module: at depth
+    ``d`` position ``i`` joins the embedding of token ``i + d`` with the
+    representation below it, ``h' = eh_proj [enorm(emb) ; hnorm(h)]`` (the
+    order of the released checkpoints' ``eh_proj``), runs one decoder layer
+    over the row (the row's own segment ids and rope) and norms the result for
+    the model's head. ``hidden`` is the last layer's output before the final
+    norm. Returns ([one normed hidden per module], the layers' stats [D, 4])."""
+    mp = compute["mtp"]
+    body = partial(_decoder_layer, cfg=cfg, cos=cos, sin=sin, segment_ids=segment_ids,
+                   window=None, is_moe_segment=cfg.is_moe)
+    if cfg.remat:
+        body = jax.checkpoint(body, policy=_remat_policy(cfg))
+    normed, stats = [], []
+    for d in range(cfg.num_nextn_predict_layers):
+        lp = jax.tree.map(lambda t: t[d], mp)
+        emb = compute["embed_tokens"][_shift_left(input_ids, d + 1, 0)]
+        if cfg.embed_scale:
+            emb = emb * jnp.asarray(cfg.embed_scale, cfg.dtype)
+        joined = jnp.concatenate(
+            [_norm(emb, lp["enorm"], cfg), _norm(hidden, lp["hnorm"], cfg)], axis=-1)
+        hidden, st = body(jnp.dot(joined, lp["eh_proj"]), lp)
+        normed.append(_norm(hidden, lp["norm"], cfg))
+        stats.append(st)
+    return normed, jnp.stack(stats)
+
+
+def mtp_labels(labels, segment_ids, depth: int):
+    """Labels of the MTP module at ``depth`` (from 1): position ``i`` predicts
+    token ``i + depth + 1``, which ``labels`` (pre-shifted: ``labels[i]`` is
+    token ``i + 1``) holds at ``i + depth``. In a packed row it counts only
+    where that token lies in ``i``'s own document (then every token between
+    does too)."""
+    out = _shift_left(labels, depth, -100)
+    if segment_ids is not None:
+        same = _shift_left(segment_ids, depth + 1, 0) == segment_ids
+        out = jnp.where(same & (segment_ids > 0), out, -100)
+    return out
 
 
 def lm_head_kernel(params: Params, cfg: TransformerConfig):
@@ -803,18 +994,24 @@ def sequence_logprob_sums(
     return -nll
 
 
+def _head_ce(params: Params, cfg: TransformerConfig, hidden, labels):
+    """(sum of the next-token NLL, count of predicting positions) of normed
+    ``hidden [B,S,H]`` through the model's head."""
+    b, s, h = hidden.shape
+    with jax.named_scope("lm_head_loss"):
+        kernel = lm_head_kernel(params, cfg).astype(cfg.dtype)
+        return ops.fused_linear_cross_entropy(
+            hidden.reshape(b * s, h), kernel, labels.reshape(b * s),
+            logit_softcap=cfg.final_logit_softcap or None,
+        )
+
+
 def head_loss(
     params: Params, cfg: TransformerConfig, hidden: jax.Array, labels: jax.Array,
     moe_aux: jax.Array, moe_dropped: jax.Array = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """lm-head + CE in token-sum space, shared by text/VLM/omni loss fns."""
-    b, s, h = hidden.shape
-    with jax.named_scope("lm_head_loss"):
-        kernel = lm_head_kernel(params, cfg).astype(cfg.dtype)
-        loss_sum, ntokens = ops.fused_linear_cross_entropy(
-            hidden.reshape(b * s, h), kernel, labels.reshape(b * s),
-            logit_softcap=cfg.final_logit_softcap or None,
-        )
+    loss_sum, ntokens = _head_ce(params, cfg, hidden, labels)
     metrics = {"loss_sum": loss_sum, "ntokens": ntokens, "moe_aux_loss": moe_aux}
     if moe_dropped is not None:
         metrics["moe_dropped_frac"] = moe_dropped
@@ -836,7 +1033,26 @@ def loss_fn(
     batch: input_ids/position_ids/segment_ids [B,S], labels [B,S] pre-shifted
     with -100 padding (collator contract, reference data_collator.py:371-428).
     """
-    hidden, moe_aux, moe_dropped = forward_hidden(
-        params, cfg, batch["input_ids"], batch["position_ids"], batch.get("segment_ids")
+    out = forward_layers(
+        params, cfg, batch["input_ids"], batch["position_ids"], batch.get("segment_ids"),
+        with_mtp=True,
     )
-    return head_loss(params, cfg, hidden, batch["labels"], moe_aux, moe_dropped)
+    total, metrics = head_loss(
+        params, cfg, out["hidden"], batch["labels"], out["moe_aux"], out["moe_dropped_frac"])
+    if cfg.is_moe:
+        # read by observability/callback.py (moe.assignments* counters, the gauge)
+        metrics.update({k: out[k] for k in ("moe_assignment_counts", "moe_load_max_over_mean")})
+    if "mtp_hidden" in out:
+        # L = L_main + lambda * mean over depths of L_mtp (DeepSeek-V3, eq. 25),
+        # each a mean over its own valid positions; in the step's token-sum
+        # space that mean is scaled by the main loss's token count
+        mtp_loss = jnp.float32(0.0)
+        with jax.named_scope("mtp"):
+            for d, hidden in enumerate(out["mtp_hidden"]):
+                labels = mtp_labels(batch["labels"], batch.get("segment_ids"), d + 1)
+                loss_d, n_d = _head_ce(params, cfg, hidden, labels)
+                mtp_loss = mtp_loss + loss_d / jnp.maximum(n_d, 1)
+        mtp_loss = mtp_loss / len(out["mtp_hidden"])
+        metrics["mtp_loss"] = mtp_loss
+        total = total + cfg.mtp_loss_weight * mtp_loss * metrics["ntokens"]
+    return total, metrics

@@ -50,6 +50,28 @@ class ObservabilityCallback(Callback):
         self._win_steps = 0
         self._win_max_step_s = 0.0
         self._fleet_warm = False
+        # MoE routing counts of the steps since the last sync, still on the
+        # device: (the step's summed [assignments, held, dropped], load)
+        self._moe_pending = []
+
+    def _flush_moe(self):
+        """``moe.assignments`` / ``moe.assignments_held`` /
+        ``moe.assignments_dropped`` / ``moe.load_max_over_mean`` from the queued
+        steps' metrics (the loss function's ``moe_assignment_counts``, which
+        the step sums over its micro-steps, and ``moe_load_max_over_mean``).
+        Called where the loop has synced anyway (a sync step, the end of
+        train), so the fetch waits for nothing."""
+        if not self._moe_pending or self.registry is None:
+            return
+        import jax
+
+        pending, self._moe_pending = self._moe_pending, []
+        counters = [self.registry.counter(f"moe.{name}")
+                    for name in ("assignments", "assignments_held", "assignments_dropped")]
+        for counts, load in jax.device_get(pending):
+            for counter, value in zip(counters, counts):
+                counter.inc(float(value))
+        self.registry.gauge("moe.load_max_over_mean").set(float(load))
 
     def on_train_begin(self, trainer, state):
         t = trainer.args.train
@@ -122,6 +144,11 @@ class ObservabilityCallback(Callback):
             self._armed = True
         else:
             self.detector.check()
+        if "moe_assignment_counts" in state.metrics:
+            self._moe_pending.append(
+                (state.metrics["moe_assignment_counts"], state.metrics["moe_load_max_over_mean"]))
+            if state.synced:
+                self._flush_moe()
         if not state.synced:
             return
         state.metrics.update(self.tracker.end_window())
@@ -154,6 +181,15 @@ class ObservabilityCallback(Callback):
     def on_train_end(self, trainer, state):
         if self.registry is None:  # train() without on_train_begin (tests)
             return
+        self._flush_moe()
+        routed = self.registry.get("moe.assignments")
+        if routed is not None and routed.value:
+            logger.info_rank0(
+                "moe routing over the run: %d assignments, %d to held experts, %d of "
+                "those dropped, load max/mean of the last step %.2f", routed.value,
+                self.registry.counter("moe.assignments_held").value,
+                self.registry.counter("moe.assignments_dropped").value,
+                self.registry.gauge("moe.load_max_over_mean").value)
         if self.tracker is not None:
             state.metrics.update(self.tracker.end_window())
         if self.cost_window is not None:

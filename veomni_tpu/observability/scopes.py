@@ -37,6 +37,14 @@ SERVE_SCOPES = (
     "sampler",       # temperature / top-k / top-p / greedy token choice
 )
 SCOPES = TRAIN_SCOPES + SERVE_SCOPES
+# Scopes that cut ACROSS the taxonomy: a module that runs the same stages
+# again (its layer's ``attn.*`` and ``moe.*`` scopes lie inside it, and an
+# instruction keeps its innermost taxonomy scope). A reader sums the
+# instructions whose path holds the name, as ``recompute`` is read.
+MODULE_SCOPES = (
+    "mtp",           # the multi-token-prediction modules: embedding of the next
+                     # token, the joining projection, one decoder layer, head loss
+)
 
 KERNEL_NAMES = (
     "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",   # ops/pallas/flash_attention.py

@@ -20,6 +20,10 @@ external flash-attn CUDA wheels, varlen via cu_seqlens). TPU-native design:
   the block the last live step named, so the pipeline copies nothing for it.
 * GQA: the kv BlockSpec index-maps q-head -> q_head // group, so no
   materialized head repeat; dK/dV come out per q head and XLA sums the group.
+* q and k may be wider than v (MLA's training form: 128 nope + 64 rope
+  against 128): q, k, dQ, dK blocks are ``d`` wide, v, o, dO, dV blocks ``dv``
+  wide. Where the two are equal every block, scratch and limit is what it was
+  before the widths were told apart.
 
 Numerics: scores/softmax in f32 (MXU preferred_element_type), output cast
 back to the input dtype.
@@ -70,30 +74,42 @@ class Tiles(NamedTuple):
     dq: Tuple[int, int]
 
 
-def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, itemsize: int) -> int:
+def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, itemsize: int,
+                dv: Optional[int] = None) -> int:
     """VMEM a kernel needs at these tiles: its blocks twice (the pipeline
-    double-buffers them), its scratch, and its score-sized f32 temporaries."""
+    double-buffers them), its scratch, and its score-sized f32 temporaries.
+    ``d`` is the width of q and k, ``dv`` that of v and o. Where they differ
+    (``dv`` given) a block's last dim counts as whole 128-lane tiles: 192 is
+    256 in VMEM. (Equal widths count as they always did, so that no existing
+    call's tiles or limit move.)"""
     col = lambda n: n * _LANES * 4           # an [n, 1] or [n, _ROWS] block pads to 128 lanes
     row = lambda n: 8 * n * 4                # a [1, n] block pads to 8 sublanes
-    q_side, kv_side = bq * d * itemsize, bk * d * itemsize
+    if dv is None:
+        dv = d
+    else:
+        d, dv = (-(-w // _LANES) * _LANES for w in (d, dv))
+    # q (or dQ), k (or dK) blocks; v (or dV), o (or dO) blocks
+    q_blk, k_blk, o_blk, v_blk = bq * d * itemsize, bk * d * itemsize, bq * dv * itemsize, \
+        bk * dv * itemsize
     if kernel == "fwd":
-        blocks = 2 * q_side + 2 * kv_side + 2 * col(bq) + row(bk)
-        scratch = 2 * col(bq) + bq * d * 4
+        blocks = q_blk + o_blk + k_blk + v_blk + 2 * col(bq) + row(bk)
+        scratch = 2 * col(bq) + bq * dv * 4
         scores = 4 * bq * bk * 4
     elif kernel == "dq":
-        blocks = 3 * q_side + 2 * kv_side + 3 * col(bq) + row(bk)
+        blocks = 2 * q_blk + o_blk + k_blk + v_blk + 3 * col(bq) + row(bk)
         scratch = bq * d * 4
         scores = 6 * bq * bk * 4
     else:  # dkv
-        blocks = 2 * q_side + 2 * kv_side + 2 * bk * d * 4 + 3 * row(bq) + col(bk)
-        scratch = 2 * bk * d * 4
+        blocks = q_blk + o_blk + k_blk + v_blk + bk * (d + dv) * 4 + 3 * row(bq) + col(bk)
+        scratch = bk * (d + dv) * 4
         scores = 6 * bq * bk * 4
     return 2 * blocks + scratch + scores
 
 
 @functools.lru_cache(maxsize=None)
-def choose_tiles(s: int, d: int, dtype, causal: bool) -> Tiles:
-    """Tile sizes for a call of sequence length ``s`` and head dim ``d``:
+def choose_tiles(s: int, d: int, dtype, causal: bool, dv: Optional[int] = None) -> Tiles:
+    """Tile sizes for a call of sequence length ``s`` and head dims ``d`` (q,
+    k) and ``dv`` (v; ``d`` where not given):
     per kernel the pair of largest area among {1024, 512, 256, 128}^2 that
     divides ``s`` and fits the VMEM budget (the backward's kernels hold more
     score-sized temporaries than the forward, so theirs come out smaller); of
@@ -107,7 +123,7 @@ def choose_tiles(s: int, d: int, dtype, causal: bool) -> Tiles:
 
     def best(kernel):
         fits = [(bq, bk) for bq in sizes for bk in sizes
-                if _vmem_bytes(kernel, bq, bk, d, itemsize) <= _VMEM_BUDGET]
+                if _vmem_bytes(kernel, bq, bk, d, itemsize, dv) <= _VMEM_BUDGET]
         return max(fits or [(sizes[-1], sizes[-1])], key=lambda t: (t[0] * t[1], t[1]))
 
     return Tiles(fwd=best("fwd"), dkv=best("dkv"), dq=best("dq"))
@@ -144,7 +160,8 @@ def tile_liveness(segment_ids, s: int, bq: int, bk: int, causal: bool):
     return live
 
 
-def tile_census(segment_ids, head_dim: int, dtype, causal: bool = True) -> Tuple[int, int]:
+def tile_census(segment_ids, head_dim: int, dtype, causal: bool = True,
+                v_head_dim: Optional[int] = None) -> Tuple[int, int]:
     """(tile pairs, live tile pairs) of the forward kernel over a host batch's
     rows ``[..., S]``, by the functions the kernel wrapper itself calls: what
     the trainer loop counts into ``attn.flash.tile_pairs[_live]``. (0, 0)
@@ -153,7 +170,7 @@ def tile_census(segment_ids, head_dim: int, dtype, causal: bool = True) -> Tuple
     s = seg.shape[-1]
     if s % _LANES:
         return 0, 0
-    bq, bk = choose_tiles(s, head_dim, dtype, causal).fwd
+    bq, bk = choose_tiles(s, head_dim, dtype, causal, v_head_dim).fwd
     live = tile_liveness(seg.reshape(-1, s), s, bq, bk, causal)
     return int(live.size), int(live.sum())
 
@@ -212,12 +229,15 @@ def _schedule(segment_ids, s: int, bq: int, bk: int, causal: bool, q_outer: bool
     return _fetch_table(live if q_outer else jnp.swapaxes(live, 1, 2)), where
 
 
-def _block_specs(bq: int, bk: int, d: int, group: int, segmented: bool, q_outer: bool, where):
+def _block_specs(bq: int, bk: int, d: int, group: int, segmented: bool, q_outer: bool, where,
+                 dv: Optional[int] = None):
     """BlockSpecs over a grid (batch, q head, outer tile, inner tile): q-side
-    blocks [bq, d], kv-side blocks [bk, d], the q-side row stats as columns
+    blocks [bq, d] (``q``) and [bq, dv] (``o``), kv-side blocks [bk, d]
+    (``kv``) and [bk, dv] (``v``), the q-side row stats as columns
     [bq, _ROWS] and as rows [1, bq], and the two segment-id blocks (the ids
     along the score block's rows as a column, those along its columns as a
     row). The inner tile is read through the table."""
+    dv = dv or d
 
     def tiles(bi, outer, inner, tbl):  # (q tile, kv tile) a grid step names
         named = _inner_block(tbl, bi, outer, inner, **where)
@@ -235,7 +255,9 @@ def _block_specs(bq: int, bk: int, d: int, group: int, segmented: bool, q_outer:
                      spec((None, 1, bq), lambda bi, hi, tq, tk: (bi, 0, tq))]
     return dict(
         q=spec((1, 1, bq, d), lambda bi, hi, tq, tk: (bi, hi, tq, 0)),
+        o=spec((1, 1, bq, dv), lambda bi, hi, tq, tk: (bi, hi, tq, 0)),
         kv=spec((1, 1, bk, d), lambda bi, hi, tq, tk: (bi, hi // group, tk, 0)),
+        v=spec((1, 1, bk, dv), lambda bi, hi, tq, tk: (bi, hi // group, tk, 0)),
         q_cols=spec((1, 1, bq, _ROWS), lambda bi, hi, tq, tk: (bi, hi, tq, 0)),
         q_rows=spec((1, 1, 1, bq), lambda bi, hi, tq, tk: (bi, hi, 0, tq)),
         segs=seg_specs,
@@ -258,8 +280,13 @@ def _admitted(seg_col, seg_row, q0, k0, shape, causal: bool, q_on_rows: bool):
     return mask
 
 
-def _compiler_params(kernel: str, bq: int, bk: int, d: int, dtype):
-    need = _vmem_bytes(kernel, bq, bk, d, jnp.dtype(dtype).itemsize)
+def _other_width(d: int, dv: int) -> Optional[int]:
+    """v's width as the VMEM count takes it: None where it is q's and k's."""
+    return None if dv == d else dv
+
+
+def _compiler_params(kernel: str, bq: int, bk: int, d: int, dtype, dv: Optional[int] = None):
+    need = _vmem_bytes(kernel, bq, bk, d, jnp.dtype(dtype).itemsize, dv)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         # twice the counted footprint: the compiler's own temporaries are not
@@ -328,29 +355,30 @@ def _prefetch(tbl):
 
 def _fwd(q, k, v, segment_ids, scale, causal, tiles):
     b, hq, s, d = q.shape
+    dv = v.shape[-1]
     bq, bk = tiles.fwd
     segmented = segment_ids is not None
     tbl, where = _schedule(segment_ids, s, bq, bk, causal, True)
-    specs = _block_specs(bq, bk, d, hq // k.shape[1], segmented, True, where)
+    specs = _block_specs(bq, bk, d, hq // k.shape[1], segmented, True, where, dv)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                           table=tbl is not None, segmented=segmented, where=where),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(tbl is not None),
             grid=(b, hq, s // bq, s // bk),
-            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["kv"]],
-            out_specs=[specs["q"], specs["q_cols"]],
+            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["v"]],
+            out_specs=[specs["o"], specs["q_cols"]],
             scratch_shapes=[
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, hq, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, s, _ROWS), jnp.float32),
         ],
-        compiler_params=_compiler_params("fwd", bq, bk, d, q.dtype),
+        compiler_params=_compiler_params("fwd", bq, bk, d, q.dtype, _other_width(d, dv)),
         interpret=_interpret(),
         name="flash_fwd",  # observability/scopes.py::KERNEL_NAMES
     )(*_prefetch(tbl), *_seg_forms(segment_ids), q, k, v)
@@ -447,6 +475,7 @@ def _bwd(scale, causal, tiles, residuals, g):
     q, k, v, segment_ids, out, lse = residuals
     do = g[0] if isinstance(g, (tuple, list)) else g
     b, hq, s, d = q.shape
+    dv_ = v.shape[-1]
     hkv = k.shape[1]
     group = hq // hkv
     segmented = segment_ids is not None
@@ -460,52 +489,53 @@ def _bwd(scale, causal, tiles, residuals, g):
     # ---- dK, dV: grid (b, h, kv tile, q tile)
     bq, bk = tiles.dkv
     tbl, where = _schedule(segment_ids, s, bq, bk, causal, False)
-    specs = _block_specs(bq, bk, d, group, segmented, False, where)
-    dkv_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))
+    specs = _block_specs(bq, bk, d, group, segmented, False, where, dv_)
+    dk_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))
+    dv_spec = pl.BlockSpec((1, 1, bk, dv_), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))
     dk_per_head, dv_per_head = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                           table=tbl is not None, segmented=segmented, where=where),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(tbl is not None),
             grid=(b, hq, s // bk, s // bq),
-            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["kv"], specs["q"],
+            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["v"], specs["o"],
                       specs["q_rows"], specs["q_rows"]],
-            out_specs=[dkv_spec, dkv_spec],
+            out_specs=[dk_spec, dv_spec],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dv_), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, s, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, s, dv_), jnp.float32),
         ],
-        compiler_params=_compiler_params("dkv", bq, bk, d, q.dtype),
+        compiler_params=_compiler_params("dkv", bq, bk, d, q.dtype, _other_width(d, dv_)),
         interpret=_interpret(),
         name="flash_bwd_dkv",
     )(*_prefetch(tbl), *segs, q, k, v, do, lse_rows, delta_rows)
 
     # GQA: fold the q-head group into the kv head grad
     dk = dk_per_head.reshape(b, hkv, group, s, d).sum(axis=2).astype(k.dtype)
-    dv = dv_per_head.reshape(b, hkv, group, s, d).sum(axis=2).astype(v.dtype)
+    dv = dv_per_head.reshape(b, hkv, group, s, dv_).sum(axis=2).astype(v.dtype)
 
     # ---- dQ: grid (b, h, q tile, kv tile)
     bq, bk = tiles.dq
     tbl, where = _schedule(segment_ids, s, bq, bk, causal, True)
-    specs = _block_specs(bq, bk, d, group, segmented, True, where)
+    specs = _block_specs(bq, bk, d, group, segmented, True, where, dv_)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                           table=tbl is not None, segmented=segmented, where=where),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(tbl is not None),
             grid=(b, hq, s // bq, s // bk),
-            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["kv"], specs["q"],
+            in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["v"], specs["o"],
                       specs["q_cols"], specs["q_cols"]],
             out_specs=specs["q"],
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-        compiler_params=_compiler_params("dq", bq, bk, d, q.dtype),
+        compiler_params=_compiler_params("dq", bq, bk, d, q.dtype, _other_width(d, dv_)),
         interpret=_interpret(),
         name="flash_bwd_dq",
     )(*_prefetch(tbl), *segs, q, k, v, do, lse, delta_cols)
@@ -543,8 +573,6 @@ def _handoff_reason(q, k, v, sliding_window, sinks, pstate) -> Optional[str]:
         return "sliding_window"
     if sinks is not None:
         return "sinks"
-    if v.shape[-1] != d:
-        return "v head_dim != qk head_dim"
     if k.shape[1] != s:
         return "Sq != Sk"
     # lane-aligned tiles that tile the sequence exactly
@@ -571,7 +599,7 @@ def flash_attention(
     sinks: Optional[jax.Array] = None,
 ):
     """[B, S, H, D] facade-layout wrapper. Shapes/features the kernel
-    doesn't cover (sliding window, sinks, MLA's asymmetric v-dim, cross
+    doesn't cover (sliding window, sinks, cross
     attention, tiny/ragged S) go to the XLA impl, with one log line naming
     the reason. GSPMD cannot partition a Mosaic kernel, so under it on a
     multi-device mesh the kernel runs in a shard_map over the batch (dp)
@@ -595,7 +623,7 @@ def flash_attention(
             sinks=sinks,
         )
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    tiles = choose_tiles(s, d, q.dtype, causal)
+    tiles = choose_tiles(s, d, q.dtype, causal, _other_width(d, v.shape[-1]))
 
     def kernel(q, k, v, *seg):
         out = _flash_bhsd(

@@ -104,12 +104,13 @@ def _dispatch_combine(x2d, topk_idx, topk_probs, experts_local, *, cfg,
     return combined, dropped_frac
 
 
-def ep_moe_mlp(x, lp, cfg, pstate: ParallelState):
+def ep_moe_mlp(x, lp, cfg, pstate: ParallelState, with_load: bool = False):
     """Expert-parallel MoE layer forward. x [B, S, H] globally sharded
     (dp, sp, -); returns ([B, S, H], aux_loss, dropped_frac) where
     dropped_frac is the mesh-mean fraction of (token, expert) assignments
     discarded by the capacity bound (0 in dropless mode) — the observability
-    counterpart of the reference's dropless variable-split a2a."""
+    counterpart of the reference's dropless variable-split a2a. ``with_load``
+    appends the busiest expert's assignments over the mean."""
     b, s, h = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     ep = pstate.ep_size
@@ -166,4 +167,7 @@ def ep_moe_mlp(x, lp, cfg, pstate: ParallelState):
 
         with jax.named_scope("moe.combine"):
             out = out + _shared_experts_out(x, lp, cfg)
+    if with_load:
+        counts = jnp.bincount(topk_idx.reshape(-1), length=e)
+        return out, aux, dropped, counts.max() * (e / (b * s * k))
     return out, aux, dropped

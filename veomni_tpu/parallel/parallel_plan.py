@@ -95,7 +95,7 @@ class ParallelPlan:
 
     rules: Dict[str, SpecTemplate] = field(default_factory=dict)
     default_fsdp: bool = True
-    stacked_layer_prefixes: Tuple = ("layers", "dense_layers")
+    stacked_layer_prefixes: Tuple = ("layers", "dense_layers", "mtp")
 
     def _default_spec(self, shape, state: ParallelState) -> SpecTemplate:
         if not self.default_fsdp or not shape:

@@ -233,7 +233,11 @@ class BaseTrainer:
                 or not isinstance(cfg, TransformerConfig)
                 or self.parallel_state.cp_size > 1):
             return None
-        head_dim, dtype = cfg.head_dim, cfg.dtype
+        # MLA's q and k are qk_head_dim wide and its v v_head_dim: the
+        # config's head_dim is not a width of its attention
+        head_dim, v_dim = (
+            (cfg.qk_head_dim, cfg.v_head_dim) if cfg.use_mla else (cfg.head_dim, None))
+        dtype = cfg.dtype
         reg = get_registry()
         pairs, live = reg.counter("attn.flash.tile_pairs"), reg.counter("attn.flash.tile_pairs_live")
         share = reg.gauge("attn.flash.tiles_live_share")
@@ -242,7 +246,7 @@ class BaseTrainer:
             seg = batch_np.get("segment_ids")
             if seg is None:
                 return
-            n, n_live = tile_census(seg, head_dim, dtype)
+            n, n_live = tile_census(seg, head_dim, dtype, v_head_dim=v_dim)
             if n:
                 pairs.inc(n)
                 live.inc(n_live)

@@ -7,7 +7,10 @@ per-architecture formulas used by the MFU meter. Implemented terms:
   qwen3_next gated-attention q_proj doubling;
 * MLA (deepseek q/kv low-rank compression — NOT approximated as plain
   ``nh * head_dim`` projections);
-* MoE (top-k routed + shared experts + router);
+* MoE (top-k routed + shared experts + router; leading dense layers; where a
+  chip holds a share of the experts, that share of the routed term);
+* multi-token prediction (one layer, the [2H, H] projection and the head
+  again per module);
 * qwen3_next GatedDeltaNet linear-attention layers (chunkwise cost model);
 * ViT towers (per-patch, window or full attention) and DiT blocks via the
   dedicated helpers, fed to the meter as ``extra_flops``.
@@ -39,6 +42,12 @@ class FlopsCounter:
     moe_intermediate_size: int = 0
     num_shared_experts: int = 0
     shared_expert_intermediate_size: int = 0
+    first_k_dense_replace: int = 0   # leading layers with the dense MLP
+    # routed experts this chip holds (0 => all): the routed term counts the
+    # share of a token's top-k that an even routing sends to held experts
+    num_experts_held: int = 0
+    # multi-token-prediction modules: one more layer, projection and head each
+    num_nextn_predict_layers: int = 0
     tie_word_embeddings: bool = False
     # MLA (deepseek); kv_lora_rank > 0 switches the attention-projection term
     q_lora_rank: int = 0
@@ -86,11 +95,12 @@ class FlopsCounter:
             return self.num_heads * per_head
         return 2 * 2 * self.num_heads * self.head_dim * (seq_len / 2)
 
-    def _mlp_flops(self) -> float:
+    def _mlp_flops(self, dense: bool = False) -> float:
         h = self.hidden_size
-        if self.num_experts and self.num_experts_per_tok:
+        if self.num_experts and self.num_experts_per_tok and not dense:
             inter = self.moe_intermediate_size or self.intermediate_size
-            mlp = 2 * 3 * h * inter * self.num_experts_per_tok
+            held = (self.num_experts_held or self.num_experts) / self.num_experts
+            mlp = 2 * 3 * h * inter * self.num_experts_per_tok * held
             shared = self.shared_expert_intermediate_size or (
                 inter * self.num_shared_experts
             )
@@ -130,6 +140,12 @@ class FlopsCounter:
         else:
             body = self.num_layers * full_layer
         lm_head = 2 * self.hidden_size * self.vocab_size
+        if self.num_experts and self.first_k_dense_replace:
+            body += min(self.first_k_dense_replace, self.num_layers) * (
+                self._mlp_flops(dense=True) - mlp)
+        if self.num_nextn_predict_layers:
+            h = self.hidden_size
+            body += self.num_nextn_predict_layers * (full_layer + 2 * 2 * h * h + lm_head)
         return body + lm_head
 
     def batch_flops(self, total_tokens: int, seq_len: int, include_backward: bool = True) -> float:
@@ -158,6 +174,9 @@ class FlopsCounter:
             moe_intermediate_size=g("moe_intermediate_size", 0),
             num_shared_experts=g("n_shared_experts", 0),
             shared_expert_intermediate_size=g("shared_expert_intermediate_size", 0),
+            first_k_dense_replace=g("first_k_dense_replace", 0),
+            num_experts_held=g("moe_experts_held", 0),
+            num_nextn_predict_layers=g("num_nextn_predict_layers", 0),
             tie_word_embeddings=g("tie_word_embeddings", False),
             q_lora_rank=g("q_lora_rank", 0),
             kv_lora_rank=g("kv_lora_rank", 0),

@@ -50,7 +50,8 @@ def capture_router_stats(model, params, batch) -> Dict[str, np.ndarray]:
             else int(cfg.head_dim * cfg.partial_rotary_factor)
         )
         cos, sin = transformer.ops.rotary_tables(
-            batch["position_ids"], rope_dim, cfg.rope_theta, cfg.rope_scaling
+            batch["position_ids"], rope_dim, cfg.rope_theta, cfg.rope_scaling,
+            interleaved=transformer.mla_rope_interleaved(cfg),
         )
         cos, sin = cos.astype(cfg.dtype), sin.astype(cfg.dtype)
         L = cfg.num_hidden_layers
