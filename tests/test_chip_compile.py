@@ -188,27 +188,36 @@ def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
     assert text.count("tpu_custom_call") == 1
 
 
-# the smoke's shapes, and the held experts' buffer of the
-# joyai_llm_flash.train_packed_8k cell (16 of 256 experts, 8,192 rows)
-GMM_SHAPES = chip_smoke.GMM_SHAPES + (dict(m=8192, k=2048, n=768, e=16),)
+# the smoke's shapes (a fused gate_up of twice the width, as
+# models/deepseek_v4.py's; E 128 at the Qwen3-30B-A3B widths), and the held
+# experts' buffer of the joyai_llm_flash.train_packed_8k cell (16 of 256
+# experts, 8,192 rows) at its gate/up and its down projection
+GMM_SHAPES = chip_smoke.GMM_SHAPES + (
+    dict(m=8192, k=2048, n=768, e=16), dict(m=8192, k=768, n=2048, e=16),
+)
 
 
 @pytest.mark.parametrize("shape", GMM_SHAPES,
-                         ids=lambda s: f"m{s['m']}n{s['n']}e{s['e']}")
+                         ids=lambda s: f"m{s['m']}k{s['k']}n{s['n']}e{s['e']}")
 @pytest.mark.parametrize("kernel", ["fwd", "dlhs", "drhs"])
 def test_grouped_gemm_lowers_for_v5e(v5e, on_chip_kernels, kernel, shape):
     from veomni_tpu.ops.pallas import grouped_gemm as gg
 
     m, k, n, e = (shape[x] for x in ("m", "k", "n", "e"))
+    tiles = gg.choose_tiles(m, k, n, e, jnp.dtype(jnp.bfloat16))
+    assert gg._rows_vmem_bytes(tiles.fwd[0], k, tiles.fwd[1], 2) <= gg._VMEM_BUDGET
+    assert gg._rows_vmem_bytes(tiles.dlhs[0], n, tiles.dlhs[1], 2) <= gg._VMEM_BUDGET
+    assert gg._drhs_vmem_bytes(*tiles.drhs, 2) <= gg._VMEM_BUDGET
     lhs = _described(v5e[0], (m, k), jnp.bfloat16)
     g = _described(v5e[0], (m, n), jnp.bfloat16)
     rhs = _described(v5e[0], (e, k, n), jnp.bfloat16)
     starts = _described(v5e[0], (e + 1,), jnp.int32)
     fn, args = {
-        "fwd": (lambda a, w, st: gg._gmm_raw(a, w, st, gg._BM, gg._BN), (lhs, rhs, starts)),
-        "dlhs": (lambda a, w, st: gg._gmm_dlhs(a, w, st, gg._BM, gg._BK), (g, rhs, starts)),
-        "drhs": (lambda a, b_, st: gg._gmm_transpose(a, b_, st, e, gg._BM, gg._BK, gg._BN),
-                 (lhs, g, starts)),
+        "fwd": (lambda a, w, st: gg._gmm_rows(a, w, st, *tiles.fwd, name="gmm_fwd"),
+                (lhs, rhs, starts)),
+        "dlhs": (lambda a, w, st: gg._gmm_rows(a, w, st, *tiles.dlhs, name="gmm_dlhs"),
+                 (g, rhs, starts)),
+        "drhs": (lambda a, b_, st: gg._gmm_drhs(a, b_, st, *tiles.drhs), (lhs, g, starts)),
     }[kernel]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 1

@@ -106,7 +106,7 @@ def test_program_matches_the_reference_in_float32(factor):
     (want, (main, mtp)), want_grads = jax.jit(jax.value_and_grad(
         lambda p: ref.total_loss(p, model, batch["input_ids"], batch["segment_ids"]),
         has_aux=True))(params)
-    routed, held, dropped = (float(c) for c in m["moe_assignment_counts"])
+    routed, held, dropped, *_ = (float(c) for c in m["moe_assignment_counts"])
     assert routed == 3 * 128 * 4 and 0 < held < routed   # two expert layers and the MTP module's
     if factor == 1.0:
         assert ref.rank_capacity(model, 128) == transformer.held_rows(cfg, 128) == 128
@@ -159,7 +159,7 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
         first = 4 * j
         part = dict(lp, experts={k: v[first:first + 4] for k, v in lp["experts"].items()})
         cfg = program_cfg(dict(MODEL, first_expert_held=first), moe_capacity_factor=0.0)
-        out, _, (dropped, held, _) = transformer.moe_mlp_with_stats(x, part, cfg)
+        out, _, (dropped, held, *_) = transformer.moe_mlp_with_stats(x, part, cfg)
         assert float(dropped) == 0.0
         # the reference, given the same share, gives the same part
         want = ref.expert_layer(x, part, dict(MODEL, first_expert_held=first))
@@ -168,7 +168,7 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     assert held_rows == x.shape[0] * MODEL["num_experts_per_tok"]  # every assignment, once
     np.testing.assert_allclose(np.asarray(total + shared), np.asarray(uncut), rtol=1e-5, atol=1e-6)
     # and the program's own uncut layer (all experts held: the old path)
-    out, _, (_, held, load) = transformer.moe_mlp_with_stats(x, lp, program_cfg(whole))
+    out, _, (_, held, *_, load) = transformer.moe_mlp_with_stats(x, lp, program_cfg(whole))
     np.testing.assert_allclose(np.asarray(out), np.asarray(uncut), rtol=1e-5, atol=1e-6)
     assert float(held) == held_rows and float(load) >= 1.0
 
@@ -179,19 +179,52 @@ def test_a_buffer_too_short_drops_and_counts_and_a_long_one_is_dropless():
     x = jax.random.normal(jax.random.PRNGKey(2), (128, MODEL["hidden_size"]), jnp.float32)
     dropless = program_cfg(moe_capacity_factor=0.0)
     assert transformer.held_rows(dropless, 128) == 128 * 4
-    full, _, (d0, held, _) = transformer.moe_mlp_with_stats(x, lp, dropless)
+    full, _, (d0, held, *_) = transformer.moe_mlp_with_stats(x, lp, dropless)
     assert float(d0) == 0.0
     roomy = program_cfg(moe_capacity_factor=2.0)   # 2 x 128 = 256 rows >= what it got
     assert transformer.held_rows(roomy, 128) == 256 and float(held) <= 256
-    out, _, (d1, held1, _) = transformer.moe_mlp_with_stats(x, lp, roomy)
+    out, _, (d1, held1, *_) = transformer.moe_mlp_with_stats(x, lp, roomy)
     assert float(d1) == 0.0 and float(held1) == float(held)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full), rtol=1e-6, atol=1e-7)
     tight = program_cfg(moe_capacity_factor=0.5)   # 128 rows: fewer than it got
     assert transformer.held_rows(tight, 128) == 128 < float(held)
-    short, _, (d2, held2, _) = transformer.moe_mlp_with_stats(x, lp, tight)
+    short, _, (d2, held2, *_) = transformer.moe_mlp_with_stats(x, lp, tight)
     assert float(held2) == float(held)             # counted before the cut
     assert float(d2) == float(held) - 128         # the assignments past the buffer
     assert float(jnp.abs(short - full).max()) > 1e-3 and bool(jnp.isfinite(short).all())
+
+
+def test_the_layer_counts_the_grouped_gemms_tile_visits():
+    """At widths the kernel takes (multiples of 128) the layer's stats carry
+    the live (row tile, expert) visits of its grouped GEMM and the pairs a
+    grid over every pair would walk; at the toy widths both are 0."""
+    wide = dict(MODEL, hidden_size=128, moe_intermediate_size=128)
+    lp = jax.tree.map(lambda t: t[0], ref.nest(ref.make_params(wide, ref.seed_key(5)))["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(2), (128, 128), jnp.float32)
+    cfg = program_cfg(wide, moe_capacity_factor=0.0)  # a buffer of 512 rows: 4 tiles of 128
+    _, _, (_, held, visits, pairs, _) = transformer.moe_mlp_with_stats(x, lp, cfg)
+    assert float(pairs) == 4 * 4
+    # at least the tiles the held rows fill, at most one more per expert boundary
+    assert -(-float(held) // 128) <= float(visits) <= -(-float(held) // 128) + 3
+    toy = jax.tree.map(lambda t: t[0], ref.nest(ref.make_params(MODEL, ref.seed_key(5)))["layers"])
+    _, _, (_, _, visits, pairs, _) = transformer.moe_mlp_with_stats(
+        x[:, :64], toy, program_cfg(moe_capacity_factor=0.0))
+    assert (float(visits), float(pairs)) == (0.0, 0.0)
+
+
+def test_tile_counters_and_their_share_reach_the_registry():
+    from veomni_tpu.observability.callback import ObservabilityCallback
+    from veomni_tpu.observability.metrics import MetricsRegistry
+
+    cb = ObservabilityCallback()
+    cb.registry = MetricsRegistry()
+    cb._moe_pending = [(jnp.asarray([100.0, 40.0, 4.0, 6.0, 64.0]), jnp.float32(2.0)),
+                       (jnp.asarray([100.0, 10.0, 0.0, 2.0, 64.0]), jnp.float32(3.0))]
+    cb._flush_moe()
+    read = lambda name: cb.registry.get(name).value
+    assert (read("moe.gmm.tile_visits"), read("moe.gmm.tile_pairs")) == (8.0, 128.0)
+    assert read("moe.gmm.tile_visits_share") == 8.0 / 128.0
+    assert (read("moe.assignments_held"), read("moe.load_max_over_mean")) == (50.0, 3.0)
 
 
 def test_held_experts_and_expert_parallel_do_not_mix():
@@ -351,6 +384,8 @@ def test_routing_counters_reach_the_registry_without_a_sync(tmp_path):
         assert reg.get("train.mtp_loss").value > 1.0
         assert reg.get("train.moe_dropped_frac").value == 0.0
         assert reg.get("moe.assignments_dropped").value == 0.0
+        # the toy widths (64, 32) are not the kernel's: nothing to count
+        assert reg.get("moe.gmm.tile_pairs").value == 0.0
     finally:
         set_registry(old)
         destroy_parallel_state()

@@ -32,6 +32,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from veomni_tpu import ops
 from veomni_tpu.models.config import TransformerConfig
+from veomni_tpu.ops.pallas.grouped_gemm import tile_census
 
 Params = Dict[str, Any]
 
@@ -375,8 +376,9 @@ def moe_mlp_with_stats(x, lp, cfg: TransformerConfig):
     experts are gathered, sorted and multiplied: the layer gives its own
     experts' part of the result (what expert parallelism asks of one rank,
     without the exchange). Returns (out [T, H], aux, stats) with stats =
-    (dropped fraction of the held assignments, held assignments, the busiest
-    held expert's rows over the mean)."""
+    (dropped held assignments, held assignments, the grouped GEMM's live tile
+    visits and its row tiles x experts, the busiest held expert's rows over
+    the mean)."""
     t, h = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     n_held = cfg.experts_held
@@ -411,6 +413,8 @@ def moe_mlp_with_stats(x, lp, cfg: TransformerConfig):
         xs = x[token_idx]  # [rows, H] sorted by expert
         held = counts.sum().astype(jnp.float32)
         load = counts.max() * (counts.shape[0] / jnp.maximum(held, 1.0))
+        visits, pairs = tile_census(
+            group_sizes, xs.shape[0], *lp["experts"]["gate_proj"].shape[1:], xs.dtype)
     with jax.named_scope("moe.experts"):
         out = experts_apply_sorted(xs, lp["experts"], group_sizes, expert_of_row, cfg)
 
@@ -423,7 +427,7 @@ def moe_mlp_with_stats(x, lp, cfg: TransformerConfig):
         combined = jnp.zeros((t, h), out.dtype).at[token_idx].add(out * weight)
         if cfg.n_shared_experts or cfg.shared_expert_intermediate_size:
             combined = combined + _shared_experts_out(x, lp, cfg)
-    return combined, aux, (dropped, held, load)
+    return combined, aux, (dropped, held, visits, pairs, load)
 
 
 def _moe_mlp(x, lp, cfg: TransformerConfig):
@@ -658,8 +662,9 @@ def _decoder_layer(
     # last stage: "mlp" when dense, "moe.route" / "moe.combine" when sparse
     with jax.named_scope("moe.route" if is_moe else "mlp"):
         x = _norm(hidden, pre_norm, cfg)
-    # (dropped assignments, held assignments, busiest expert's rows over the mean)
-    moe_stats = (jnp.float32(0.0),) * 3
+    # (dropped assignments, held assignments, the grouped GEMM's tile visits
+    # and tile pairs, busiest expert's rows over the mean)
+    moe_stats = (jnp.float32(0.0),) * 5
     if is_moe:
         from veomni_tpu.parallel.parallel_state import get_parallel_state_or_none
 
@@ -674,7 +679,8 @@ def _decoder_layer(
                     "every expert: leave it at 0")
             out, aux, dropped, load = ep_moe_mlp(x, lp, cfg, ps, with_load=True)
             assigned = jnp.float32(b * s * cfg.num_experts_per_tok)
-            moe_stats = (dropped * assigned, assigned, load)  # the mesh holds them all
+            # the mesh holds them all; the dispatch's own kernels are not counted
+            moe_stats = (dropped * assigned, assigned, 0.0, 0.0, load)
         else:
             out, aux, moe_stats = moe_mlp_with_stats(x.reshape(b * s, h), lp, cfg)
             out = out.reshape(b, s, h)
@@ -718,11 +724,11 @@ def _decoder_layer(
 
 
 def _add_layer_stats(total, stats):
-    """Fold layers' ``[n, 4]`` stats (aux, dropped, held, load) into the
-    running ``[4]``: sums, and the largest load."""
+    """Fold layers' ``[n, 6]`` stats (aux, dropped, held, tile visits, tile
+    pairs, load) into the running ``[6]``: sums, and the largest load."""
     with jax.named_scope("moe.route"):
-        return jnp.concatenate([total[:3] + stats[:, :3].sum(0),
-                                jnp.maximum(total[3:], stats[:, 3:].max(0))])
+        return jnp.concatenate([total[:5] + stats[:, :5].sum(0),
+                                jnp.maximum(total[5:], stats[:, 5:].max(0))])
 
 
 def forward_hidden(
@@ -756,8 +762,9 @@ def forward_layers(
     cfg.dtype), ``moe_aux``, ``moe_dropped_frac`` (dropped over held) and, for
     a MoE model, ``moe_assignment_counts`` (a vector, so that the train step
     SUMS it over micro-steps: token-expert assignments routed, those to
-    experts this model holds, and those of them a rank capacity dropped, summed
-    over the MoE layers), ``moe_load_max_over_mean`` (largest over the layers); with
+    experts this model holds, those of them a rank capacity dropped, and the
+    grouped GEMM's live tile visits and tile pairs, summed over the MoE
+    layers), ``moe_load_max_over_mean`` (largest over the layers); with
     ``with_mtp``, ``mtp_hidden``: one final normed hidden per multi-token-
     prediction module (:func:`_mtp_hidden`), whose MoE layers count too.
 
@@ -811,7 +818,7 @@ def forward_layers(
             else:
                 runs.append([i, 1, *sig])
 
-        total = jnp.zeros((4,), jnp.float32)
+        total = jnp.zeros((6,), jnp.float32)
         for start, n, window, local in runs:
             sub = (
                 layer_tree if n == count
@@ -847,7 +854,7 @@ def forward_layers(
                 total = _add_layer_stats(total, stats)
         return hidden, total, dsa_carry
 
-    stats_total = jnp.zeros((4,), jnp.float32)
+    stats_total = jnp.zeros((6,), jnp.float32)
     K_inject = 0 if post_layer_residuals is None else post_layer_residuals.shape[0]
     # DSA "shared" layers reuse the previous layer's selection; the [B,S,S]
     # carry (threaded across run/segment boundaries, zeros before the first
@@ -908,8 +915,8 @@ def forward_layers(
         routed = n_moe * hidden.shape[0] * hidden.shape[1] * cfg.num_experts_per_tok
         with jax.named_scope("moe.route"):
             out["moe_assignment_counts"] = jnp.stack(
-                [jnp.float32(routed), stats_total[2], stats_total[1]])
-        out["moe_load_max_over_mean"] = stats_total[3]
+                [jnp.float32(routed), stats_total[2], stats_total[1], *stats_total[3:5]])
+        out["moe_load_max_over_mean"] = stats_total[5]
     return out
 
 
@@ -926,7 +933,7 @@ def _mtp_hidden(compute: Params, cfg: TransformerConfig, hidden, input_ids, cos,
     order of the released checkpoints' ``eh_proj``), runs one decoder layer
     over the row (the row's own segment ids and rope) and norms the result for
     the model's head. ``hidden`` is the last layer's output before the final
-    norm. Returns ([one normed hidden per module], the layers' stats [D, 4])."""
+    norm. Returns ([one normed hidden per module], the layers' stats [D, 6])."""
     mp = compute["mtp"]
     body = partial(_decoder_layer, cfg=cfg, cos=cos, sin=sin, segment_ids=segment_ids,
                    window=None, is_moe_segment=cfg.is_moe)

@@ -56,7 +56,9 @@ class ObservabilityCallback(Callback):
 
     def _flush_moe(self):
         """``moe.assignments`` / ``moe.assignments_held`` /
-        ``moe.assignments_dropped`` / ``moe.load_max_over_mean`` from the queued
+        ``moe.assignments_dropped`` / ``moe.gmm.tile_visits`` /
+        ``moe.gmm.tile_pairs`` / ``moe.load_max_over_mean`` (and the gauge
+        ``moe.gmm.tile_visits_share``, over the run so far) from the queued
         steps' metrics (the loss function's ``moe_assignment_counts``, which
         the step sums over its micro-steps, and ``moe_load_max_over_mean``).
         Called where the loop has synced anyway (a sync step, the end of
@@ -67,11 +69,15 @@ class ObservabilityCallback(Callback):
 
         pending, self._moe_pending = self._moe_pending, []
         counters = [self.registry.counter(f"moe.{name}")
-                    for name in ("assignments", "assignments_held", "assignments_dropped")]
+                    for name in ("assignments", "assignments_held", "assignments_dropped",
+                                 "gmm.tile_visits", "gmm.tile_pairs")]
         for counts, load in jax.device_get(pending):
             for counter, value in zip(counters, counts):
                 counter.inc(float(value))
         self.registry.gauge("moe.load_max_over_mean").set(float(load))
+        visits, pairs = (counter.value for counter in counters[3:])
+        if pairs:
+            self.registry.gauge("moe.gmm.tile_visits_share").set(visits / pairs)
 
     def on_train_begin(self, trainer, state):
         t = trainer.args.train
@@ -186,10 +192,13 @@ class ObservabilityCallback(Callback):
         if routed is not None and routed.value:
             logger.info_rank0(
                 "moe routing over the run: %d assignments, %d to held experts, %d of "
-                "those dropped, load max/mean of the last step %.2f", routed.value,
+                "those dropped, load max/mean of the last step %.2f; the grouped GEMM "
+                "walked %d of %d (row tile, expert) pairs", routed.value,
                 self.registry.counter("moe.assignments_held").value,
                 self.registry.counter("moe.assignments_dropped").value,
-                self.registry.gauge("moe.load_max_over_mean").value)
+                self.registry.gauge("moe.load_max_over_mean").value,
+                self.registry.counter("moe.gmm.tile_visits").value,
+                self.registry.counter("moe.gmm.tile_pairs").value)
         if self.tracker is not None:
             state.metrics.update(self.tracker.end_window())
         if self.cost_window is not None:
