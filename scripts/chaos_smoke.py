@@ -1,9 +1,9 @@
 """Tier-1 chaos smoke: the self-healing fleet survives a seeded storm.
 
-Runs the bench's chaos soak leg (``bench.run_serve_open_loop_bench`` with
-``chaos_seed``) on the tiny CPU model: a fixed-seed deterministic fault
-schedule — replica kill + hang/delay/exception across the serve fault
-points — fires over a 3-replica self-healing router while an open-loop
+Runs the storm drill's chaos leg (``resilience/storm.py::
+run_open_loop_storm`` with ``chaos_seed``) on the tiny CPU model: a
+fixed-seed deterministic fault schedule — replica kill +
+hang/delay/exception across the serve fault points — fires over a 3-replica self-healing router while an open-loop
 Poisson storm replays, then the same storm replays fault-free. The plan
 also schedules one mid-storm weight publish, so the drill covers the
 rolling hot-swap path under fire. Exits 0 only when every fleet
@@ -14,7 +14,7 @@ converged to the published weights version) and chaos goodput stays
 
 Budgeted for CI: one rate, a small storm, aggressive (sub-second) wedge
 deadlines — the whole drill finishes in well under a minute on CPU.
-Invoked by ``scripts/tier1.sh`` before the shard loop; the fixed seed
+Invoked by ``scripts/tier1.sh`` before the tests; the fixed seed
 means a failure here replays bit-for-bit with the same command.
 """
 
@@ -37,8 +37,8 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    import bench
     from veomni_tpu.models import TransformerConfig, build_foundation_model
+    from veomni_tpu.resilience.storm import run_open_loop_storm
 
     cfg = TransformerConfig(
         model_type="qwen3", vocab_size=128, hidden_size=64,
@@ -53,11 +53,10 @@ def main() -> int:
     # absorbs the whole storm in ~0.1s at measured capacity, which makes
     # the 2s chaos hang dominate any goodput ratio. 2.5 req/s spreads 16
     # requests over ~6s so the ratio measures healing, not storm length.
-    r = bench.run_serve_open_loop_bench(
-        num_slots=2, block_size=8, n_requests=16, prompt_lens=(8, 12),
-        max_new_tokens=6, arrival_rates=(2.5,), seed=SEED,
+    r = run_open_loop_storm(
+        params, cfg, num_slots=2, block_size=8, n_requests=16,
+        prompt_lens=(8, 12), max_new_tokens=6, arrival_rates=(2.5,), seed=SEED,
         chaos_seed=SEED, chaos_stall_s=0.5, chaos_publishes=1,
-        _model=(params, cfg),
     )
     c = r["chaos"]
     line = {

@@ -9,7 +9,7 @@ Usage:
     python scripts/lint.py --raw            # include allowlisted findings
 
 Exit codes: 0 clean, 1 findings, 2 internal error. The tier-1 runner
-(scripts/tier1.sh) runs this BEFORE the pytest shards: it finishes in
+(scripts/tier1.sh) runs this BEFORE the tests: it finishes in
 seconds because nothing here imports jax — `veomni_tpu.analysis` is
 import-light by design, and this script asserts that property so a future
 import can't silently turn the lint stage into a backend init.

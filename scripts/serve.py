@@ -6,8 +6,9 @@ comma-separated prompts (``--prompt-ids 5,17,3`` repeatable). Streams every
 token event to stdout as it lands and prints the engine metrics at the end.
 
 By default builds a tiny random-weight qwen3-style model (engine plumbing
-demo / CPU smoke); ``--preset`` switches to a bench-scale model on the real
-accelerator.
+demo / CPU smoke); ``--preset <name>`` builds the model of
+``configs/text/<name>_v5e.yaml`` (``qwen3_0p6b``: Qwen3-0.6B at its published
+widths) for the real accelerator.
 
 Run:
   python scripts/serve.py --synthetic 8 --max-new 32
@@ -83,21 +84,38 @@ import json
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
 
 
 def build_model(preset: str = "", seed: int = 0):
-    """(params, cfg): a bench.py BENCH_PRESETS model, or the tiny demo model
-    when ``preset`` is empty; random weights from ``seed``."""
+    """(params, cfg): the model of ``configs/text/<preset>_v5e.yaml``, or the
+    tiny demo model when ``preset`` is empty; random weights from ``seed``.
+
+    The preset's widths are the recipe's ``model.config_overrides``, built
+    through ``models.auto.build_config`` as the trainer builds them, so they
+    are written once. The recipe names no dtype: compute in bfloat16 over
+    float32 parameters is ``TransformerConfig``'s default."""
     import jax
     import jax.numpy as jnp
 
     from veomni_tpu.models import TransformerConfig, build_foundation_model
 
     if preset:
-        from bench import bench_config
+        import yaml
 
-        cfg = bench_config(preset=preset)
+        from veomni_tpu.models.auto import build_config
+
+        recipes = os.path.join(_REPO, "configs", "text")
+        path = os.path.join(recipes, f"{preset}_v5e.yaml")
+        if not os.path.isfile(path):
+            known = sorted(n[:-len("_v5e.yaml")] for n in os.listdir(recipes)
+                           if n.endswith("_v5e.yaml"))
+            raise SystemExit(
+                f"unknown --preset {preset!r}: no {path}; choose from {known}")
+        with open(path) as f:
+            overrides = yaml.safe_load(f)["model"]["config_overrides"]
+        cfg = build_config(**overrides)
     else:  # tiny random demo model
         cfg = TransformerConfig(
             model_type="qwen3", vocab_size=256, hidden_size=64,
@@ -152,7 +170,8 @@ def main():
     ap.add_argument("--eos-id", type=int, default=-1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--preset", default="",
-                    help="bench.py BENCH_PRESETS model instead of the tiny demo")
+                    help="the model of configs/text/<preset>_v5e.yaml "
+                         "instead of the tiny demo")
     ap.add_argument("--slots", type=int,
                     default=int(os.environ.get("VEOMNI_SERVE_SLOTS", 4)))
     ap.add_argument("--block-size", type=int,

@@ -1,79 +1,42 @@
 #!/usr/bin/env bash
-# Default tier-1 entry point (ROADMAP.md "Tier-1 verify").
+# Tier-1 as the driver runs it (ROADMAP.md "Tier-1 verify", docs/testing.md):
+# lint, the chaos smoke, then ONE pytest invocation over tests/ with six
+# xdist workers (one test file per worker at a time) under one timeout. The
+# driver's own run took 843 s of its 1,470 (its last run before PR 31).
+# Prints DOTS_PASSED=<passed tests>; the worst exit code of the three wins,
+# and a failing stage never stops the later ones.
 #
-# The full suite exceeds a single 870s invocation on a 2-core box, so this
-# runs it as N deterministic shards (scripts/tier1_shard.py: crc32-stable
-# file partition) SEQUENTIALLY, each under its own timeout, and merges the
-# passed-dot counts into the one DOTS_PASSED line drivers grep for. A shard
-# that times out or fails makes the whole run fail (worst rc wins), but the
-# later shards still run — a hang in shard 1 must not hide shard 2's result.
-#
-# Knobs (env):
-#   TIER1_SHARDS         shard count (default 5; 4 stopped fitting the
-#                        per-shard budget when the scale-out router tier
-#                        grew the suite — shard 1/4 hit 870s)
-#   TIER1_SHARD_TIMEOUT  per-shard budget in seconds (default 870, the
-#                        ROADMAP's historical single-run budget)
-#   TIER1_LOG_DIR        where per-shard logs land (default /tmp)
-#
-# Usage (docs/testing.md "Sharded tier-1"):
-#   bash scripts/tier1.sh
-#   TIER1_SHARDS=3 TIER1_SHARD_TIMEOUT=600 bash scripts/tier1.sh
+# TIER1_LOG_DIR  where the three logs land (default /tmp)
 set -u -o pipefail
 
 cd "$(dirname "$0")/.."
 
-SHARDS="${TIER1_SHARDS:-5}"
-SHARD_TIMEOUT="${TIER1_SHARD_TIMEOUT:-870}"
 LOG_DIR="${TIER1_LOG_DIR:-/tmp}"
 mkdir -p "$LOG_DIR"
-
-total_dots=0
 rc=0
 
-# Fast static-analysis stage (graftlint, docs/static-analysis.md): AST-only,
-# never initializes a JAX backend, finishes in seconds. Runs BEFORE the
-# pytest shards so a trace-purity / lock-discipline / doc-drift violation
-# fails tier-1 without waiting out two ~870s shards; the shards still run so
-# a lint failure never hides a test regression (worst rc wins, same policy
-# as a failing shard). --json artifact lands next to the shard logs for CI.
-lint_log="$LOG_DIR/_t1_lint.log"
+# graftlint (docs/static-analysis.md): AST only, no JAX backend, seconds
 timeout -k 5 120 python scripts/lint.py --json "$LOG_DIR/_t1_lint.json" \
-  2>&1 | tee "$lint_log"
+  2>&1 | tee "$LOG_DIR/_t1_lint.log"
 lint_rc=${PIPESTATUS[0]}
 echo "LINT rc=${lint_rc}"
-if [ "$lint_rc" -ne 0 ]; then
-  rc=$lint_rc
-fi
-# Bounded chaos smoke (scripts/chaos_smoke.py, docs/testing.md): the
-# fixed-seed self-healing fleet drill — kill + hang + delay/exception over
-# 3 replicas, fleet invariants + goodput floor checked against a fault-free
-# replay. ~50s on CPU; the 120s timeout is headroom, not budget. Runs
-# before the shard loop for the same reason lint does: a broken resurrect
-# path fails fast, and a smoke failure never hides a shard regression.
-chaos_log="$LOG_DIR/_t1_chaos.log"
-timeout -k 5 120 env JAX_PLATFORMS=cpu python scripts/chaos_smoke.py \
-  2>&1 | tee "$chaos_log"
+[ "$lint_rc" -ne 0 ] && rc=$lint_rc
+
+# the fixed-seed self-healing fleet drill (scripts/chaos_smoke.py): about
+# 45 s on the CPU; the timeout is headroom
+timeout -k 5 180 env JAX_PLATFORMS=cpu python scripts/chaos_smoke.py \
+  2>&1 | tee "$LOG_DIR/_t1_chaos.log"
 chaos_rc=${PIPESTATUS[0]}
 echo "CHAOS_SMOKE rc=${chaos_rc}"
-if [ "$chaos_rc" -ne 0 ] && [ "$rc" -eq 0 ]; then
-  rc=$chaos_rc
-fi
-for k in $(seq 1 "$SHARDS"); do
-  log="$LOG_DIR/_t1_shard${k}of${SHARDS}.log"
-  rm -f "$log"
-  timeout -k 10 "$SHARD_TIMEOUT" env JAX_PLATFORMS=cpu \
-    python scripts/tier1_shard.py --shard "$k/$SHARDS" 2>&1 | tee "$log"
-  shard_rc=${PIPESTATUS[0]}
-  # pytest's -q progress lines are runs of [.FEsx] (with an optional
-  # percentage suffix); count the dots = passed tests, same recipe the
-  # single-invocation verify line used
-  dots=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$log" | tr -cd . | wc -c)
-  echo "SHARD_DOTS ${k}/${SHARDS}=${dots} rc=${shard_rc}"
-  total_dots=$((total_dots + dots))
-  if [ "$shard_rc" -ne 0 ] && [ "$rc" -eq 0 ]; then
-    rc=$shard_rc
-  fi
-done
-echo "DOTS_PASSED=${total_dots}"
+[ "$chaos_rc" -ne 0 ] && [ "$rc" -eq 0 ] && rc=$chaos_rc
+
+log="$LOG_DIR/_t1.log"
+rm -f "$log"
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+  -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
+  -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee "$log"
+test_rc=${PIPESTATUS[0]}
+[ "$test_rc" -ne 0 ] && [ "$rc" -eq 0 ] && rc=$test_rc
+# pytest's -q progress lines are runs of [.FEsx] with an optional percentage
+echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$log" | tr -cd . | wc -c)"
 exit "$rc"

@@ -44,7 +44,7 @@ def qwen3():
 
 def test_chaos_plan_same_seed_identical():
     """Same seed -> field-for-field identical schedule; the to_doc() form
-    is the canonical comparison (and what bench artifacts embed)."""
+    is the canonical comparison (and what soak reports embed)."""
     kw = dict(duration_s=7.5, kills=2, hangs=2, delays=3, exceptions=2,
               hang_seconds=1.5, delay_ms=10.0, expected_ticks=200)
     a = build_chaos_plan(123, **kw)

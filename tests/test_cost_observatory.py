@@ -2,7 +2,7 @@
 
 The census must be CPU-exercisable end to end: non-zero XLA FLOPs/bytes for
 the train-step and paged-decode jit sites, a window MFU gauge that agrees
-with the offline bench-style computation, a live buffer census aggregated
+with the offline computation, a live buffer census aggregated
 by dtype, well-formed ``/debug/memory`` + ``/debug/cost`` documents, a
 serving-side recompile warning after the warmup grace, and a subprocess
 drill proving a simulated ``RESOURCE_EXHAUSTED`` produces a post-mortem
@@ -156,8 +156,8 @@ def test_train_step_census_nonzero_on_cpu():
 
 def test_window_mfu_agrees_with_offline_computation():
     """Acceptance: the window MFU gauge agrees with the offline
-    bench.py-style computation (census FLOPs x steps / dt / peak) within
-    5% over the same step loop."""
+    computation (census FLOPs x steps / dt / peak) within 5% over the same
+    step loop."""
     from veomni_tpu.models import TransformerConfig, build_foundation_model
     from veomni_tpu.optim import build_lr_scheduler, build_optimizer
     from veomni_tpu.parallel import init_parallel_state, use_parallel_state
@@ -430,28 +430,6 @@ def test_debug_memory_and_cost_endpoints():
     finally:
         exp.stop()
     del anchor
-
-
-# ----------------------------------------------------------------- bench
-def test_bench_census_fields_and_drift_warning(capsys):
-    import bench
-
-    census = CostCensus(registry=MetricsRegistry())
-    census.record("train_step", "drift_unit", compile_time_s=2.0,
-                  num_devices=4, flops=250.0)
-    out = bench.census_bench_fields(1000.0, census=census)
-    assert out["xla_flops_per_step"] == 1000.0  # 250 per device x 4
-    assert out["analytic_vs_xla_flops_ratio"] == 1.0
-    assert out["compile_time_s"]["drift_unit"] == 2.0
-    assert "WARNING" not in capsys.readouterr().err
-
-    # the same bucket again: compile-time DELTA only (sweep discipline)
-    census.record("train_step", "drift_unit", compile_time_s=0.5,
-                  num_devices=4, flops=250.0)
-    out = bench.census_bench_fields(2000.0, census=census)
-    assert out["compile_time_s"]["drift_unit"] == pytest.approx(0.5)
-    assert out["analytic_vs_xla_flops_ratio"] == 2.0
-    assert "WARNING" in capsys.readouterr().err  # outside FLOPS_RATIO_BAND
 
 
 # ------------------------------------------------------ subprocess drill

@@ -227,8 +227,8 @@ def test_e2e_eval_loop(tmp_path):
 
 
 def test_e2e_training_ctx_remat_policy(tmp_path):
-    """bench.py's default remat policy ("ctx": save only the named attention
-    context) must train end-to-end through the CLI argument plumbing
+    """The "ctx" remat policy (save only the named attention context) must
+    train end-to-end through the CLI argument plumbing
     (train.gradient_checkpointing_policy -> cfg.remat_policy) with losses
     matching the nothing-policy run exactly (same seeds, pure remat change)."""
     from veomni_tpu.trainer import TextTrainer

@@ -676,30 +676,6 @@ def test_native_buckets_exact_and_monotone_past_reservoir():
         prev = cur
 
 
-def test_tier1_shard_partitions_deterministically():
-    """Satellite: N shards partition the suite exactly (every test file in
-    exactly one shard), and membership is stable under file additions."""
-    shard_mod = _load_script("tier1_shard")
-    files = shard_mod.discover()
-    assert os.path.join(_REPO, "tests", "test_fleet_observatory.py") in files
-    for n in (2, 3):
-        shards = [shard_mod.shard_files(files, k, n)
-                  for k in range(1, n + 1)]
-        flat = [f for s in shards for f in s]
-        assert sorted(flat) == sorted(files)      # exact partition
-        assert len(set(flat)) == len(flat)        # disjoint
-    # stability: adding a file never moves an existing one
-    two = shard_mod.shard_files(files, 1, 2)
-    grown = files + [os.path.join(_REPO, "tests", "test_zzz_new.py")]
-    assert [f for f in shard_mod.shard_files(grown, 1, 2)
-            if "zzz_new" not in f] == two
-    with pytest.raises(ValueError):
-        shard_mod.parse_shard("0/2")
-    with pytest.raises(ValueError):
-        shard_mod.parse_shard("3/2")
-    assert shard_mod.parse_shard("2/3") == (2, 3)
-
-
 def test_delay_mode_plan_grammar():
     """The delay mode parses from the JSON plan grammar with its ms knob
     and rejects nothing a drill needs."""

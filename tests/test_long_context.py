@@ -42,7 +42,7 @@ def test_chunk_mbs_equivalence():
 def test_ctx_remat_under_sequence_parallel():
     """The ctx policy's checkpoint_name sits outside the Ulysses shard_map
     body — saving the attention context must not change loss/grad-norm
-    under an sp layout (the bench default composes exactly this way)."""
+    under an sp layout."""
     from tests.test_parallel_equivalence import _batch, _loss_and_gnorm, _toy_cfg
 
     cfg = _toy_cfg()

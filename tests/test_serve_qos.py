@@ -566,8 +566,7 @@ def _drive_overload(params, cfg, classes, batch_prompts, inter_prompts,
         classes=classes, queue_bound=queue_bound,
     ))
     # warm EVERY bucket the timed run can hit (one length class at a time,
-    # full allocation trajectory — the run_serve_bench warmup discipline):
-    # a cold compile landing on an interactive request in one engine but a
+    # full allocation trajectory): a cold compile landing on an interactive request in one engine but a
     # batch request in the other would swamp the scheduling signal the
     # TTFT comparison measures
     for p in _prompts((6, 9, 12), seed=99):
@@ -649,18 +648,17 @@ def test_overload_interactive_p99_beats_fifo_and_parity(qwen3):
 
 
 def test_open_loop_bench_smoke(qwen3):
-    """BENCH_SERVE_OPEN_LOOP machinery end to end on CPU: Poisson arrivals
-    at 3x measured capacity against a bounded queue produce a well-formed
-    sweep entry with nonzero rejects, a respected bound, and the JSON
-    fields the bench line promises (reject_rate / p99 TTFT / goodput)."""
-    import bench
+    """The open-loop storm drill end to end on CPU: Poisson arrivals at 3x
+    measured capacity against a bounded queue produce a well-formed sweep
+    entry with nonzero rejects, a respected bound, and the fields its
+    callers read (reject_rate / p99 TTFT / goodput)."""
+    from veomni_tpu.resilience.storm import run_open_loop_storm
 
     params, cfg = qwen3
-    r = bench.run_serve_open_loop_bench(
-        num_slots=2, block_size=8, n_requests=16, prompt_lens=(12, 20),
+    r = run_open_loop_storm(
+        params, cfg, num_slots=2, block_size=8, n_requests=16, prompt_lens=(12, 20),
         max_new_tokens=6, arrival_rate_mults=(3.0,), queue_bound=3,
         deadline_s=2.0, interactive_frac=0.5, seed=42,
-        _model=(params, cfg),
     )
     assert r["capacity_rps"] > 0
     (entry,) = r["sweep"]
@@ -673,4 +671,4 @@ def test_open_loop_bench_smoke(qwen3):
     assert entry["reject_rate"] > 0  # 3x capacity vs a 3-deep queue
     assert entry["max_queue_depth"] <= 3
     assert entry["completed"] > 0 and entry["goodput_tok_s"] >= 0
-    json.dumps(r)  # the whole result is JSON-serializable (bench line)
+    json.dumps(r)  # the whole result is JSON-serializable

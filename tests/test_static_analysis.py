@@ -11,6 +11,7 @@ fixes this PR landed (locked instrument reads, locked flight-recorder
 introspection) hold under a thread hammer.
 """
 
+import functools
 import json
 import os
 import re
@@ -318,3 +319,63 @@ def test_flight_recorder_len_dropped_consistent_under_hammer():
     stop.set()
     assert not errs
     assert len(rec) <= 64 and rec.dropped >= 0
+
+
+# ------------------------------------------------------- the documents' paths
+_DOCUMENTS = (
+    ["README.md", os.path.join(".claude", "skills", "verify", "SKILL.md")]
+    + sorted(os.path.join("docs", n)
+             for n in os.listdir(os.path.join(_REPO, "docs"))
+             if n.endswith(".md"))
+)
+_PATH_RE = re.compile(r"[\w.<>*{}$/-]*[\w>*}]\.(?:py|sh|yaml|json)\b")
+# docs/MIGRATION.md's left column names the REFERENCE project's files
+_THE_REFERENCES = {"train.py", "tasks/train_torch.py",
+                   "multimodal_chat_template.py"}
+
+
+@functools.lru_cache(maxsize=None)
+def _repo_basenames():
+    names = set()
+    for dirpath, dirnames, filenames in os.walk(_REPO):
+        dirnames[:] = [d for d in dirnames
+                       if not d.startswith(".") and d not in (
+                           "__pycache__", "output", "chiprun_out",
+                           "archive_check")]
+        names.update(filenames)
+    return frozenset(names)
+
+
+@pytest.mark.parametrize("doc", _DOCUMENTS)
+def test_documents_name_only_files_that_exist(doc):
+    """Every repo path ending in .py, .sh, .yaml or .json that a document
+    names exists, and no document mentions a ``BENCH_`` switch: the harness
+    that read them is gone, and a document that teaches a dead entry point
+    costs every reader after it. A path with a slash is looked up from the
+    repo root, then from ``veomni_tpu/`` (the documents write
+    ``models/transformer.py``), where its first directory is one of theirs
+    (``global_step_N/manifest.json`` is a run's artifact); a bare .py or .sh
+    name must be some file's name. Placeholders (``<cell>``, ``*``, ``{}``,
+    ``$``), absolute paths and bare .json/.yaml names (run artifacts,
+    examples) are not paths of the repo."""
+    text = open(os.path.join(_REPO, doc), encoding="utf-8").read()
+    assert not re.search(r"BENCH_[A-Z]", text), (
+        f"{doc} still names a BENCH_* switch")
+    basenames = _repo_basenames()
+    missing = []
+    for m in _PATH_RE.finditer(text):
+        name = m.group(0)
+        if (re.search(r"[<>*{}$]", name) or name.startswith("/")
+                or name in _THE_REFERENCES):
+            continue
+        name = name.lstrip("./") if name.startswith(("./", "../")) else name
+        if "/" not in name:
+            if name.endswith((".py", ".sh")) and name not in basenames:
+                missing.append(name)
+            continue
+        bases = [b for b in ("", "veomni_tpu") if os.path.isdir(
+            os.path.join(_REPO, b, name.split("/")[0]))]
+        if bases and not any(os.path.exists(os.path.join(_REPO, b, name))
+                             for b in bases):
+            missing.append(name)
+    assert not missing, f"{doc} names files that do not exist: {missing}"

@@ -383,8 +383,10 @@ def test_chaos_plan_publish_draws_deterministic_and_prefix_stable():
 
 def test_chaos_soak_publish_only_converges_and_requires_publish_fn(qwen3):
     """A publish-only storm (no faults, no kills) through the soak
-    harness: every invariant incl. version convergence holds, and a plan
-    that schedules publishes without a publish_fn is refused loudly."""
+    harness: every invariant incl. version convergence holds, nothing is
+    traced from the moment of the publish on (drain -> swap -> rotation
+    under load is a buffer swap, never a recompile), and a plan that
+    schedules publishes without a publish_fn is refused loudly."""
     from veomni_tpu.resilience.chaos import build_chaos_plan, run_chaos_soak
 
     params, cfg = qwen3
@@ -396,16 +398,25 @@ def test_chaos_soak_publish_only_converges_and_requires_publish_fn(qwen3):
     def factory():
         r = Router(params, cfg, _engine_cfg(num_slots=2), RouterConfig(
             replicas=3))
-        r.run(_reqs(_prompts(2, seed=51), n_new=2))  # warm the programs
+        # warm with the storm's own prompts, twice: the second pass's
+        # prefix-cache hits reach the chunked-prefill program too
+        for _ in range(2):
+            r.run(_reqs(_prompts(8, seed=50), n_new=4))
         return r
+
+    traces_at_publish = {}
+
+    def publish(router, idx):
+        traces_at_publish.update(decode_mod.TRACE_COUNTS)
+        return router.publish_weights(_perturb(params), f"storm-v{idx + 1}")
 
     with pytest.raises(ValueError, match="publish_fn"):
         run_chaos_soak(router_factory=factory, requests=reqs,
                        arrivals=arrivals, plan=plan)
     report = run_chaos_soak(
         router_factory=factory, requests=reqs, arrivals=arrivals, plan=plan,
-        publish_fn=lambda router, idx:
-            router.publish_weights(_perturb(params), f"storm-v{idx + 1}"))
+        publish_fn=publish)
+    assert dict(decode_mod.TRACE_COUNTS) == traces_at_publish
     assert report["publishes"] == 1
     assert report["published_versions"] == ["storm-v1"]
     assert report["version_converged"], report

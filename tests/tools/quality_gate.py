@@ -1,7 +1,7 @@
 """Pinned quality-gate bounds for non-bit-exact serving features.
 
 The core scoring lives in ``veomni_tpu/serving/quality.py`` (the engine
-and bench use it too); this helper pins the REPO-WIDE bounds and gives
+uses it too); this helper pins the REPO-WIDE bounds and gives
 tests a one-call assertion. Any future deliberately-non-bit-exact feature
 (fp8 KV, quantized lm head, approximate attention) should certify itself
 through :func:`assert_quality_gate` rather than inventing its own

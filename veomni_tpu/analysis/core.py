@@ -38,7 +38,6 @@ from typing import Callable, Dict, Iterable, List, Optional
 #: trigger rules (tests/test_static_analysis.py runs passes over them with
 #: a dedicated index), and test code is allowed to be impure.
 DEFAULT_SCAN_DIRS = ("veomni_tpu", "scripts", "tasks")
-DEFAULT_SCAN_FILES = ("bench.py",)
 EXCLUDE_PARTS = ("__pycache__",)
 
 #: default allowlist location, relative to the repo root
@@ -98,8 +97,7 @@ class RepoIndex:
 
     @classmethod
     def load(cls, root: str,
-             scan_dirs: Iterable[str] = DEFAULT_SCAN_DIRS,
-             scan_files: Iterable[str] = DEFAULT_SCAN_FILES) -> "RepoIndex":
+             scan_dirs: Iterable[str] = DEFAULT_SCAN_DIRS) -> "RepoIndex":
         paths: List[str] = []
         for d in scan_dirs:
             base = os.path.join(root, d)
@@ -108,10 +106,6 @@ class RepoIndex:
                 for fname in sorted(filenames):
                     if fname.endswith(".py"):
                         paths.append(os.path.join(dirpath, fname))
-        for f in scan_files:
-            p = os.path.join(root, f)
-            if os.path.isfile(p):
-                paths.append(p)
         files: Dict[str, SourceFile] = {}
         for abspath in sorted(paths):
             rel = os.path.relpath(abspath, root).replace(os.sep, "/")

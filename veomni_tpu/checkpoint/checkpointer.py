@@ -624,7 +624,7 @@ class Checkpointer:
                     )
                     if i + 1 < len(candidates):
                         # integrity.ckpt_fallbacks means "walked past storage
-                        # rot" (/healthz + bench surface it next to the
+                        # rot" (/healthz surfaces it next to the
                         # quarantine count) — a fallback past a transient
                         # restore failure is NOT an integrity incident and
                         # must not send an operator hunting for .corrupt

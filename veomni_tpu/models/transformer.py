@@ -41,18 +41,15 @@ def _remat_policy(cfg: TransformerConfig):
     """Map cfg.remat_policy to a jax.checkpoint policy (the TPU analogue of
     the reference's activation-offload contexts, ``offloading.py:32-74``).
 
-    Policies, by saved-activation footprint (qwen3-0.6B, seq 4096 x mb 8 on
-    a 15.75G-HBM v5e; pre-round builder note, not re-measured):
-    - "dots": every no-batch-dim dot output (~22G — OOMs one v5e chip next
-      to f32 optimizer state; the right default on pods where FSDP shards
-      the state).
+    Policies, by what the backward keeps (what each costs on the chip is in
+    docs/performance.md, "Remat policy guide": only "nothing" is measured):
+    - "dots": every no-batch-dim dot output.
     - "ctx": ONLY the attention context (the post-softmax [B,S,nh*hd]
       tensor, named "attn_ctx") + scan-carry layer boundaries. Backward
-      re-runs the cheap projection/FFN matmuls but never the O(S^2)
-      attention — the sweet spot on a single chip.
+      re-runs the projection/FFN matmuls but never the O(S^2) attention.
     - "ctx_offload": same saves, parked in pinned host RAM.
     - "offload": dot saves of "dots" parked in pinned host RAM.
-    - "nothing": full recompute.
+    - "nothing": full recompute; what both benchmark cells run.
     """
     if cfg.remat_policy == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable

@@ -17,8 +17,8 @@ paged decode/prefill buckets — routes its compiles through
   top of the live buffers);
 * compile wall time and invocation counts.
 
-That census turns MFU from an offline ``bench.py`` number into a
-continuous per-sync-window gauge: :class:`CostWindow` deltas the census
+That census makes MFU a continuous per-sync-window gauge beside the
+benchmark's offline one (``benchmark/flops.py``): :class:`CostWindow` deltas the census
 call counts over the trainer's existing sync cadence and divides achieved
 FLOPs/bytes by the window wall and the device peaks
 (``utils/device.py::get_device_peak_flops`` /
@@ -448,9 +448,9 @@ class CostCensus:
 
     def latest(self, site: str) -> Optional[ProgramCost]:
         """The most recently *recorded* program for a site. Recency is a
-        per-record() stamp, not dict insertion order: a sweep that revisits
-        an earlier bucket re-records it in place, and mfu_sweep-style
-        callers need THAT record, not the last-inserted one."""
+        per-record() stamp, not dict insertion order: a caller that revisits
+        an earlier bucket re-records it in place and needs THAT record, not
+        the last-inserted one."""
         with self._lock:
             out = None
             for (s, _b), rec in self._programs.items():
@@ -516,7 +516,7 @@ class CostWindow:
     ``begin()`` snapshots the per-program call counts; ``end()`` multiplies
     each program's new invocations by its census FLOPs/bytes and divides by
     the elapsed wall and the per-device peaks — the continuous analogue of
-    ``bench.py``'s offline ``flops / dt / peak``. Census FLOPs are already
+    an offline ``flops / dt / peak``. Census FLOPs are already
     per device (partitioned module), so no world-size factor appears.
     ``exclude_sites`` (default :data:`DIAGNOSTIC_SITES`) keeps diagnostic
     programs out of the utilization math; an explicit ``sites`` allowlist

@@ -23,8 +23,7 @@ closes the gap three ways:
    phase) in the output dir every sync window. A *wedged* rank — the
    failure mode in which no in-band exchange can run — is diagnosable from
    OUTSIDE the process: its heartbeat age keeps growing while its
-   neighbors' stay fresh. ``scripts/fleet.py`` and the bench's stall JSON
-   read these. The "rank" may also be a string — the serving router's
+   neighbors' stay fresh. ``scripts/fleet.py`` reads these. The "rank" may also be a string — the serving router's
    per-replica pump workers beat as ``heartbeat-<rid>.json`` (phase
    ``serve_pump``), so a replica wedged inside ``engine.step()`` is
    nameable from outside the process exactly like a wedged trainer rank.
@@ -72,8 +71,7 @@ def _rank_sort_key(rank: Any):
     return (1, rank) if isinstance(rank, str) else (0, rank)
 
 #: heartbeat older than this many seconds reads as stale in
-#: :func:`heartbeat_ages` (callers may pass their own threshold — the bench
-#: stall JSON uses its watchdog timeout)
+#: :func:`heartbeat_ages` (callers may pass their own threshold)
 DEFAULT_STALE_S = 120.0
 
 
@@ -147,8 +145,7 @@ def heartbeat_ages(dirpath: str, now: Optional[float] = None,
                    stale_after_s: float = DEFAULT_STALE_S
                    ) -> List[Dict[str, Any]]:
     """Per-rank heartbeat freshness: ``{rank, age_s, stale, global_step,
-    step_time_s, phase}`` rows — the table the bench stall JSON and
-    ``/debug/fleet`` embed so a wedged rank is *named*, not inferred."""
+    step_time_s, phase}`` rows — the table ``/debug/fleet`` embeds so a wedged rank is *named*, not inferred."""
     now = time.time() if now is None else now
     rows = []
     for doc in read_heartbeats(dirpath):

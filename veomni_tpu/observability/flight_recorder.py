@@ -215,8 +215,7 @@ class FlightRecorder:
                     )
                     continue
                 doc[k] = v
-        # the dump dir may be declared-but-not-created (bench's lazy per-pid
-        # default): a missing parent must not cost the artifact
+        # the dump dir may be declared-but-not-created: a missing parent must not cost the artifact
         parent = os.path.dirname(path)
         if parent:
             os.makedirs(parent, exist_ok=True)

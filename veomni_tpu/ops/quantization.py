@@ -190,8 +190,8 @@ def kv_block_nbytes(num_layers: int, block_size: int, num_kv_heads: int,
                     head_dim: int, *, kv_quant: str = "none",
                     dtype_bytes: int = 4) -> int:
     """Bytes ONE pool block (k + v, all layers) occupies in the given
-    storage mode — the sizing primitive bench uses to build equal-byte
-    pools across quantization modes without allocating either."""
+    storage mode — the sizing primitive for equal-byte pools across
+    quantization modes without allocating either."""
     rows = num_layers * block_size * num_kv_heads
     if kv_quant == "int8":
         per_pool = rows * (head_dim * 1 + 4)  # int8 payload + f32 scale/row

@@ -15,9 +15,9 @@ because they are *control-plane* actions (``Router.kill_replica``,
 drawn AFTER every fault and kill draw, so adding ``publishes=N`` to a
 plan never moves the faults/kills an existing seed pins.
 
-:func:`run_chaos_soak` is the shared storm driver behind the bench's
-``BENCH_SERVE_CHAOS=<seed>`` leg, the tier-1 ``scripts/chaos_smoke.py``
-stage and the chaos tests: it replays an open-loop arrival schedule
+:func:`run_chaos_soak` is the shared storm driver behind the storm drill's
+chaos leg (``resilience/storm.py``, which the tier-1
+``scripts/chaos_smoke.py`` stage runs) and the chaos tests: it replays an open-loop arrival schedule
 through a fresh router while the plan's faults fire, lets the
 self-healing machinery (wedge detection -> respawn -> probation,
 ``serving/router.py``) do its job, then drives a bounded *restore* phase
@@ -36,7 +36,7 @@ checks the fleet invariants:
 
 Layering: this module is resilience-layer and imports serving types only
 inside the soak driver, so arming/parsing plans stays importable from
-anywhere (bench, scripts, tests) without dragging in the engine.
+anywhere (scripts, tests) without dragging in the engine.
 """
 
 from __future__ import annotations
@@ -104,7 +104,7 @@ class ChaosPlan:
         return sorted(self.publishes, key=lambda p: p.at_s)
 
     def to_doc(self) -> Dict[str, Any]:
-        """JSON-ready canonical form (bench artifacts, determinism pin)."""
+        """JSON-ready canonical form (soak reports, determinism pin)."""
         return {
             "seed": self.seed,
             "duration_s": self.duration_s,

@@ -232,7 +232,7 @@ class InferenceEngine:
     Threading contract (lock-discipline audit, docs/static-analysis.md):
     the engine holds no locks because only the pump thread touches its
     state. Anything another thread needs — the exporter's HTTP handlers,
-    bench readers — goes through the thread-safe surfaces the engine
+    a load driver — goes through the thread-safe surfaces the engine
     *publishes into*: the metrics registry gauges/counters and the
     RequestTracer (both internally locked). Do not hand live engine or
     scheduler attributes to another thread."""
@@ -1191,8 +1191,7 @@ class InferenceEngine:
                                          len(seq.generated))
             if tl is not None:
                 # surface the lifecycle rollup on the output the caller
-                # already holds (bench/SLO tooling reads these, not the
-                # tracer)
+                # already holds (SLO tooling reads these, not the tracer)
                 out.queue_wait_s = tl.queue_wait_s
                 out.tpot_s = tl.tpot_s
                 out.preemptions = tl.preemptions
@@ -1254,7 +1253,7 @@ class InferenceEngine:
                 "cached_tokens": float(self._cached_tokens_total),
                 "prompt_tokens": float(self._prompt_tokens_total),
                 "prefill_chunks": float(self._prefill_chunks_total),
-                # speculative decoding: lifetime totals (bench deltas) +
+                # speculative decoding: lifetime totals (callers take deltas) +
                 # the window acceptance rate (drafts the verify step kept)
                 "spec_proposed": float(self._spec_proposed_total),
                 "spec_accepted": float(self._spec_accepted_total),
@@ -1262,9 +1261,9 @@ class InferenceEngine:
                     self._win_spec_accepted
                     / max(1, self._win_spec_proposed)
                 ),
-                # QoS / overload outcomes (lifetime totals; bench takes
-                # deltas) + the window goodput rate — tokens from requests
-                # that met their deadline, the overload bench's headline
+                # QoS / overload outcomes (lifetime totals; the storm drill
+                # takes deltas) + the window goodput rate — tokens from
+                # requests that met their deadline
                 "rejected": float(self._rejected_total),
                 "shed_tokens": float(self._shed_tokens_total),
                 "deadline_misses": float(self._deadline_miss_total),

@@ -21,8 +21,7 @@ the requested storage mode, so the quantize-on-write and
 dequantize-in-attend code under test is exactly the code the engine runs.
 
 ``tests/tools/quality_gate.py`` wraps this with the pinned repo-wide
-bounds; ``bench.py``'s kv-quant sweep records the same stats in its JSON
-line so a perf run can never silently trade quality for capacity.
+bounds, so a capacity win is never accepted without its quality cost.
 """
 
 from __future__ import annotations

@@ -499,8 +499,8 @@ class Router:
         return h
 
     def kill_replica(self, rid: str, reason: str = "killed") -> None:
-        """Simulate a replica crash (tests, the bench's mid-storm kill
-        drill): the replica is drained out of rotation exactly as if its
+        """Simulate a replica crash (tests, the chaos soak's mid-storm
+        kills): the replica is drained out of rotation exactly as if its
         pump had raised — stranded requests re-dispatched or surfaced
         terminal, never hung."""
         self._on_replica_failure(self.replicas[rid], RuntimeError(reason))

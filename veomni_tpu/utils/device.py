@@ -70,7 +70,7 @@ _PEAKS = {
 # The CPU has no published peak. These nominal values exist only so the cost
 # census can form a finite machine balance (roofline verdicts in its tests);
 # nothing reported under a device metric's name (MFU, TFLOP/s, utilisation)
-# may be derived from them — see EnvironMeter.step and bench.py.
+# may be derived from them — see EnvironMeter.step.
 _CPU_NOMINAL = (1e12, 1e11, 1e10)
 
 

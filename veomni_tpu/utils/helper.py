@@ -103,11 +103,11 @@ def dump_thread_stacks() -> str:
 
 
 class Watchdog:
-    """Stall detector on a daemon thread (promoted from ``bench.py:_watchdog``
-    so the trainer supervisor and the bench share one implementation).
+    """Stall detector on a daemon thread (the train-loop supervisor's hang
+    watchdog).
 
     Arms at :meth:`start`; :meth:`pet` resets the deadline (call once per unit
-    of expected progress — a train step, a bench phase). If ``timeout_s``
+    of expected progress — a train step). If ``timeout_s``
     elapses with no pet, the dog dumps every thread's stack via
     :func:`dump_thread_stacks`, writes a flight-recorder post-mortem
     (``postmortem-<rank>.json`` — the stack dump alone loses the event
@@ -124,8 +124,7 @@ class Watchdog:
     petting ride two ``threading.Event`` objects; ``stall_count`` /
     ``last_dump`` / ``last_postmortem_path`` are written only by the
     watchdog thread and read by observers AFTER a stall is signalled
-    (bench reads them from ``on_stall``, which the watchdog thread itself
-    invokes) — single-writer, causally-ordered reads.
+    (from ``on_stall``, which the watchdog thread itself invokes) — single-writer, causally-ordered reads.
     """
 
     # the post-mortem write gets its own deadline: when the stall IS a hung
@@ -194,7 +193,7 @@ class Watchdog:
             )
             # the stack dump says WHERE each thread is; the flight recorder
             # says WHAT the run was doing in the seconds before. Dump BEFORE
-            # on_stall so the callback (bench's stall JSON) can reference
+            # on_stall so the callback can reference
             # the artifact path — which means THIS stall must be put on the
             # ring here, not by on_stall, or the artifact it triggers is the
             # one dump with no record of it. Never fatal — dump() is

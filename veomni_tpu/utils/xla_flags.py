@@ -7,7 +7,7 @@ compute when these flags are on).
 The flags travel in ``LIBTPU_INIT_ARGS``, which only the TPU runtime reads:
 ``XLA_FLAGS`` is parsed by every backend and jaxlib aborts the process on a
 ``--xla_tpu_*`` flag it does not know. Must run BEFORE the first JAX backend
-initialization; entrypoints (tasks/*, scripts/serve.py, bench.py,
+initialization; entrypoints (tasks/*, scripts/serve.py,
 chip_smoke.py) call ``apply_performance_flags()`` first thing. Disable with
 ``VEOMNI_XLA_PERF_FLAGS=0``.
 """
