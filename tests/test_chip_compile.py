@@ -1,8 +1,9 @@
 """What can be known about the chip without the chip.
 
 1. AOT compiles for a DESCRIBED ``v5e:2x2`` (the TPU compiler is installed;
-   no device is attached): both Pallas kernels, forward and backward, at the
-   widths ``chip_smoke.py`` runs them at, and the dense train step at the
+   no device is attached): the Pallas kernels, forward and backward, at the
+   widths ``chip_smoke.py`` and the benchmark's cells run them at, and the
+   dense train step at the
    smoke's size with the compiler's memory analysis held against 16 GiB.
    Interpret mode and the attention impl are steered here, in the test: the
    program picks both from ``jax.default_backend()``, which is the CPU.
@@ -106,11 +107,11 @@ def _kernel_instructions(text):
     """{kernel name: custom calls named after it} in a compiled text."""
     import re
 
-    from veomni_tpu.observability.scopes import KERNEL_NAMES
+    from veomni_tpu.observability.scopes import ALL_KERNEL_NAMES
 
     found = re.findall(r"^\s*(?:ROOT\s+)?%?([a-z_]+)\.\d+ = .* custom-call\(.*"
                        r'custom_call_target="tpu_custom_call"', text, re.MULTILINE)
-    assert set(found) <= set(KERNEL_NAMES), found
+    assert set(found) <= set(ALL_KERNEL_NAMES), found
     return {k: found.count(k) for k in set(found)}
 
 
@@ -188,6 +189,79 @@ def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
     assert text.count("tpu_custom_call") == 1
 
 
+# the shapes the q/k norm + rope op is handed: the qwen cell's, one long row,
+# gemma's 256-wide heads under a zero-centred weight, rope alone (llama: no
+# qk-norm), and MQA at a sequence only 128 divides
+QK_NORM_ROPE_CALLS = {
+    "cell": dict(b=4, s=4096, hq=16, hkv=8, d=128, normed=True),
+    "long": dict(b=1, s=32768, hq=16, hkv=8, d=128, normed=True),
+    "gemma": dict(b=2, s=2048, hq=8, hkv=4, d=256, normed=True, zero_centered=True),
+    "rope_alone": dict(b=2, s=4096, hq=32, hkv=8, d=128, normed=False),
+    "s384": dict(b=2, s=384, hq=4, hkv=1, d=128, normed=True),
+}
+
+
+def _qk_norm_rope_args(c, described):
+    b, s, d = c["b"], c["s"], c["d"]
+    q, k = described((b, s, c["hq"] * d)), described((b, s, c["hkv"] * d))
+    table = described((b, s, d))
+    w = described((d,)) if c["normed"] else None
+    return q, k, table, table, w, w
+
+
+def _qk_norm_rope_fns(zero_centered=False):
+    from veomni_tpu.ops.pallas.qk_norm_rope import qk_norm_rope
+
+    def fwd(q, k, cos, sin, wq, wk):
+        with jax.named_scope("attn.qkv"):  # as the model calls it
+            return qk_norm_rope(q, k, cos, sin, wq, wk, 1e-6, zero_centered)
+
+    def loss(*args):
+        q, k = fwd(*args)
+        return q.astype(jnp.float32).sum() + k.astype(jnp.float32).sum()
+
+    return fwd, loss
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("call", list(QK_NORM_ROPE_CALLS))
+def test_qk_norm_rope_lowers_for_v5e(v5e, on_chip_kernels, call, direction):
+    """One custom call each way, named after the kernel: the backward needs
+    nothing of the forward's output, so the gradient alone holds no forward."""
+    c = QK_NORM_ROPE_CALLS[call]
+    args = _qk_norm_rope_args(c, lambda shape: _described(v5e[0], shape, jnp.bfloat16))
+    fwd, loss = _qk_norm_rope_fns(c.get("zero_centered", False))
+    wrt = (0, 1, 4, 5) if c["normed"] else (0, 1)
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=wrt)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert _kernel_instructions(text) == {"qk_norm_rope_" + direction: 1}
+
+
+def test_qk_norm_rope_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
+    """FSDP 2 x Ulysses 2 on four chips: the op sits under GSPMD with its
+    activations sharded (dp, sp, None), and runs per device in a shard_map
+    over exactly that; the weights' gradients are summed over the mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from veomni_tpu.parallel import init_parallel_state, use_parallel_state
+
+    ps = init_parallel_state(devices=v5e, ulysses_size=2)
+    rows = NamedSharding(ps.mesh, P(ps.dp_axes, ps.sp_axes))
+    whole = NamedSharding(ps.mesh, P())
+
+    def described(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=rows if len(shape) == 3 else whole)
+
+    args = _qk_norm_rope_args(QK_NORM_ROPE_CALLS["cell"], described)
+    _, loss = _qk_norm_rope_fns()
+    with use_parallel_state(ps):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 4, 5))).lower(*args).compile().as_text()
+    assert _kernel_instructions(text) == {"qk_norm_rope_bwd": 1}
+    assert "bf16[2,2048,2048]" in text  # a device's share of q: half the batch, half the rows
+    assert "all-reduce" in text         # the weights' gradients
+
+
 # the smoke's shapes (a fused gate_up of twice the width, as
 # models/deepseek_v4.py's; E 128 at the Qwen3-30B-A3B widths), and the held
 # experts' buffer of the joyai_llm_flash.train_packed_8k cell (16 of 256
@@ -258,7 +332,8 @@ def _compile_smoke_step(v5e):
         # on the chip the registry resolves attention to pallas_flash by
         # platform; here the platform is the CPU, so the test pins it
         model = build_foundation_model(
-            config=cfg, ops_implementation={"attention": "pallas_flash"})
+            config=cfg, ops_implementation={"attention": "pallas_flash",
+                                            "qk_norm_rotary": "pallas"})
         opt = build_optimizer(
             model.abstract(), optimizer=t.optimizer,
             lr=build_lr_scheduler(t.lr_decay_style, lr=t.lr, train_steps=t.train_steps),
@@ -286,13 +361,15 @@ def _compile_smoke_step(v5e):
 
 
 def test_smoke_train_step_fits_one_v5e(smoke_step):
-    """The attention kernel is in it, and arguments + temporaries leave room
-    in 16 GiB."""
+    """The kernels are in it, and arguments + temporaries leave room in
+    16 GiB."""
     compiled = smoke_step
-    # fwd, then the recomputed fwd + dkv + dq of the backward
+    # a layer body's forward, then the recomputed forward and the backward:
+    # flash fwd, fwd + dkv + dq; the q/k norm + rope fwd, fwd + bwd
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 4
-    assert _kernel_instructions(text) == {"flash_fwd": 2, "flash_bwd_dkv": 1, "flash_bwd_dq": 1}
+    assert text.count("tpu_custom_call") == 7
+    assert _kernel_instructions(text) == {"flash_fwd": 2, "flash_bwd_dkv": 1, "flash_bwd_dq": 1,
+                                          "qk_norm_rope_fwd": 2, "qk_norm_rope_bwd": 1}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 6 * GIB  # f32 params + AdamW moments
     # 1 GiB under the 16 GiB line for what the process holds besides

@@ -117,6 +117,7 @@ def test_reference_recipe_translates(tmp_path):
     assert a.model.ops_implementation == {
         "fused_linear_cross_entropy": "xla_chunked",
         "rms_norm": "xla",
+        "qk_norm_rotary": "xla",  # the eager norm covers the op that joins it to rope
     }
     # data block
     assert a.data.dataset_type == "iterable"

@@ -97,6 +97,11 @@ def _translate_model(model: Dict[str, Any], notes: List[str]) -> None:
             else:
                 _warn(notes, f"model.ops_implementation.{key}",
                       "unrecognized op field, ignored")
+        # an eager norm or rope also means the op that joins the two in the
+        # attention block (ops/qk_norm_rotary.py)
+        pins = model["ops_implementation"]
+        if "xla" in (pins.get("rms_norm"), pins.get("rotary")):
+            pins["qk_norm_rotary"] = "xla"
     lora = model.pop("lora_config", None)
     if isinstance(lora, dict):
         out: Dict[str, Any] = {}

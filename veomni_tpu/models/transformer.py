@@ -459,20 +459,14 @@ def _standard_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, wi
             q = q + lp["q_bias"]
             kk = kk + lp["k_bias"]
             v = v + lp["v_bias"]
-        q = q.reshape(b, s, cfg.num_attention_heads, cfg.head_dim)
-        kk = kk.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
+        # the q/k norm and rope in one op, from the projections' own outputs
+        # to the [B, S, H, D] the attention op takes
+        q, kk = ops.qk_norm_rotary(
+            q, kk, cos, sin,
+            lp["q_norm"] if cfg.qk_norm else None, lp["k_norm"] if cfg.qk_norm else None,
+            eps=cfg.rms_norm_eps, zero_centered=cfg.norm_zero_centered, head_dim=cfg.head_dim,
+        )
         v = v.reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
-        if cfg.qk_norm:
-            q = _norm(q, lp["q_norm"], cfg)
-            kk = _norm(kk, lp["k_norm"], cfg)
-        rot_dim = cos.shape[-1]
-        if rot_dim < cfg.head_dim:
-            # partial rotary (glm4_moe): rope covers the leading dims only
-            q_rot, kk_rot = ops.apply_rotary(q[..., :rot_dim], kk[..., :rot_dim], cos, sin)
-            q = jnp.concatenate([q_rot, q[..., rot_dim:]], axis=-1)
-            kk = jnp.concatenate([kk_rot, kk[..., rot_dim:]], axis=-1)
-        else:
-            q, kk = ops.apply_rotary(q, kk, cos, sin)
     scale = (
         cfg.query_pre_attn_scalar ** -0.5 if cfg.query_pre_attn_scalar
         else cfg.head_dim ** -0.5

@@ -46,7 +46,16 @@ MODULE_SCOPES = (
                      # token, the joining projection, one decoder layer, head loss
 )
 
+# Kernels a trace reader files by their NAME (``benchmark/scopes.py::KERNELS``
+# is the yardstick's copy of this tuple, scope and phase beside each name,
+# and its own test holds the two equal).
 KERNEL_NAMES = (
     "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",   # ops/pallas/flash_attention.py
     "gmm_fwd", "gmm_dlhs", "gmm_drhs",              # ops/pallas/grouped_gemm.py
 )
+# Kernels a reader files as it files a fusion, by the scope in their
+# ``op_name`` (``attn.qkv`` here): named in every trace, in no reader's table.
+SCOPED_KERNEL_NAMES = (
+    "qk_norm_rope_fwd", "qk_norm_rope_bwd",         # ops/pallas/qk_norm_rope.py
+)
+ALL_KERNEL_NAMES = KERNEL_NAMES + SCOPED_KERNEL_NAMES

@@ -2,13 +2,15 @@
 
 Reference: ``veomni/ops/`` — KERNEL_REGISTRY + OpSlot dispatch with per-op
 implementation selection (eager vs Triton vs external CUDA). Here the impl
-axes are {"xla", "pallas"}; XLA already fuses most elementwise chains, so
-Pallas is reserved for the genuinely hot ops (flash attention, grouped GEMM).
+axes are {"xla", "pallas"}; Pallas is for the ops the chip showed to be hot
+(flash attention, the grouped GEMM, and the q/k norm + rope chain, which XLA
+does not fuse the way one would hope: ``ops/pallas/qk_norm_rope.py``).
 """
 
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY, KernelSpec, resolve_op
 from veomni_tpu.ops import rms_norm as _rms_norm  # noqa: F401 register
 from veomni_tpu.ops import rotary as _rotary  # noqa: F401
+from veomni_tpu.ops import qk_norm_rotary as _qk_norm_rotary  # noqa: F401
 from veomni_tpu.ops import swiglu as _swiglu  # noqa: F401
 from veomni_tpu.ops import attention as _attention  # noqa: F401
 from veomni_tpu.ops import cross_entropy as _cross_entropy  # noqa: F401
@@ -21,6 +23,7 @@ from veomni_tpu.ops import pallas as _pallas  # noqa: F401  (registers TPU kerne
 rms_norm = _rms_norm.rms_norm
 apply_rotary = _rotary.apply_rotary
 rotary_tables = _rotary.rotary_tables
+qk_norm_rotary = _qk_norm_rotary.qk_norm_rotary
 swiglu = _swiglu.swiglu
 attention = _attention.attention
 fused_linear_cross_entropy = _cross_entropy.fused_linear_cross_entropy
@@ -49,6 +52,7 @@ __all__ = [
     "rms_norm",
     "apply_rotary",
     "rotary_tables",
+    "qk_norm_rotary",
     "swiglu",
     "attention",
     "fused_linear_cross_entropy",

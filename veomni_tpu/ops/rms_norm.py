@@ -1,8 +1,14 @@
 """RMSNorm. Reference: ``veomni/ops/kernels/rms_norm/`` (Liger/Triton impls).
 
-On TPU, XLA fuses the reduction+rsqrt+scale chain into neighboring ops; a
-Pallas kernel buys nothing here, so "xla" is the only impl (the reference's
-batch-invariant Triton variant is moot — XLA is batch-invariant by design).
+"xla" is the only impl of the stand-alone norm (the reference's
+batch-invariant Triton variant is moot: XLA is batch-invariant by design).
+That XLA fuses the reduction + rsqrt + scale chain into its neighbours was
+written before the chip. What the chip read (PERF.md, PR 32): the q/k norm
+in front of rope wrote an f32 copy of the q projection's output and read it
+back between fusions, and the norm + rope chain of the qwen cell moved about
+six times the bytes it needs. That chain is now one op with a Pallas kernel each
+way (``ops/qk_norm_rotary.py``); the layer norms and MLA's latent norms,
+which feed a matmul, stay here and have not been read apart from it.
 """
 
 from __future__ import annotations

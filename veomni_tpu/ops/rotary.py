@@ -1,7 +1,14 @@
 """Rotary position embeddings (llama-style half-rotation, position-id driven).
 
 Reference: ``veomni/ops/kernels/rotary/`` — Liger / deterministic-Triton
-impls. Plain XLA here (fuses into the attention projections).
+impls. Plain XLA here. It does not fuse into the attention projections on a
+v5e, as this docstring once said: at 16 + 8 heads of 128 the compiled chain
+holds ``_rotate_half``'s two halves as 64-lane arrays, each padded to a
+whole 128-lane tile, f32 copies of q and k between fusions and a relayout of
+the whole tensor (PERF.md, PR 32). The GQA/MHA attention block therefore
+calls ``ops.qk_norm_rotary``, whose Pallas impl rolls whole heads and folds
+the sign into the sine table; ``apply_rotary`` serves MLA's 64-wide slice,
+the interleaved layout, partial rotary and the VL / DiT models' own calls.
 """
 
 from __future__ import annotations
