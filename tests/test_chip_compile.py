@@ -129,6 +129,9 @@ def _described(device, shape, dtype):
 # rope, v of 128: the joyai_llm_flash.train_packed_8k cell's call)
 FLASH_CALLS = {
     "mla": dict(b=2, s=8192, hq=32, hkv=32, d=192, dv=128, causal=True, segments=True),
+    # granite-4.0-h-micro's attention layer: 32 / 8 heads of 64, one row of
+    # 8192, the configured scale 1/64 (the granite_4_0_h_micro cell's call)
+    "nope64": dict(b=1, s=8192, hq=32, hkv=8, d=64, causal=True, segments=True, scale=1 / 64),
     "cell": dict(chip_smoke.FLASH_SHAPE, causal=True, segments=True),
     "vision": dict(b=2, s=2048, hq=16, hkv=16, d=64, causal=False, segments=True),
     "long": dict(b=1, s=32768, hq=16, hkv=8, d=128, causal=True, segments=True),
@@ -154,7 +157,8 @@ def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, call, direction, c
         # named after the kernel alone only below some named scope (bare
         # under jax.grad it comes out as jvp_flash_fwd_)
         with jax.named_scope("attn.flash"):
-            return flash_attention(q, k, v, segment_ids=seg, causal=c["causal"])
+            return flash_attention(q, k, v, segment_ids=seg, causal=c["causal"],
+                                   softmax_scale=c.get("scale"))
 
     def loss(q, k, v, seg):
         return fwd(q, k, v, seg).astype(jnp.float32).sum()
@@ -167,6 +171,35 @@ def test_flash_attention_lowers_for_v5e(v5e, on_chip_kernels, call, direction, c
     want = {"fwd": {"flash_fwd": 1}, "bwd": {"flash_fwd": 1, "flash_bwd_dkv": 1,
                                              "flash_bwd_dq": 1}}[direction]
     assert _kernel_instructions(text) == want
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_ssd_scan_lowers_for_v5e_within_a_chunks_memory(v5e, direction):
+    """The state-space scan at the granite cell's shapes (one row of 8192, 64
+    heads of 64, state 128, chunks of 256): it compiles for the chip, and its
+    temporaries stay a chunk's, not the 0.5 GB a whole ``[H, S/c, c, c]`` f32
+    decay matrix would take (forward AND backward)."""
+    from veomni_tpu import ops
+
+    b, s, h, p, g, n = 1, 8192, 64, 64, 1, 128
+    x = _described(v5e[0], (b, s, h, p), jnp.bfloat16)
+    dt = _described(v5e[0], (b, s, h), jnp.float32)
+    head = _described(v5e[0], (h,), jnp.float32)
+    bc = _described(v5e[0], (b, s, g, n), jnp.bfloat16)
+    seg = _described(v5e[0], (b, s), jnp.int32)
+
+    def fwd(x, dt, a, bm, cm, d, seg):
+        with jax.named_scope("ssm.scan"):
+            return ops.ssd_scan(x, dt, a, bm, cm, d, seg, 256)
+
+    def loss(*args):
+        return fwd(*args).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=tuple(range(6)))
+    compiled = jax.jit(fn).lower(x, dt, head, bc, bc, head, seg).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # impl xla: no kernel yet
+    whole_decay = h * s * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < whole_decay // 2
 
 
 def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
@@ -308,7 +341,7 @@ def smoke_step(v5e):
         return _compile_smoke_step(v5e)
 
 
-def _compile_smoke_step(v5e):
+def _compile_smoke_step(v5e, config=chip_smoke.TRAIN_CONFIG):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from veomni_tpu.arguments import VeOmniArguments, parse_args
@@ -319,7 +352,7 @@ def _compile_smoke_step(v5e):
     from veomni_tpu.train import build_train_state, build_train_step
     from veomni_tpu.train.train_step import resolve_state_shardings
 
-    args = parse_args(VeOmniArguments, [os.path.join(REPO, chip_smoke.TRAIN_CONFIG)])
+    args = parse_args(VeOmniArguments, [os.path.join(REPO, config)])
     t = args.train
     overrides = dict(args.model.config_overrides)
     cfg = build_config(
@@ -374,6 +407,19 @@ def test_smoke_train_step_fits_one_v5e(smoke_step):
     assert mem.argument_size_in_bytes > 6 * GIB  # f32 params + AdamW moments
     # 1 GiB under the 16 GiB line for what the process holds besides
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * GIB
+
+
+def test_hybrid_state_space_train_step_fits_one_v5e(v5e, on_chip_kernels):
+    """The step of configs/text/granite_4_0_h_micro_v5e.yaml (the benchmark's
+    third cell: 772 M parameters at 16 bytes, ONE row of 8192): its one
+    attention layer runs the flash kernels, the nine scans are XLA, and
+    arguments + temporaries leave room in the 15.75 GiB a v5e gives a program."""
+    compiled = _compile_smoke_step(v5e, "configs/text/granite_4_0_h_micro_v5e.yaml")
+    assert _kernel_instructions(compiled.as_text()) == {
+        "flash_fwd": 2, "flash_bwd_dkv": 1, "flash_bwd_dq": 1}
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 8.5 * GIB  # f32 params + AdamW moments
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5 * GIB
 
 
 # what carries no scope of the taxonomy in the compiled step, by the last

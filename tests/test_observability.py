@@ -214,7 +214,30 @@ def test_merge_chrome_trace_monotonic_pid_remap(tmp_path):
 
 
 # ------------------------------------------------------------------ goodput
-def test_goodput_fractions_sum_to_one(spans_on):
+class _Clock:
+    """A clock the test moves: what ``time.sleep`` did, without the wall."""
+
+    def __init__(self):
+        self.ns = 1_000_000_000
+
+    def advance(self, seconds: float) -> None:
+        self.ns += int(seconds * 1e9)
+
+    def perf_counter_ns(self) -> int:
+        return self.ns
+
+    def perf_counter(self) -> float:
+        return self.ns * 1e-9
+
+
+def test_goodput_fractions_sum_to_one(spans_on, monkeypatch):
+    from veomni_tpu.observability import goodput as goodput_mod
+
+    clock = _Clock()
+    # the spans' and the tracker's clock: shares of a window under six loaded
+    # workers are no property of the code
+    monkeypatch.setattr(spans_mod, "time", clock)
+    monkeypatch.setattr(goodput_mod, "time", clock)
     reg = MetricsRegistry()
     tracker = GoodputTracker(reg)
     # synthetic step built from the exact spans the trainer emits — but fed
@@ -224,18 +247,18 @@ def test_goodput_fractions_sum_to_one(spans_on):
     try:
         tracker.begin_window()
         with span("data.wait"):
-            time.sleep(0.03)
+            clock.advance(0.03)
         with span("data.ship"):
-            time.sleep(0.005)
+            clock.advance(0.005)
         with span("step.dispatch"):
-            time.sleep(0.01)
+            clock.advance(0.01)
         with span("host.callbacks"):
             with span("ckpt.save"):
-                time.sleep(0.01)
-            time.sleep(0.005)
+                clock.advance(0.01)
+            clock.advance(0.005)
         with span("step.backpressure"):
-            time.sleep(0.015)  # the loop's wait for the oldest in-flight step
-        time.sleep(0.005)  # unattributed (the sync fetch)
+            clock.advance(0.015)  # the loop's wait for the oldest in-flight step
+        clock.advance(0.005)  # unattributed (the sync fetch)
         w = tracker.end_window()
     finally:
         spans_mod.get_registry = prev

@@ -84,6 +84,27 @@ def _register_qwen3_next():
 _register_qwen3_next()
 
 
+def _register_granite_hybrid():
+    from veomni_tpu.models import granite_hybrid as gh
+
+    MODEL_REGISTRY.register(
+        "granitemoehybrid",
+        ModelFamily(
+            model_type="granitemoehybrid",
+            init_params=gh.init_params,
+            abstract_params=gh.abstract_params,
+            loss_fn=gh.loss_fn,
+            forward_logits=gh.forward_logits,
+            hf_to_params=gh.hf_to_params,
+            save_hf_checkpoint=gh.save_hf_checkpoint,
+            parallel_plan_fn=gh.parallel_plan,
+        ),
+    )
+
+
+_register_granite_hybrid()
+
+
 def _register_deepseek_v4():
     from veomni_tpu.models import deepseek_v4 as dsv4
 
@@ -404,6 +425,11 @@ def build_config(model_type: str = "", **overrides):
         text = dict(overrides.pop("text", {}) or {})
         text.update(overrides)
         return VLMConfig(model_type=model_type, text=text, **vlm_kw)
+    if model_type == "granitemoehybrid":
+        # config.json's spellings (embedding_multiplier, ...) are taken too
+        for theirs, ours in TransformerConfig._GRANITE_HYBRID_RENAMED.items():
+            if theirs in overrides:
+                overrides[ours] = overrides.pop(theirs)
     if model_type in TransformerConfig._DEEPSEEK_V3_DIALECT:
         for key, value in TransformerConfig.deepseek_defaults(model_type).items():
             overrides.setdefault(key, value)

@@ -108,6 +108,22 @@ class TransformerConfig:
     linear_conv_kernel_dim: int = 4
     full_attention_interval: int = 4
     attn_output_gate: bool = False      # full-attn layers: out *= sigmoid(gate)
+    # granitemoehybrid (models/granite_hybrid.py): Mamba-2 state-space layers
+    # and attention layers in the order ``layer_types`` gives ("mamba" |
+    # "attention"), its period found from the list itself
+    mamba_n_heads: int = 0              # 0 -> no state-space layers
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    position_embedding_type: str = "rope"  # "nope": attention with no rotary
+    attention_multiplier: float = 0.0   # softmax scale; 0 -> head_dim ** -0.5
+    residual_multiplier: float = 1.0    # h += multiplier * sublayer(norm(h))
+    logits_scaling: float = 1.0         # logits = head(h) / logits_scaling
     # EP dispatch capacity factor; <= 0 means dropless (see parallel/moe.py)
     moe_capacity_factor: float = 0.0
     # HF checkpoint expert-tensor layout: "" = auto by model_type
@@ -207,6 +223,28 @@ class TransformerConfig:
         return dict(
             scoring_func="softmax" if model_type == "deepseek_v2" else "sigmoid",
             norm_topk_prob=True, router_aux_loss_coef=0.0, rope_interleave=True)
+
+    _GRANITE_HYBRID_FIELDS = (
+        "mamba_n_heads mamba_d_head mamba_d_state mamba_n_groups mamba_d_conv "
+        "mamba_expand mamba_chunk_size mamba_conv_bias mamba_proj_bias "
+        "position_embedding_type attention_multiplier residual_multiplier "
+        "logits_scaling"
+    ).split()
+    # config.json's spelling -> the field it sets here
+    _GRANITE_HYBRID_RENAMED = {
+        "embedding_multiplier": "embed_scale",
+        "shared_intermediate_size": "intermediate_size",  # the one MLP a layer has
+        "num_local_experts": "num_experts",
+    }
+
+    @classmethod
+    def granite_hybrid_fields(cls, hf: Dict[str, Any]) -> Dict[str, Any]:
+        """The granitemoehybrid keys of ``hf`` (a config.json, or overrides in
+        its spelling) as this class's fields."""
+        kw = {k: hf[k] for k in cls._GRANITE_HYBRID_FIELDS if hf.get(k) is not None}
+        kw.update({ours: hf[theirs] for theirs, ours in cls._GRANITE_HYBRID_RENAMED.items()
+                   if hf.get(theirs) is not None})
+        return kw
 
     @classmethod
     def from_hf_config(cls, hf: Dict[str, Any], **overrides) -> "TransformerConfig":
@@ -324,6 +362,8 @@ class TransformerConfig:
                 router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0)
                 if hf.get("output_router_logits") else 0.0,
             )
+        if mt == "granitemoehybrid":
+            kw.update(cls.granite_hybrid_fields(hf))
         if not hf.get("use_sliding_window", True) and mt.startswith("qwen"):
             kw["sliding_window"] = None
         kw.update(overrides)
@@ -376,6 +416,10 @@ class TransformerConfig:
                 indexer_types=list(self.indexer_types),
                 rope_interleave=self.rope_interleave,
             )
+        if self.model_type == "granitemoehybrid":
+            hf.update({k: getattr(self, k) for k in self._GRANITE_HYBRID_FIELDS})
+            hf.update({theirs: getattr(self, ours)
+                       for theirs, ours in self._GRANITE_HYBRID_RENAMED.items()})
         if self.model_type == "qwen3_next":
             hf.update(
                 linear_num_value_heads=self.linear_num_value_heads,

@@ -8,9 +8,10 @@ shapes, one compile per (prompt_bucket, max_new) pair).
 
 Scope: the standard-attention dialect set of ``models/transformer.py``
 (GQA + qk-norm, partial/dual rotary, sliding windows, sinks, sandwich
-norms, dense or MoE MLP). MLA (deepseek), DSA, and hybrid linear-attention
-(qwen3_next) families fall back to the caller's rescoring path —
-``supports_cached_decode`` says which.
+norms, dense or MoE MLP). MLA (deepseek), DSA, hybrid linear-attention
+(qwen3_next) and state-space (granitemoehybrid) families fall back to the
+caller's rescoring path — ``supports_cached_decode`` says which, and
+``no_cached_decode_reason`` why.
 """
 
 from __future__ import annotations
@@ -31,18 +32,29 @@ from veomni_tpu.models.transformer import (
 )
 
 
+def no_cached_decode_reason(cfg) -> str:
+    """Why a config that :func:`supports_cached_decode` turns away has no
+    cached-decode path ("" where it has one or the reason is its class)."""
+    if getattr(cfg, "mamba_n_heads", 0) or getattr(cfg, "model_type", "") == "granitemoehybrid":
+        return ("its state-space layers carry a recurrent state and conv taps per "
+                "request, which the KV-cache engine does not hold")
+    if getattr(cfg, "model_type", "") == "qwen3_next":
+        return "its linear-attention layers carry a recurrent state per request"
+    return ""
+
+
 def supports_cached_decode(cfg) -> bool:
     """Fail-safe gate: True only for plain TransformerConfig dialects whose
     every decode-relevant knob ``_layer`` implements. Composite configs
-    (VLM/omni/dit), MLA/DSA, hybrid linear attention, and mrope rope
-    scaling (decode builds 1-D positions) fall back to the caller's
-    rescoring path — which is always correct, just O(n^2)."""
+    (VLM/omni/dit), MLA/DSA, hybrid linear attention, state-space layers, and
+    mrope rope scaling (decode builds 1-D positions) fall back to the
+    caller's rescoring path — which is always correct, just O(n^2)."""
     if type(cfg) is not TransformerConfig:
         return False
     if (
         getattr(cfg, "use_mla", False)
         or getattr(cfg, "use_dsa", False)
-        or cfg.model_type in ("qwen3_next",)
+        or no_cached_decode_reason(cfg)
         or getattr(cfg, "linear_attn_layers", None)
     ):
         return False

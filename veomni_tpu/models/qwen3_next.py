@@ -171,8 +171,9 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = 64, segment_ids=None):
     return out.transpose(0, 2, 1, 3)  # [B,S,H,Dv]
 
 
-def _causal_conv1d(x, weight, segment_ids=None):
-    """Depthwise causal conv: x [B,S,C], weight [C,K] -> [B,S,C] (silu'd).
+def _causal_conv1d(x, weight, segment_ids=None, bias=None):
+    """Depthwise causal conv: x [B,S,C], weight [C,K], bias [C] or None ->
+    [B,S,C] (silu'd). Shared with ``granite_hybrid``'s Mamba-2 mixer.
 
     Written as K shifted multiply-adds rather than ``lax.conv``: the kernel
     is tiny (K=4), elementwise ops fuse into the surrounding projections, and
@@ -186,18 +187,17 @@ def _causal_conv1d(x, weight, segment_ids=None):
     k = weight.shape[-1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     if segment_ids is None:
-        return jax.nn.silu(
-            sum(weight[None, None, :, i] * xp[:, i:i + s, :] for i in range(k))
+        out = sum(weight[None, None, :, i] * xp[:, i:i + s, :] for i in range(k))
+    else:
+        # pad with -1 so out-of-range taps never match a real segment id
+        segp = jnp.pad(segment_ids, ((0, 0), (k - 1, 0)), constant_values=-1)
+        out = sum(
+            weight[None, None, :, i]
+            * xp[:, i:i + s, :]
+            * (segp[:, i:i + s] == segment_ids)[..., None]
+            for i in range(k)
         )
-    # pad with -1 so out-of-range taps never match a real segment id
-    segp = jnp.pad(segment_ids, ((0, 0), (k - 1, 0)), constant_values=-1)
-    out = sum(
-        weight[None, None, :, i]
-        * xp[:, i:i + s, :]
-        * (segp[:, i:i + s] == segment_ids)[..., None]
-        for i in range(k)
-    )
-    return jax.nn.silu(out)
+    return jax.nn.silu(out if bias is None else out + bias)
 
 
 def _gated_delta_net(x, lp, cfg: TransformerConfig, segment_ids=None):
@@ -303,6 +303,37 @@ def _sublayer(hidden, lp, mixer, *, cfg):
     return constrain(hidden + out), aux, dropped
 
 
+def period_scan(hidden, stacks, period, bodies):
+    """A hybrid stack as ONE ``lax.scan`` over its periods.
+
+    ``period``: the kind of each layer of one period, in order. ``stacks``:
+    kind -> that kind's parameters ``[G, n_kind, ...]`` for ``G`` periods of
+    ``n_kind`` such layers each. ``bodies``: kind -> ``(hidden, layer params)
+    -> (hidden, aux)``, ``aux`` a pytree of scalars (or None). Inside a period
+    each run of consecutive layers of one kind is an inner scan, so every kind
+    compiles one body whatever the depth and wherever in the period it sits.
+    Returns (hidden, aux summed over each period's layers, ``[G]``)."""
+    runs, seen = [], {}
+    for kind in period:
+        at = seen.get(kind, 0)
+        seen[kind] = at + 1
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, at, 1])
+
+    def one_period(hidden, group):
+        total = None
+        for kind, start, n in runs:
+            sub = jax.tree.map(lambda t: t[start:start + n], group[kind])
+            hidden, aux = jax.lax.scan(bodies[kind], hidden, sub)
+            aux = jax.tree.map(lambda a: a.sum(0), aux)
+            total = aux if total is None else jax.tree.map(jnp.add, total, aux)
+        return hidden, total
+
+    return jax.lax.scan(one_period, hidden, stacks)
+
+
 # --------------------------------------------------------------------------
 # Params
 # --------------------------------------------------------------------------
@@ -405,34 +436,32 @@ def forward_hidden(params, cfg, input_ids, position_ids, segment_ids=None,
     )
     cos, sin = cos.astype(cfg.dtype), sin.astype(cfg.dtype)
 
-    def super_layer(hidden, group):
-        lin, full = group
+    def lin_body(h_, lp):
+        h_, aux, drop = _sublayer(
+            h_, lp,
+            lambda x, lp_: _gated_delta_net(x, lp_, cfg, segment_ids),
+            cfg=cfg,
+        )
+        return h_, (aux, drop)
 
-        def lin_body(h_, lp):
-            h_, aux, drop = _sublayer(
-                h_, lp,
-                lambda x, lp_: _gated_delta_net(x, lp_, cfg, segment_ids),
-                cfg=cfg,
-            )
-            return h_, (aux, drop)
+    def full_body(h_, lp):
+        h_, aux, drop = _sublayer(
+            h_, lp,
+            lambda x, lp_: _gated_full_attention(x, lp_, cfg, cos, sin, segment_ids),
+            cfg=cfg,
+        )
+        return h_, (aux, drop)
 
-        def full_body(h_, lp):
-            h_, aux, drop = _sublayer(
-                h_, lp,
-                lambda x, lp_: _gated_full_attention(x, lp_, cfg, cos, sin, segment_ids),
-                cfg=cfg,
-            )
-            return h_, (aux, drop)
-
-        if cfg.remat:
-            lin_body = jax.checkpoint(lin_body, policy=core._remat_policy(cfg))
-            full_body = jax.checkpoint(full_body, policy=core._remat_policy(cfg))
-        hidden, (auxes, drops) = jax.lax.scan(lin_body, hidden, lin)
-        hidden, (aux_f, drop_f) = full_body(hidden, full)
-        return hidden, (auxes.sum() + aux_f, drops.sum() + drop_f)
-
-    hidden, (auxes, drops) = jax.lax.scan(
-        super_layer, hidden, (compute["linear_layers"], compute["full_layers"])
+    if cfg.remat:
+        lin_body = jax.checkpoint(lin_body, policy=core._remat_policy(cfg))
+        full_body = jax.checkpoint(full_body, policy=core._remat_policy(cfg))
+    _, P = _group_shape(cfg)
+    hidden, (auxes, drops) = period_scan(
+        hidden,
+        # the one full layer a period has is stacked [G, ...]: give it its axis
+        {"linear": compute["linear_layers"],
+         "full": jax.tree.map(lambda t: t[:, None], compute["full_layers"])},
+        ("linear",) * P + ("full",), {"linear": lin_body, "full": full_body},
     )
     hidden = core._norm(hidden, compute["norm"], cfg)
     return hidden, auxes.sum(), drops.sum() / max(cfg.num_hidden_layers, 1)

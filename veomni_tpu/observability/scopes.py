@@ -44,6 +44,14 @@ SCOPES = TRAIN_SCOPES + SERVE_SCOPES
 MODULE_SCOPES = (
     "mtp",           # the multi-token-prediction modules: embedding of the next
                      # token, the joining projection, one decoder layer, head loss
+    # a state-space (Mamba-2) mixer, input norm to residual add; no taxonomy
+    # scope lies inside it, so a reader of the taxonomy alone files its time
+    # under ``unattributed``, and reads it by these names instead
+    "ssm",
+    "ssm.proj",      # in_proj and out_proj
+    "ssm.conv",      # the splits, the depthwise causal conv with its resets, silu
+    "ssm.scan",      # softplus of dt, the scan op (ops/ssd_scan.py), the D skip
+    "ssm.gate_norm", # the silu gate, then the RMS norm over all of d_inner
 )
 
 # Kernels a trace reader files by their NAME (``benchmark/scopes.py::KERNELS``

@@ -12,6 +12,7 @@ from veomni_tpu.ops import rms_norm as _rms_norm  # noqa: F401 register
 from veomni_tpu.ops import rotary as _rotary  # noqa: F401
 from veomni_tpu.ops import qk_norm_rotary as _qk_norm_rotary  # noqa: F401
 from veomni_tpu.ops import swiglu as _swiglu  # noqa: F401
+from veomni_tpu.ops import ssd_scan as _ssd_scan  # noqa: F401
 from veomni_tpu.ops import attention as _attention  # noqa: F401
 from veomni_tpu.ops import cross_entropy as _cross_entropy  # noqa: F401
 from veomni_tpu.ops import load_balancing as _load_balancing  # noqa: F401
@@ -25,6 +26,7 @@ apply_rotary = _rotary.apply_rotary
 rotary_tables = _rotary.rotary_tables
 qk_norm_rotary = _qk_norm_rotary.qk_norm_rotary
 swiglu = _swiglu.swiglu
+ssd_scan = _ssd_scan.ssd_scan
 attention = _attention.attention
 fused_linear_cross_entropy = _cross_entropy.fused_linear_cross_entropy
 fused_linear_topk_distill = _cross_entropy.fused_linear_topk_distill
@@ -54,6 +56,7 @@ __all__ = [
     "rotary_tables",
     "qk_norm_rotary",
     "swiglu",
+    "ssd_scan",
     "attention",
     "fused_linear_cross_entropy",
     "fused_linear_topk_distill",

@@ -69,7 +69,7 @@ import numpy as np
 from veomni_tpu.models import decode as decode_mod
 from veomni_tpu.models.config import TransformerConfig
 from veomni_tpu.ops.quantization import make_kv_pool, quantize_decode_params
-from veomni_tpu.models.decode import supports_cached_decode
+from veomni_tpu.models.decode import no_cached_decode_reason, supports_cached_decode
 from veomni_tpu.observability.metrics import LabelledRegistry, get_registry
 from veomni_tpu.observability.request_trace import RequestTracer
 from veomni_tpu.observability.spans import span
@@ -241,9 +241,11 @@ class InferenceEngine:
                  config: Optional[EngineConfig] = None,
                  programs: Optional[SharedPrograms] = None):
         if not supports_cached_decode(cfg):
+            why = no_cached_decode_reason(cfg)
             raise ValueError(
-                f"config {cfg.model_type!r} has no cached-decode path; the "
-                "serving engine requires supports_cached_decode(cfg)"
+                f"config {cfg.model_type!r} has no cached-decode path"
+                + (f" ({why})" if why else "")
+                + "; the serving engine requires supports_cached_decode(cfg)"
             )
         self.cfg = cfg
         self.config = config or EngineConfig()

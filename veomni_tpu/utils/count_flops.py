@@ -12,6 +12,8 @@ per-architecture formulas used by the MFU meter. Implemented terms:
 * multi-token prediction (one layer, the [2H, H] projection and the head
   again per module);
 * qwen3_next GatedDeltaNet linear-attention layers (chunkwise cost model);
+* Mamba-2 state-space layers (granitemoehybrid: projections, conv, the
+  chunked scan's four matmuls), counted per ``layer_types``;
 * ViT towers (per-patch, window or full attention) and DiT blocks via the
   dedicated helpers, fed to the meter as ``extra_flops``.
 
@@ -65,6 +67,15 @@ class FlopsCounter:
     full_attention_interval: int = 0
     attn_output_gate: bool = False
     delta_chunk: int = 64
+    # granitemoehybrid: Mamba-2 state-space layers where ``layer_types`` says
+    # "mamba" (``n_ssm_layers`` of ``num_layers``), attention elsewhere
+    n_ssm_layers: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
 
     # ------------------------------------------------------------- per-term
     def _attn_proj_flops(self) -> float:
@@ -128,11 +139,26 @@ class FlopsCounter:
         delta = nv * (4 * c * dk + 2 * c * dv + 6 * dk * dv)
         return proj + conv + delta
 
+    def _ssm_flops(self) -> float:
+        """Mamba-2 mixer per-token fwd cost: in_proj and out_proj, the conv,
+        and the chunked scan's matmuls (C B^T and its product with x inside a
+        chunk of c tokens; the state read by C and written by B)."""
+        h, nh, p, n = self.hidden_size, self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state
+        d_inner, bc = nh * p, self.mamba_n_groups * n
+        proj = 2 * h * (2 * d_inner + 2 * bc + nh) + 2 * d_inner * h
+        conv = 2 * (d_inner + 2 * bc) * self.mamba_d_conv
+        c = self.mamba_chunk_size
+        scan = 2 * c * bc + 2 * c * d_inner + 2 * 2 * d_inner * n
+        return proj + conv + scan
+
     # ------------------------------------------------------------ aggregate
     def flops_per_token_fwd(self, seq_len: int) -> float:
         mlp = self._mlp_flops()
         full_layer = self._attn_proj_flops() + self._attn_score_flops(seq_len) + mlp
-        if self.full_attention_interval and self.linear_num_value_heads:
+        if self.n_ssm_layers:
+            body = (self.n_ssm_layers * (self._ssm_flops() + mlp)
+                    + (self.num_layers - self.n_ssm_layers) * full_layer)
+        elif self.full_attention_interval and self.linear_num_value_heads:
             n_full = self.num_layers // self.full_attention_interval
             n_lin = self.num_layers - n_full
             lin_layer = self._linear_attn_flops() + mlp
@@ -192,6 +218,15 @@ class FlopsCounter:
                 g("full_attention_interval", 0) if g("linear_num_value_heads", 0) else 0
             ),
             attn_output_gate=g("attn_output_gate", False),
+            n_ssm_layers=(
+                list(g("layer_types", None) or ()).count("mamba") if g("mamba_n_heads", 0) else 0
+            ),
+            mamba_n_heads=g("mamba_n_heads", 0),
+            mamba_d_head=g("mamba_d_head", 0),
+            mamba_d_state=g("mamba_d_state", 0),
+            mamba_n_groups=g("mamba_n_groups", 1),
+            mamba_d_conv=g("mamba_d_conv", 4),
+            mamba_chunk_size=g("mamba_chunk_size", 256),
         )
 
 
