@@ -295,6 +295,117 @@ def test_qk_norm_rope_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
     assert "all-reduce" in text         # the weights' gradients
 
 
+# the shapes the MLA split + rope op is handed: the joyai cell's (32 heads of
+# 128 nope + 64 rope, v of 128: 1024 rows of four heads a step, the backward's
+# sum over the groups of heads in its scratch), DeepSeek-V3's 128 heads, and
+# the half rotation at a sequence only 128 divides (all four heads a step)
+MLA_QKV_ROPE_CALLS = {
+    "cell": dict(b=2, s=8192, h=32, interleaved=True),
+    "heads128": dict(b=1, s=4096, h=128, interleaved=True),
+    "halves_s384": dict(b=2, s=384, h=4, interleaved=False),
+}
+MLA_WIDTHS = (128, 64, 128)  # qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+
+
+def _mla_qkv_rope_args(c, described):
+    dn, dr, dv = MLA_WIDTHS
+    b, s, h = c["b"], c["s"], c["h"]
+    rope = described((b, s, dr))
+    return described((b, s, h * (dn + dr))), described((b, s, h * (dn + dv))), rope, rope, rope
+
+
+def _mla_qkv_rope_fns(interleaved):
+    from veomni_tpu.ops.pallas.mla_qkv_rope import mla_qkv_rope
+
+    def fwd(*args):
+        with jax.named_scope("attn.qkv"):  # as the model calls it
+            return mla_qkv_rope(*args, *MLA_WIDTHS, interleaved)
+
+    def loss(*args):
+        return sum(x.astype(jnp.float32).sum() for x in fwd(*args))
+
+    return fwd, loss
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("call", list(MLA_QKV_ROPE_CALLS))
+def test_mla_qkv_rope_lowers_for_v5e(v5e, on_chip_kernels, call, direction):
+    """One custom call each way, named after the kernel: the op is linear, so
+    the gradient alone holds no forward."""
+    c = MLA_QKV_ROPE_CALLS[call]
+    args = _mla_qkv_rope_args(c, lambda shape: _described(v5e[0], shape, jnp.bfloat16))
+    fwd, loss = _mla_qkv_rope_fns(c["interleaved"])
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert _kernel_instructions(text) == {"mla_qkv_rope_" + direction: 1}
+
+
+def test_mla_qkv_rope_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
+    """FSDP 2 x Ulysses 2 on four chips: the op sits under GSPMD with its
+    activations sharded (dp, sp, None), and runs per device in a shard_map
+    over exactly that; nothing is summed over the mesh (the op has no weight)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from veomni_tpu.parallel import init_parallel_state, use_parallel_state
+
+    ps = init_parallel_state(devices=v5e, ulysses_size=2)
+    rows = NamedSharding(ps.mesh, P(ps.dp_axes, ps.sp_axes))
+    args = _mla_qkv_rope_args(MLA_QKV_ROPE_CALLS["cell"],
+                              lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=rows))
+    _, loss = _mla_qkv_rope_fns(True)
+    with use_parallel_state(ps):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+    assert _kernel_instructions(text) == {"mla_qkv_rope_bwd": 1}
+    assert "bf16[1,4096,6144]" in text  # a device's share of dq: half the batch, half the rows
+    assert "all-reduce" not in text and "all-gather" not in text
+
+
+def test_mla_attention_block_hands_flash_what_the_kernel_wrote(v5e, on_chip_kernels):
+    """``_mla_attention`` at the joyai cell's shape, forward and backward, with
+    both ops resolved as the chip resolves them: five kernels, and between
+    ``mla_qkv_rope_*`` and ``flash_*`` no instruction of XLA's reads or writes
+    a 192-wide per-head array (q, k and their cotangents) except the flash
+    wrapper's own rounding of its f32 dK."""
+    import re
+
+    from veomni_tpu.arguments import VeOmniArguments, parse_args
+    from veomni_tpu.models import transformer
+    from veomni_tpu.models.auto import build_config
+    from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
+
+    args = parse_args(VeOmniArguments, [os.path.join(REPO, "configs/text/joyai_llm_flash_v5e.yaml")])
+    overrides = dict(args.model.config_overrides)
+    cfg = build_config(overrides.pop("model_type"), **overrides, dtype="bfloat16",
+                       param_dtype="float32")
+    b, s, hidden, heads = 2, 8192, cfg.hidden_size, cfg.num_attention_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    assert (heads, dn, dr, dv) == (32, *MLA_WIDTHS)
+    d = lambda *shape, dtype=jnp.bfloat16: _described(v5e[0], shape, dtype)
+    lp = {"q_a_proj": d(hidden, cfg.q_lora_rank), "q_a_layernorm": d(cfg.q_lora_rank),
+          "q_b_proj": d(cfg.q_lora_rank, heads * (dn + dr)),
+          "kv_a_proj_with_mqa": d(hidden, cfg.kv_lora_rank + dr),
+          "kv_a_layernorm": d(cfg.kv_lora_rank),
+          "kv_b_proj": d(cfg.kv_lora_rank, heads * (dn + dv)), "o_proj": d(heads * dv, hidden)}
+
+    def loss(x, lp, cos, sin, seg):
+        return transformer._mla_attention(x, lp, cfg, cos, sin, seg, None).astype(jnp.float32).sum()
+
+    KERNEL_REGISTRY.pin("attention", "pallas_flash")
+    KERNEL_REGISTRY.pin("mla_qkv_rotary", "pallas")
+    try:
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            d(b, s, hidden), lp, d(b, s, dr), d(b, s, dr), d(b, s, dtype=jnp.int32)
+        ).compile().as_text()
+    finally:
+        KERNEL_REGISTRY.clear_pins()
+    assert _kernel_instructions(text) == {"mla_qkv_rope_fwd": 1, "flash_fwd": 1, "flash_bwd_dkv": 1,
+                                          "flash_bwd_dq": 1, "mla_qkv_rope_bwd": 1}
+    wide = [line.split(", metadata")[0].strip() for line in text.splitlines()
+            if re.search(r" = \(?(?:bf16|f32)\[2,(?:32,8192|8192,32),192\]", line)
+            and not re.search(r" (?:custom-call|get-tuple-element|parameter|bitcast)\(", line)]
+    assert len(wide) == 1 and " convert(" in wide[0] and wide[0].startswith("%convert"), wide
+
+
 # the smoke's shapes (a fused gate_up of twice the width, as
 # models/deepseek_v4.py's; E 128 at the Qwen3-30B-A3B widths), and the held
 # experts' buffer of the joyai_llm_flash.train_packed_8k cell (16 of 256

@@ -688,6 +688,38 @@ def test_scope_names_in_the_code_are_the_taxonomy():
     assert kernel_lits == set(ALL_KERNEL_NAMES)
 
 
+def _observability_doc():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "docs", "observability.md")) as f:
+        return f.read()
+
+
+def test_every_kernel_name_is_in_the_metric_reference():
+    from veomni_tpu.observability.scopes import ALL_KERNEL_NAMES, SCOPED_KERNEL_NAMES
+
+    doc = _observability_doc()
+    assert [k for k in ALL_KERNEL_NAMES if f"`{k}`" not in doc] == []
+    # the kernels a reader files by the scope in their op_name, and that scope's row
+    assert set(SCOPED_KERNEL_NAMES) == {"qk_norm_rope_fwd", "qk_norm_rope_bwd",
+                                        "mla_qkv_rope_fwd", "mla_qkv_rope_bwd"}
+    row = next(line for line in doc.splitlines() if line.startswith("| `attn.qkv` |"))
+    assert "`qk_norm_rope_*`" in row and "`mla_qkv_rope_*`" in row
+
+
+@pytest.mark.parametrize("name", [
+    "attn.mla_qkv_rope.calls_kernel", "attn.mla_qkv_rope.calls_handed_over",
+    "op mla_qkv_rotary: pallas", "op qk_norm_rotary:", "op attention:",
+])
+def test_kernel_engagement_counters_and_hand_over_lines_are_in_the_metric_reference(name):
+    """And the program emits them under these names."""
+    import glob
+
+    assert name in " ".join(_observability_doc().split())
+    kernels = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                           "veomni_tpu", "ops", "pallas", "*.py")
+    assert any(f'"{name}' in open(path).read() for path in glob.glob(kernels))
+
+
 # ------------------------------------------------- flash attention's tiles
 @pytest.mark.parametrize("impl,counted", [("pallas_flash", True), ("auto", False)])
 def test_flash_tile_counters_follow_the_resolved_attention(tmp_path, impl, counted):

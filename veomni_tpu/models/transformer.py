@@ -559,21 +559,12 @@ def _mla_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, window,
                         lp["q_b_proj"])
         else:
             q = jnp.dot(x, lp["q_proj"])
-        q = q.reshape(b, s, nh, dn + dr)
-        q_nope, q_rope = q[..., :dn], q[..., dn:]
-
         kv_a = jnp.dot(x, lp["kv_a_proj_with_mqa"])  # [B,S, kvlr + dr]
         c_kv, k_rope = kv_a[..., : cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
         kv = jnp.dot(_norm(c_kv, lp["kv_a_layernorm"], cfg), lp["kv_b_proj"])
-        kv = kv.reshape(b, s, nh, dn + dv)
-        k_nope, v = kv[..., :dn], kv[..., dn:]
-
-        q_rope, k_rope = ops.apply_rotary(
-            q_rope, k_rope.reshape(b, s, 1, dr), cos, sin,
-            interleaved=cfg.rope_interleave,
-        )
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, nh, dr))], axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        # the splits, rope on the rope lanes, k_rope to every head: one op
+        q, k, v = ops.mla_qkv_rotary(q, kv, k_rope, cos, sin, dn, dr, dv,
+                                     interleaved=cfg.rope_interleave)
     from veomni_tpu.ops.rotary import yarn_attention_factor
 
     scale = (dn + dr) ** -0.5 * yarn_attention_factor(cfg.rope_scaling, dr)

@@ -19,7 +19,7 @@ site. ``docs/observability.md`` lists what each covers.
 
 TRAIN_SCOPES = (
     "embed",         # token embedding lookup (+ embed scale)
-    "attn.qkv",      # input norm, q/k/v projections, qk-norm, rope
+    "attn.qkv",      # input norm, q/k/v projections, qk-norm, rope (MLA: the split too)
     "attn.flash",    # the attention op, whichever impl the registry resolves
     "attn.out",      # output projection and the residual add
     "mlp",           # post-attention norm, dense gated MLP, residual add
@@ -65,5 +65,6 @@ KERNEL_NAMES = (
 # ``op_name`` (``attn.qkv`` here): named in every trace, in no reader's table.
 SCOPED_KERNEL_NAMES = (
     "qk_norm_rope_fwd", "qk_norm_rope_bwd",         # ops/pallas/qk_norm_rope.py
+    "mla_qkv_rope_fwd", "mla_qkv_rope_bwd",         # ops/pallas/mla_qkv_rope.py
 )
 ALL_KERNEL_NAMES = KERNEL_NAMES + SCOPED_KERNEL_NAMES

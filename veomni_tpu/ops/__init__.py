@@ -3,14 +3,18 @@
 Reference: ``veomni/ops/`` — KERNEL_REGISTRY + OpSlot dispatch with per-op
 implementation selection (eager vs Triton vs external CUDA). Here the impl
 axes are {"xla", "pallas"}; Pallas is for the ops the chip showed to be hot
-(flash attention, the grouped GEMM, and the q/k norm + rope chain, which XLA
-does not fuse the way one would hope: ``ops/pallas/qk_norm_rope.py``).
+(flash attention, the grouped GEMM, and the two chains between the attention
+block's projections and the attention op, which XLA does not fuse the way one
+would hope: the q/k norm + rope of GQA/MHA, ``ops/pallas/qk_norm_rope.py``,
+and MLA's split, rope, head broadcast and relayout, ``ops.mla_qkv_rotary``,
+``ops/pallas/mla_qkv_rope.py``).
 """
 
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY, KernelSpec, resolve_op
 from veomni_tpu.ops import rms_norm as _rms_norm  # noqa: F401 register
 from veomni_tpu.ops import rotary as _rotary  # noqa: F401
 from veomni_tpu.ops import qk_norm_rotary as _qk_norm_rotary  # noqa: F401
+from veomni_tpu.ops import mla_qkv_rotary as _mla_qkv_rotary  # noqa: F401
 from veomni_tpu.ops import swiglu as _swiglu  # noqa: F401
 from veomni_tpu.ops import ssd_scan as _ssd_scan  # noqa: F401
 from veomni_tpu.ops import attention as _attention  # noqa: F401
@@ -25,6 +29,7 @@ rms_norm = _rms_norm.rms_norm
 apply_rotary = _rotary.apply_rotary
 rotary_tables = _rotary.rotary_tables
 qk_norm_rotary = _qk_norm_rotary.qk_norm_rotary
+mla_qkv_rotary = _mla_qkv_rotary.mla_qkv_rotary
 swiglu = _swiglu.swiglu
 ssd_scan = _ssd_scan.ssd_scan
 attention = _attention.attention
@@ -55,6 +60,7 @@ __all__ = [
     "apply_rotary",
     "rotary_tables",
     "qk_norm_rotary",
+    "mla_qkv_rotary",
     "swiglu",
     "ssd_scan",
     "attention",

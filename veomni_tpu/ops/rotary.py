@@ -7,8 +7,10 @@ holds ``_rotate_half``'s two halves as 64-lane arrays, each padded to a
 whole 128-lane tile, f32 copies of q and k between fusions and a relayout of
 the whole tensor (PERF.md, PR 32). The GQA/MHA attention block therefore
 calls ``ops.qk_norm_rotary``, whose Pallas impl rolls whole heads and folds
-the sign into the sine table; ``apply_rotary`` serves MLA's 64-wide slice,
-the interleaved layout, partial rotary and the VL / DiT models' own calls.
+the sign into the sine table, and MLA's block ``ops.mla_qkv_rotary``, whose
+Pallas impl rotates the 64 rope lanes of a head in place (PERF.md, PR 35);
+``apply_rotary`` serves the ``xla`` impls of both, the indexer, partial
+rotary and the VL / DiT models' own calls.
 """
 
 from __future__ import annotations
