@@ -202,6 +202,38 @@ def test_ssd_scan_lowers_for_v5e_within_a_chunks_memory(v5e, direction):
     assert compiled.memory_analysis().temp_size_in_bytes < whole_decay // 2
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_kda_scan_lowers_for_v5e_within_a_blocks_memory(v5e, direction):
+    """The Kimi Delta Attention recurrence at the kimi_linear cell's shapes (one
+    row of 8192, 32 heads of 128, one decay a key channel): it compiles for
+    the chip, and its temporaries stay a block's, not the 8.6 GB the
+    ``[S/c, c, c, dk]`` f32 pair term of a whole row would take: the forward's
+    under a quarter of ONE of the row's ``[H, S/c, c, c]`` f32 matrices (64
+    MiB), the backward's (one 2 MiB state a block, a block's terms and their
+    cotangents) under four of them."""
+    from veomni_tpu import ops
+
+    b, s, h, d = 1, 8192, 32, 128
+    x = _described(v5e[0], (b, s, h, d), jnp.bfloat16)
+    g = _described(v5e[0], (b, s, h, d), jnp.float32)
+    beta = _described(v5e[0], (b, s, h), jnp.float32)
+    seg = _described(v5e[0], (b, s), jnp.int32)
+
+    def fwd(q, k, v, g, beta, seg):
+        with jax.named_scope("kda.scan"):
+            return ops.kda_scan(q, k, v, g, beta, seg)
+
+    def loss(*args):
+        return fwd(*args).astype(jnp.float32).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=tuple(range(5)))
+    compiled = jax.jit(fn).lower(x, x, x, g, beta, seg).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # impl xla: no kernel yet
+    pair_matrices = h * (s // 64) * 64 * 64 * 4
+    limit = pair_matrices // 4 if direction == "fwd" else 4 * pair_matrices
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
 def test_flash_attention_under_gspmd_lowers_for_v5e(v5e, on_chip_kernels):
     """Plain FSDP on four chips: attention sits under GSPMD, which refuses to
     partition a Mosaic kernel unless the wrapper shard_maps it."""
@@ -452,7 +484,7 @@ def smoke_step(v5e):
         return _compile_smoke_step(v5e)
 
 
-def _compile_smoke_step(v5e, config=chip_smoke.TRAIN_CONFIG):
+def _compile_smoke_step(v5e, config=chip_smoke.TRAIN_CONFIG, **pinned_ops):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from veomni_tpu.arguments import VeOmniArguments, parse_args
@@ -477,7 +509,7 @@ def _compile_smoke_step(v5e, config=chip_smoke.TRAIN_CONFIG):
         # platform; here the platform is the CPU, so the test pins it
         model = build_foundation_model(
             config=cfg, ops_implementation={"attention": "pallas_flash",
-                                            "qk_norm_rotary": "pallas"})
+                                            "qk_norm_rotary": "pallas", **pinned_ops})
         opt = build_optimizer(
             model.abstract(), optimizer=t.optimizer,
             lr=build_lr_scheduler(t.lr_decay_style, lr=t.lr, train_steps=t.train_steps),
@@ -531,6 +563,29 @@ def test_hybrid_state_space_train_step_fits_one_v5e(v5e, on_chip_kernels):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 8.5 * GIB  # f32 params + AdamW moments
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5 * GIB
+
+
+def test_kimi_linear_train_step_fits_one_v5e(v5e, on_chip_kernels):
+    """The step of configs/text/kimi_linear_48b_a3b_v5e.yaml (the benchmark's
+    fourth cell: 602 M parameters at 16 bytes, ONE row of 8192): its one MLA
+    layer runs the flash kernels and, NoPE as it is, the split + rope kernels
+    under the identity rotation (the counter says the kernel took the call);
+    the four recurrences are XLA; arguments + temporaries leave room in the
+    15.75 GiB a v5e gives a program."""
+    from veomni_tpu.observability.metrics import get_registry
+
+    taken = get_registry().counter("attn.mla_qkv_rope.calls_kernel")
+    handed = get_registry().counter("attn.mla_qkv_rope.calls_handed_over")
+    before = (taken.value, handed.value)
+    compiled = _compile_smoke_step(v5e, "configs/text/kimi_linear_48b_a3b_v5e.yaml",
+                                   mla_qkv_rotary="pallas")
+    assert (taken.value - before[0], handed.value - before[1]) == (1, 0)
+    assert _kernel_instructions(compiled.as_text()) == {
+        "flash_fwd": 2, "flash_bwd_dkv": 1, "flash_bwd_dq": 1,
+        "mla_qkv_rope_fwd": 2, "mla_qkv_rope_bwd": 1}
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 6.7 * GIB  # f32 params + AdamW moments
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13 * GIB
 
 
 # what carries no scope of the taxonomy in the compiled step, by the last

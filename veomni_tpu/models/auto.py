@@ -105,6 +105,27 @@ def _register_granite_hybrid():
 _register_granite_hybrid()
 
 
+def _register_kimi_linear():
+    from veomni_tpu.models import kimi_linear as kl
+
+    MODEL_REGISTRY.register(
+        "kimi_linear",
+        ModelFamily(
+            model_type="kimi_linear",
+            init_params=kl.init_params,
+            abstract_params=kl.abstract_params,
+            loss_fn=kl.loss_fn,
+            forward_logits=kl.forward_logits,
+            hf_to_params=kl.hf_to_params,
+            save_hf_checkpoint=kl.save_hf_checkpoint,
+            parallel_plan_fn=kl.parallel_plan,
+        ),
+    )
+
+
+_register_kimi_linear()
+
+
 def _register_deepseek_v4():
     from veomni_tpu.models import deepseek_v4 as dsv4
 
@@ -430,6 +451,11 @@ def build_config(model_type: str = "", **overrides):
         for theirs, ours in TransformerConfig._GRANITE_HYBRID_RENAMED.items():
             if theirs in overrides:
                 overrides[ours] = overrides.pop(theirs)
+    if model_type == "kimi_linear":
+        # config.json's spellings (num_experts_per_token, ...) are taken too
+        theirs = (*TransformerConfig._KIMI_LINEAR_RENAMED, "use_grouped_topk")
+        overrides = {**TransformerConfig.kimi_linear_fields(overrides),
+                     **{k: v for k, v in overrides.items() if k not in theirs}}
     if model_type in TransformerConfig._DEEPSEEK_V3_DIALECT:
         for key, value in TransformerConfig.deepseek_defaults(model_type).items():
             overrides.setdefault(key, value)

@@ -124,6 +124,12 @@ class TransformerConfig:
     attention_multiplier: float = 0.0   # softmax scale; 0 -> head_dim ** -0.5
     residual_multiplier: float = 1.0    # h += multiplier * sublayer(norm(h))
     logits_scaling: float = 1.0         # logits = head(h) / logits_scaling
+    # kimi_linear (models/kimi_linear.py): Kimi Delta Attention layers and MLA
+    # layers where config.json's ``linear_attn_config`` says (its 1-based
+    # ``kda_layers`` / ``full_attn_layers``, ``num_heads``, ``head_dim``,
+    # ``short_conv_kernel_size``), kept as published
+    linear_attn_config: Optional[Dict[str, Any]] = None
+    mla_use_nope: bool = False          # MLA with no rotary on either part
     # EP dispatch capacity factor; <= 0 means dropless (see parallel/moe.py)
     moe_capacity_factor: float = 0.0
     # HF checkpoint expert-tensor layout: "" = auto by model_type
@@ -246,6 +252,29 @@ class TransformerConfig:
                    if hf.get(theirs) is not None})
         return kw
 
+    # kimi_linear: config.json's spelling -> the field it sets here
+    _KIMI_LINEAR_RENAMED = {
+        "num_experts_per_token": "num_experts_per_tok",
+        "moe_router_activation_func": "scoring_func",
+        "moe_renormalize": "norm_topk_prob",
+        "num_shared_experts": "n_shared_experts",
+        "num_expert_group": "n_group",
+    }
+
+    @classmethod
+    def kimi_linear_fields(cls, hf: Dict[str, Any]) -> Dict[str, Any]:
+        """The kimi_linear keys of ``hf`` (a config.json, or overrides in its
+        spelling) as this class's fields. The family balances its experts by
+        updating the correction bias, not by a loss term."""
+        kw = {ours: hf[theirs] for theirs, ours in cls._KIMI_LINEAR_RENAMED.items()
+              if hf.get(theirs) is not None}
+        kw.update({k: hf[k] for k in ("linear_attn_config", "mla_use_nope")
+                   if hf.get(k) is not None})
+        if not hf.get("use_grouped_topk", True):
+            kw["n_group"] = kw["topk_group"] = 1
+        kw["router_aux_loss_coef"] = hf.get("router_aux_loss_coef", 0.0)
+        return kw
+
     @classmethod
     def from_hf_config(cls, hf: Dict[str, Any], **overrides) -> "TransformerConfig":
         mt = hf.get("model_type", "llama")
@@ -364,6 +393,8 @@ class TransformerConfig:
             )
         if mt == "granitemoehybrid":
             kw.update(cls.granite_hybrid_fields(hf))
+        if mt == "kimi_linear":
+            kw.update(cls.kimi_linear_fields(hf))
         if not hf.get("use_sliding_window", True) and mt.startswith("qwen"):
             kw["sliding_window"] = None
         kw.update(overrides)
@@ -420,6 +451,11 @@ class TransformerConfig:
             hf.update({k: getattr(self, k) for k in self._GRANITE_HYBRID_FIELDS})
             hf.update({theirs: getattr(self, ours)
                        for theirs, ours in self._GRANITE_HYBRID_RENAMED.items()})
+        if self.model_type == "kimi_linear":
+            for theirs, ours in self._KIMI_LINEAR_RENAMED.items():
+                hf[theirs] = hf.pop(ours, getattr(self, ours))
+            hf.update(linear_attn_config=self.linear_attn_config,
+                      mla_use_nope=self.mla_use_nope, use_grouped_topk=True)
         if self.model_type == "qwen3_next":
             hf.update(
                 linear_num_value_heads=self.linear_num_value_heads,

@@ -9,7 +9,7 @@ shapes, one compile per (prompt_bucket, max_new) pair).
 Scope: the standard-attention dialect set of ``models/transformer.py``
 (GQA + qk-norm, partial/dual rotary, sliding windows, sinks, sandwich
 norms, dense or MoE MLP). MLA (deepseek), DSA, hybrid linear-attention
-(qwen3_next) and state-space (granitemoehybrid) families fall back to the
+(qwen3_next, kimi_linear) and state-space (granitemoehybrid) families fall back to the
 caller's rescoring path — ``supports_cached_decode`` says which, and
 ``no_cached_decode_reason`` why.
 """
@@ -40,6 +40,9 @@ def no_cached_decode_reason(cfg) -> str:
                 "request, which the KV-cache engine does not hold")
     if getattr(cfg, "model_type", "") == "qwen3_next":
         return "its linear-attention layers carry a recurrent state per request"
+    if getattr(cfg, "linear_attn_config", None) or getattr(cfg, "model_type", "") == "kimi_linear":
+        return ("its Kimi Delta Attention layers carry a recurrent state and three convs' taps "
+                "per request, which the KV-cache engine does not hold")
     return ""
 
 

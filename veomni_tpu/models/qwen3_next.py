@@ -303,7 +303,7 @@ def _sublayer(hidden, lp, mixer, *, cfg):
     return constrain(hidden + out), aux, dropped
 
 
-def period_scan(hidden, stacks, period, bodies):
+def period_scan(hidden, stacks, period, bodies, fold=None):
     """A hybrid stack as ONE ``lax.scan`` over its periods.
 
     ``period``: the kind of each layer of one period, in order. ``stacks``:
@@ -312,7 +312,9 @@ def period_scan(hidden, stacks, period, bodies):
     -> (hidden, aux)``, ``aux`` a pytree of scalars (or None). Inside a period
     each run of consecutive layers of one kind is an inner scan, so every kind
     compiles one body whatever the depth and wherever in the period it sits.
-    Returns (hidden, aux summed over each period's layers, ``[G]``)."""
+    Returns (hidden, aux summed over each period's layers, ``[G]``); where a
+    sum is not how the layers' aux joins, ``fold`` is ``(zero, (total, a
+    run's aux [n, ...]) -> total)``."""
     runs, seen = [], {}
     for kind in period:
         at = seen.get(kind, 0)
@@ -323,12 +325,15 @@ def period_scan(hidden, stacks, period, bodies):
             runs.append([kind, at, 1])
 
     def one_period(hidden, group):
-        total = None
+        total = None if fold is None else fold[0]
         for kind, start, n in runs:
             sub = jax.tree.map(lambda t: t[start:start + n], group[kind])
             hidden, aux = jax.lax.scan(bodies[kind], hidden, sub)
-            aux = jax.tree.map(lambda a: a.sum(0), aux)
-            total = aux if total is None else jax.tree.map(jnp.add, total, aux)
+            if fold is not None:
+                total = fold[1](total, aux)
+            else:
+                aux = jax.tree.map(lambda a: a.sum(0), aux)
+                total = aux if total is None else jax.tree.map(jnp.add, total, aux)
         return hidden, total
 
     return jax.lax.scan(one_period, hidden, stacks)

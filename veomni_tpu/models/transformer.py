@@ -599,42 +599,13 @@ def _mla_attention(x, lp, cfg: TransformerConfig, cos, sin, segment_ids, window,
         return jnp.dot(attn.reshape(b, s, nh * dv), lp["o_proj"])
 
 
-def _decoder_layer(
-    hidden, lp, dsa_prev=None, dsa_shared=None, *, cfg: TransformerConfig,
-    cos, sin, segment_ids, window=None, is_moe_segment=None,
-):
+def feed_forward(hidden, lp, cfg: TransformerConfig, is_moe: bool):
+    """The second half of a decoder layer: ``hidden + mlp(norm(hidden))``,
+    dense or sparse, whatever mixer came before it (``models/kimi_linear.py``
+    puts it behind its two). Returns (hidden, stats ``[6]``: aux, then the
+    five of :func:`moe_mlp_with_stats`, zeros for a dense MLP)."""
     b, s, h = hidden.shape
-    is_moe = cfg.is_moe if is_moe_segment is None else is_moe_segment
     constrain = _activation_constraint()
-    hidden = constrain(hidden)
-    with jax.named_scope("attn.qkv"):
-        x = _norm(hidden, lp["input_layernorm"], cfg)
-    dsa_bias = None
-    if cfg.use_dsa:
-        # "shared" layers reuse the previous layer's top-k selection
-        # (reference skip_topk, arXiv:2603.12201); lax.cond skips the
-        # indexer compute at runtime on those layers. The [B,S,S] carry only
-        # exists when the config actually has shared layers.
-        if dsa_shared is None:
-            dsa_bias = _dsa_bias(x, lp, cfg, cos, sin, segment_ids)
-        else:
-            dsa_bias = jax.lax.cond(
-                dsa_shared,
-                lambda: dsa_prev,
-                lambda: _dsa_bias(x, lp, cfg, cos, sin, segment_ids),
-            )
-    if cfg.use_mla:
-        attn_out = _mla_attention(x, lp, cfg, cos, sin, segment_ids, window,
-                                  dsa_bias=dsa_bias)
-    else:
-        attn_out = _standard_attention(
-            x, lp, cfg, cos, sin, segment_ids, window, lp.get("sinks")
-        )
-    with jax.named_scope("attn.out"):
-        if cfg.sandwich_norms:
-            attn_out = _norm(attn_out, lp["post_attention_layernorm"], cfg)
-        hidden = hidden + attn_out
-
     hidden = constrain(hidden)
     pre_norm = (
         lp["pre_feedforward_layernorm"] if cfg.sandwich_norms
@@ -700,6 +671,45 @@ def _decoder_layer(
             out = _norm(out, lp["post_feedforward_layernorm"], cfg)
         hidden = constrain(hidden + out)
     stats = jnp.stack([aux, *moe_stats]).astype(jnp.float32)
+    return hidden, stats
+
+
+def _decoder_layer(
+    hidden, lp, dsa_prev=None, dsa_shared=None, *, cfg: TransformerConfig,
+    cos, sin, segment_ids, window=None, is_moe_segment=None,
+):
+    is_moe = cfg.is_moe if is_moe_segment is None else is_moe_segment
+    constrain = _activation_constraint()
+    hidden = constrain(hidden)
+    with jax.named_scope("attn.qkv"):
+        x = _norm(hidden, lp["input_layernorm"], cfg)
+    dsa_bias = None
+    if cfg.use_dsa:
+        # "shared" layers reuse the previous layer's top-k selection
+        # (reference skip_topk, arXiv:2603.12201); lax.cond skips the
+        # indexer compute at runtime on those layers. The [B,S,S] carry only
+        # exists when the config actually has shared layers.
+        if dsa_shared is None:
+            dsa_bias = _dsa_bias(x, lp, cfg, cos, sin, segment_ids)
+        else:
+            dsa_bias = jax.lax.cond(
+                dsa_shared,
+                lambda: dsa_prev,
+                lambda: _dsa_bias(x, lp, cfg, cos, sin, segment_ids),
+            )
+    if cfg.use_mla:
+        attn_out = _mla_attention(x, lp, cfg, cos, sin, segment_ids, window,
+                                  dsa_bias=dsa_bias)
+    else:
+        attn_out = _standard_attention(
+            x, lp, cfg, cos, sin, segment_ids, window, lp.get("sinks")
+        )
+    with jax.named_scope("attn.out"):
+        if cfg.sandwich_norms:
+            attn_out = _norm(attn_out, lp["post_attention_layernorm"], cfg)
+        hidden = hidden + attn_out
+
+    hidden, stats = feed_forward(hidden, lp, cfg, is_moe)
     if dsa_prev is not None:  # carry mode (configs with "shared" layers)
         return hidden, stats, dsa_bias
     return hidden, stats

@@ -52,6 +52,13 @@ MODULE_SCOPES = (
     "ssm.conv",      # the splits, the depthwise causal conv with its resets, silu
     "ssm.scan",      # softplus of dt, the scan op (ops/ssd_scan.py), the D skip
     "ssm.gate_norm", # the silu gate, then the RMS norm over all of d_inner
+    # a Kimi Delta Attention mixer (models/kimi_linear.py), input norm to
+    # residual add, read as the state-space mixer is
+    "kda",
+    "kda.proj",      # q, k, v, the two low-rank pairs (decay, output gate), beta, o_proj
+    "kda.conv",      # the three depthwise causal convs with their resets, silu
+    "kda.gate",      # l2norm of q and k, the log-decay (softplus), beta, the gated norm
+    "kda.scan",      # the recurrence op (ops/kda.py)
 )
 
 # Kernels a trace reader files by their NAME (``benchmark/scopes.py::KERNELS``

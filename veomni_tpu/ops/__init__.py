@@ -17,6 +17,7 @@ from veomni_tpu.ops import qk_norm_rotary as _qk_norm_rotary  # noqa: F401
 from veomni_tpu.ops import mla_qkv_rotary as _mla_qkv_rotary  # noqa: F401
 from veomni_tpu.ops import swiglu as _swiglu  # noqa: F401
 from veomni_tpu.ops import ssd_scan as _ssd_scan  # noqa: F401
+from veomni_tpu.ops import kda as _kda  # noqa: F401
 from veomni_tpu.ops import attention as _attention  # noqa: F401
 from veomni_tpu.ops import cross_entropy as _cross_entropy  # noqa: F401
 from veomni_tpu.ops import load_balancing as _load_balancing  # noqa: F401
@@ -32,6 +33,7 @@ qk_norm_rotary = _qk_norm_rotary.qk_norm_rotary
 mla_qkv_rotary = _mla_qkv_rotary.mla_qkv_rotary
 swiglu = _swiglu.swiglu
 ssd_scan = _ssd_scan.ssd_scan
+kda_scan = _kda.kda_scan
 attention = _attention.attention
 fused_linear_cross_entropy = _cross_entropy.fused_linear_cross_entropy
 fused_linear_topk_distill = _cross_entropy.fused_linear_topk_distill
@@ -63,6 +65,7 @@ __all__ = [
     "mla_qkv_rotary",
     "swiglu",
     "ssd_scan",
+    "kda_scan",
     "attention",
     "fused_linear_cross_entropy",
     "fused_linear_topk_distill",

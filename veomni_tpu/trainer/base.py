@@ -123,7 +123,7 @@ class BaseTrainer:
             with use_parallel_state(self.parallel_state):
                 self._build_model()
                 self._flash_tile_counters = self._build_flash_tile_counters()
-                self._ssm_chunk_counters = self._build_ssm_chunk_counters()
+                self._scan_chunk_counters = self._build_scan_chunk_counters()
                 with span("setup.data"):
                     self._build_data_transform()
                     self._build_dataset()
@@ -255,27 +255,38 @@ class BaseTrainer:
 
         return count
 
-    def _build_ssm_chunk_counters(self):
-        """``ssm.scan.chunks[_with_reset]`` and their ratio: how many of the
-        state-space scan's chunks hold a document's start (a reset inside the
-        chunk, which a kernel has to mask and cannot skip), counted once a
-        step ON THE HOST from the host batch's segment ids, as the flash
-        kernel's tiles are. None for a model without state-space layers."""
+    def _build_scan_chunk_counters(self):
+        """``<scan>.chunks[_with_reset]`` and their ratio ``<scan>.
+        reset_chunk_share`` for a model with recurrent layers (``ssm.scan``:
+        the Mamba-2 layers' state-space scan; ``kda.scan``: the Kimi Delta
+        Attention layers' recurrence): how many of the scan's chunks hold a
+        document's start (a reset inside the chunk, which a kernel has to mask
+        and cannot skip), counted once a step ON THE HOST from the host
+        batch's segment ids, as the flash kernel's tiles are. None for a model
+        without such layers."""
+        from veomni_tpu.ops import kda
         from veomni_tpu.ops.ssd_scan import chunk_census
 
         cfg = getattr(self.model, "config", None)
-        layers = list(getattr(cfg, "layer_types", None) or ()).count("mamba")
-        if not getattr(cfg, "mamba_n_heads", 0) or not layers:
+        if getattr(cfg, "mamba_n_heads", 0):
+            name, chunk = "ssm.scan", cfg.mamba_chunk_size
+            layers = list(getattr(cfg, "layer_types", None) or ()).count("mamba")
+        elif getattr(cfg, "linear_attn_config", None):
+            name, chunk = "kda.scan", kda.CHUNK
+            layers = len(cfg.linear_attn_config.get("kda_layers", ()))
+        else:
+            return None
+        if not layers:
             return None
         reg = get_registry()
-        chunks, resets = reg.counter("ssm.scan.chunks"), reg.counter("ssm.scan.chunks_with_reset")
-        share = reg.gauge("ssm.scan.reset_chunk_share")
+        chunks, resets = reg.counter(f"{name}.chunks"), reg.counter(f"{name}.chunks_with_reset")
+        share = reg.gauge(f"{name}.reset_chunk_share")
 
         def count(batch_np):
             seg = batch_np.get("segment_ids")
             if seg is None:
                 return
-            n, n_reset = chunk_census(seg, cfg.mamba_chunk_size)
+            n, n_reset = chunk_census(seg, chunk)
             chunks.inc(n * layers)
             resets.inc(n_reset * layers)
             share.set(resets.value / chunks.value)
@@ -968,8 +979,8 @@ class BaseTrainer:
                             self.current_batch = batch_np
                             if self._flash_tile_counters is not None:
                                 self._flash_tile_counters(batch_np)
-                            if self._ssm_chunk_counters is not None:
-                                self._ssm_chunk_counters(batch_np)
+                            if self._scan_chunk_counters is not None:
+                                self._scan_chunk_counters(batch_np)
                             # straggler drill point (fleet observatory): a
                             # `delay`-mode fault here slows THIS rank's loop
                             # deterministically, so the skew exchange +

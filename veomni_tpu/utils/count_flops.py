@@ -14,6 +14,8 @@ per-architecture formulas used by the MFU meter. Implemented terms:
 * qwen3_next GatedDeltaNet linear-attention layers (chunkwise cost model);
 * Mamba-2 state-space layers (granitemoehybrid: projections, conv, the
   chunked scan's four matmuls), counted per ``layer_types``;
+* Kimi Delta Attention layers (kimi_linear: projections, convs, the chunked
+  recurrence's matmuls), counted per ``linear_attn_config``;
 * ViT towers (per-patch, window or full attention) and DiT blocks via the
   dedicated helpers, fed to the meter as ``extra_flops``.
 
@@ -76,6 +78,13 @@ class FlopsCounter:
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # kimi_linear: Kimi Delta Attention layers where ``linear_attn_config``
+    # lists them (``n_kda_layers`` of ``num_layers``), MLA elsewhere
+    n_kda_layers: int = 0
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_chunk: int = 64
 
     # ------------------------------------------------------------- per-term
     def _attn_proj_flops(self) -> float:
@@ -151,6 +160,17 @@ class FlopsCounter:
         scan = 2 * c * bc + 2 * c * d_inner + 2 * 2 * d_inner * n
         return proj + conv + scan
 
+    def _kda_flops(self) -> float:
+        """Kimi Delta Attention mixer per-token fwd cost: q, k, v, the two
+        low-rank pairs, beta and the output projection; three convs; the
+        chunked recurrence (inside a chunk of c tokens the two pair terms, the
+        triangular system applied once and its rows against P: 8 c d a head;
+        the state read through k and q and written: 6 d^2)."""
+        h, nh, d, c = self.hidden_size, self.kda_num_heads, self.kda_head_dim, self.kda_chunk
+        proj = 2 * h * 3 * nh * d + 2 * (2 * h * d + 2 * d * nh * d) + 2 * h * nh + 2 * nh * d * h
+        conv = 2 * 3 * nh * d * self.kda_conv_kernel
+        return proj + conv + nh * (8 * c * d + 6 * d * d)
+
     # ------------------------------------------------------------ aggregate
     def flops_per_token_fwd(self, seq_len: int) -> float:
         mlp = self._mlp_flops()
@@ -158,6 +178,9 @@ class FlopsCounter:
         if self.n_ssm_layers:
             body = (self.n_ssm_layers * (self._ssm_flops() + mlp)
                     + (self.num_layers - self.n_ssm_layers) * full_layer)
+        elif self.n_kda_layers:
+            body = (self.n_kda_layers * (self._kda_flops() + mlp)
+                    + (self.num_layers - self.n_kda_layers) * full_layer)
         elif self.full_attention_interval and self.linear_num_value_heads:
             n_full = self.num_layers // self.full_attention_interval
             n_lin = self.num_layers - n_full
@@ -227,7 +250,20 @@ class FlopsCounter:
             mamba_n_groups=g("mamba_n_groups", 1),
             mamba_d_conv=g("mamba_d_conv", 4),
             mamba_chunk_size=g("mamba_chunk_size", 256),
+            **cls._kda_fields(g("linear_attn_config", None)),
         )
+
+    @staticmethod
+    def _kda_fields(linear_attn_config) -> dict:
+        if not linear_attn_config:
+            return {}
+        from veomni_tpu.ops.kda import CHUNK
+
+        return dict(n_kda_layers=len(linear_attn_config["kda_layers"]),
+                    kda_num_heads=linear_attn_config["num_heads"],
+                    kda_head_dim=linear_attn_config["head_dim"],
+                    kda_conv_kernel=linear_attn_config["short_conv_kernel_size"],
+                    kda_chunk=CHUNK)
 
 
 def vit_flops_fwd(vision_cfg, n_patches: int, window_seq: Optional[int] = None) -> float:
