@@ -7,6 +7,11 @@ the chips they name. So the whole of ``benchmark/tests`` runs once, in a
 child with the plain CPU backend (``JAX_PLATFORMS=cpu python -m pytest
 benchmark/tests``, as ``benchmark/README.md`` says), and every case of it is
 one case here that reads its outcome from the child's report.
+
+The child spreads its cases over a few workers of its own: in one process
+the suite takes 10 to 13 minutes alone and over 20 beside tier-1's other
+workers, which was the whole run's longest pole and passed this module's
+own limit (every case here then errors at once).
 """
 
 import os
@@ -18,6 +23,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SUITE = os.path.join("benchmark", "tests")
+CHILD_WORKERS = 3
 
 
 def _child(args, timeout):
@@ -27,12 +33,12 @@ def _child(args, timeout):
         env.pop(key, None)
     return subprocess.run(
         [sys.executable, "-m", "pytest", SUITE, "-q", "-p", "no:cacheprovider",
-         "-p", "no:xdist", "-p", "no:randomly", *args],
+         "-p", "no:randomly", *args],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=timeout)
 
 
 def _collect():
-    out = _child(["--collect-only"], timeout=300).stdout
+    out = _child(["--collect-only", "-p", "no:xdist"], timeout=300).stdout
     return [line.strip() for line in out.splitlines()
             if line.startswith(SUITE.replace(os.sep, "/")) and "::" in line]
 
@@ -44,7 +50,8 @@ CASES = _collect()
 def outcomes(tmp_path_factory):
     """node id -> (outcome, message) from one run of the whole suite."""
     report = tmp_path_factory.mktemp("benchmark_suite") / "report.xml"
-    proc = _child([f"--junitxml={report}", "-o", "junit_family=xunit1"], timeout=1200)
+    proc = _child([f"--junitxml={report}", "-o", "junit_family=xunit1",
+                   "-p", "xdist", "-n", str(CHILD_WORKERS)], timeout=1200)
     assert os.path.exists(report), proc.stdout[-3000:] + proc.stderr[-3000:]
     found = {}
     for case in ET.parse(report).getroot().iter("testcase"):

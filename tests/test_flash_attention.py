@@ -4,6 +4,8 @@ Reference test model: ``tests/ops/test_kernel_registry_numerical.py``
 (per-(op,impl) alignment). Runs the kernel in interpret mode on CPU.
 """
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,29 +97,70 @@ def _tiled_flash(q, k, v, seg, causal, tiles):
     return jnp.swapaxes(out, 1, 2)
 
 
+@contextlib.contextmanager
+def _split_backward():
+    """No dQ row fits: every backward traced under this is the split pair."""
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(fa, "_DQ_ROW_CEILING", 0)
+        yield
+
+
+@pytest.fixture
+def registry():
+    """A registry of this test's own, for the trace-time counters."""
+    from veomni_tpu.observability.metrics import MetricsRegistry, set_registry
+
+    fresh = MetricsRegistry()
+    old = set_registry(fresh)
+    yield fresh
+    set_registry(old)
+
+
+def _bwd_calls(registry):
+    return tuple(registry.counter(f"attn.flash.bwd.calls_{form}").value
+                 for form in ("fused", "split"))
+
+
+def _grads(q, k, v, w, seg, causal, tiles):
+    loss = lambda q, k, v: (_tiled_flash(q, k, v, seg, causal, tiles) * w).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _dense_grads(q, k, v, w, seg, causal):
+    seg = None if seg is None else jnp.asarray(seg)
+    loss = lambda q, k, v: (_attention_dense(q, k, v, segment_ids=seg, causal=causal) * w).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 def _check_parity(q, k, v, w, seg, causal, tiles):
-    ref_seg = None if seg is None else jnp.asarray(seg)
+    """Forward against the dense impl; the backward in BOTH its forms from
+    one forward's residuals: the fused kernel (what a row this short takes)
+    and the split pair, each against the dense impl's gradients, and against
+    each other: dK and dV bit for bit (the fused kernel's dK, dV are the
+    split dKV kernel's own lines at its own tiles), dQ within the tolerance
+    (other tiles, another order of the same f32 sums)."""
+    seg = None if seg is None else jnp.asarray(seg)
+    scale = q.shape[-1] ** -0.5
+    bhsd = lambda x: jnp.swapaxes(x, 1, 2)
+    out, residuals = fa._flash_fwd_rule(bhsd(q), bhsd(k), bhsd(v), seg, scale, causal, tiles)
+    ref = _attention_dense(q, k, v, segment_ids=seg, causal=causal)
+    np.testing.assert_allclose(np.asarray(bhsd(out)), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    assert fa._fuses_bwd(q.shape[1], q.shape[-1], q.dtype, tiles)
+    g_ref = _dense_grads(q, k, v, w, seg, causal)
+    backward = lambda: [bhsd(g) for g in fa._bwd(scale, causal, tiles, residuals, bhsd(w))[:3]]
+    g_fused = backward()
+    with _split_backward():
+        g_split = backward()
+    for form, g_got in (("fused", g_fused), ("split", g_split)):
+        for a, b_, name in zip(g_got, g_ref, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4,
+                                       err_msg=f"{form} backward: grad d{name} mismatch")
+    for a, b_, name in zip(g_fused[1:], g_split[1:], "kv"):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
+                                      err_msg=f"fused d{name} is not the split dKV kernel's")
 
-    def loss(fn):
-        return lambda q, k, v: (fn(q, k, v) * w).sum()
 
-    ref_fn = lambda q, k, v: _attention_dense(q, k, v, segment_ids=ref_seg, causal=causal)
-    got_fn = lambda q, k, v: _tiled_flash(q, k, v, seg, causal, tiles)
-    np.testing.assert_allclose(np.asarray(got_fn(q, k, v)), np.asarray(ref_fn(q, k, v)),
-                               rtol=2e-5, atol=2e-5)
-    g_ref = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
-    g_got = jax.grad(loss(got_fn), argnums=(0, 1, 2))(q, k, v)
-    for a, b_, name in zip(g_got, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4,
-                                   err_msg=f"grad d{name} mismatch")
-
-
-@pytest.mark.parametrize("group", [1, 2])
-@pytest.mark.parametrize("kind", SEG_KINDS)
-@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-def test_tiled_flash_matches_dense(causal, kind, group):
-    """Forward and backward at 4 x 4 tiles of 128 against the dense impl."""
-    b, s, hkv, d = 2, 512, 1, 64
+def _tiled_case(causal, kind, group, b=2, s=512, hkv=1, d=64):
     q, k, v, w = _bhsd_inputs(b, s, hkv * group, hkv, d)
     seg = _segments(kind, b, s, seed=3)
     t = (128, 128)
@@ -125,6 +168,21 @@ def test_tiled_flash_matches_dense(causal, kind, group):
         live = fa.tile_liveness(seg, s, 128, 128, causal)
         assert 0 < live.sum() < live.size  # some tile pairs are dead, some are not
     _check_parity(q, k, v, w, seg, causal, fa.Tiles(t, t, t))
+
+
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("kind", SEG_KINDS)
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_tiled_flash_matches_dense(causal, kind, group):
+    """Forward and backward at 4 x 4 tiles of 128 against the dense impl."""
+    _tiled_case(causal, kind, group)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_tiled_flash_with_a_gqa_group_of_four(causal):
+    """Four q heads a kv head: the kv index map's ``hi // group``, the f32
+    per-q-head dK, dV and XLA's group sum, in both backward forms."""
+    _tiled_case(causal, "packed_pad", 4, b=1)
 
 
 @pytest.mark.parametrize("tiles", [
@@ -175,6 +233,100 @@ def test_tiled_flash_without_a_table(monkeypatch):
     t = (128, 128)
     assert fa._fetch_table(fa.tile_liveness(None, s, 128, 128, True)) is None
     _check_parity(q, k, v, w, _segments("packed", b, s), True, fa.Tiles(t, t, t))
+
+
+@pytest.mark.parametrize("dead", [0, 3])
+def test_fused_backward_hands_over_zeros_for_a_q_tile_no_step_computes(dead, monkeypatch):
+    """The zeroing of a q tile's dQ rows and their hand-over run whether or
+    not the step is live: with a table (forged, for the backward's walk alone)
+    in which q tile ``dead`` is live under no kv tile, its dQ rows come out
+    zeros and not what VMEM held; every other q tile's rows are bit for bit
+    what the honest table gives, and dK, dV lose that tile's share alone."""
+    b, s, h, d, t = 2, 512, 2, 64, 128
+    q, k, v, w = _bhsd_inputs(b, s, h, h, d, seed=6)
+    seg = _segments("packed_pad", b, s, seed=3)
+    tiles = fa.Tiles((t, t), (t, t), (t, t))
+    honest = _grads(q, k, v, w, seg, True, tiles)
+    schedule = fa._schedule
+
+    def forged(segment_ids, s_, bq, bk, causal, q_outer):
+        tbl, where = schedule(segment_ids, s_, bq, bk, causal, q_outer)
+        if not q_outer:  # [B, kv tile, q tile]: q tile `dead` names another's block
+            tbl = tbl.reshape(b, s // t, s // t).at[:, :, dead].set((dead + 1) % 4).reshape(-1)
+        return tbl, where
+
+    monkeypatch.setattr(fa, "_schedule", forged)
+    got = _grads(q, k, v, w, seg, True, tiles)
+    rows = slice(dead * t, (dead + 1) * t)
+    assert np.abs(np.asarray(honest[0][:, rows])).max() > 1e-3  # the honest rows are not zeros
+    np.testing.assert_array_equal(np.asarray(got[0][:, rows]), 0.0)
+    kept = np.ones(s, bool)
+    kept[rows] = False
+    np.testing.assert_array_equal(np.asarray(got[0][:, kept]), np.asarray(honest[0][:, kept]))
+    # dK, dV without that q tile's queries: the dense impl's with their weights zeroed
+    # (dK also loses the dead tile's ds q, which zeroed weights give too: ds = p (dp - delta))
+    g_ref = _dense_grads(q, k, v, w.at[:, rows].set(0.0), seg, True)
+    for a, b_, name in zip(got[1:], g_ref[1:], "kv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4,
+                                   err_msg=f"grad d{name} mismatch")
+
+
+# the benchmark's four cells' calls: (S, q/k width, v width)
+CELL_CALLS = {"qwen3_0p6b": (4096, 128, 128), "joyai_llm_flash": (8192, 192, 128),
+              "granite_4_0_h_micro": (8192, 64, 64), "kimi_linear_48b_a3b": (8192, 192, 128)}
+
+
+@pytest.mark.parametrize("cell", list(CELL_CALLS))
+def test_every_cells_backward_is_the_fused_one_at_dkvs_tiles(cell):
+    """The rule reads S and D alone: each cell's call keeps its dQ row in
+    VMEM, beside the tiles the split dKV kernel had (the row is counted on
+    its own line, against its own ceiling, and moves no tile)."""
+    s, d, dv = CELL_CALLS[cell]
+    tiles = fa.choose_tiles(s, d, jnp.bfloat16, True, fa._other_width(d, dv))
+    assert fa._fuses_bwd(s, d, jnp.bfloat16, tiles)
+    lanes = fa._lane_padded(d)
+    row = fa._dq_row_bytes(s, tiles.dkv[0], d, 2)
+    assert row == s * lanes * 4 + 2 * tiles.dkv[0] * lanes * 2 <= fa._DQ_ROW_CEILING
+    bq, bk = tiles.dkv
+    assert fa._vmem_bytes("dkv", bq, bk, d, 2, fa._other_width(d, dv)) <= fa._VMEM_BUDGET
+    assert bq * bk >= 512 * 1024 or d > 128  # what Tiles.dkv was before there was a row
+
+
+def test_a_row_too_long_for_the_ceiling_keeps_the_split_pair(monkeypatch, registry):
+    """A 32k row of 128-wide heads is fused (16 MiB of dQ), a 64k or 128k row
+    is not; and with the ceiling between two small rows, the shorter goes
+    fused, the longer split, by the counters, and both agree with the dense
+    impl."""
+    fuses = lambda s, d: fa._fuses_bwd(s, d, jnp.bfloat16, fa.choose_tiles(s, d, jnp.bfloat16, True))
+    assert fuses(32768, 128) and not fuses(65536, 128) and not fuses(131072, 128)
+    assert fuses(16384, 256) and not fuses(32768, 256) and not fuses(65536, 64)  # 64 fills 128 lanes
+    t = (128, 128)
+    tiles = fa.Tiles(t, t, t)
+    monkeypatch.setattr(fa, "_DQ_ROW_CEILING", fa._dq_row_bytes(128, 128, 64, 4))
+    for s, calls in ((128, (1, 0)), (256, (1, 1))):
+        q, k, v, w = _bhsd_inputs(1, s, 2, 1, 64, seed=s)
+        seg = _segments("packed_pad", 1, s, seed=2)
+        got = _grads(q, k, v, w, seg, True, tiles)
+        assert _bwd_calls(registry) == calls
+        for a, b_ in zip(got, _dense_grads(q, k, v, w, seg, True)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4)
+
+
+def test_backward_counters_count_traced_call_sites(registry):
+    """``attn.flash.bwd.calls_fused`` / ``.calls_split`` rise once a traced
+    backward (a call site: a jitted gradient that runs three times traces
+    once), under the form the call took; a forward alone counts nothing."""
+    q, k, v, seg = _inputs()
+    loss = lambda q, k, v: flash_attention(q, k, v, segment_ids=seg, causal=True).sum()
+    flash_attention(q, k, v, segment_ids=seg, causal=True)
+    assert _bwd_calls(registry) == (0, 0)
+    g = jax.jit(jax.grad(loss))
+    for _ in range(3):
+        g(q, k, v)
+    assert _bwd_calls(registry) == (1, 0)
+    with _split_backward():
+        jax.grad(loss)(q, k, v)
+    assert _bwd_calls(registry) == (1, 1)
 
 
 def _dense_liveness(seg, s, bq, bk, causal):

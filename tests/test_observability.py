@@ -708,6 +708,7 @@ def test_every_kernel_name_is_in_the_metric_reference():
 
 @pytest.mark.parametrize("name", [
     "attn.mla_qkv_rope.calls_kernel", "attn.mla_qkv_rope.calls_handed_over",
+    "attn.flash.bwd.calls_fused", "attn.flash.bwd.calls_split",
     "op mla_qkv_rotary: pallas", "op qk_norm_rotary:", "op attention:",
 ])
 def test_kernel_engagement_counters_and_hand_over_lines_are_in_the_metric_reference(name):

@@ -7,10 +7,29 @@ external flash-attn CUDA wheels, varlen via cu_seqlens). TPU-native design:
   attends to the tokens of equal id, and to nothing else. Padding is one more
   id (the collator's 0): padding positions attend to each other, and their
   rows are dropped by the loss, not by the kernel.
-* layout [B, H, S, D]; three kernels, ``flash_fwd``, ``flash_bwd_dkv`` and
-  ``flash_bwd_dq`` (the flash-v2 recomputation split, from the saved LSE),
-  each over a grid (batch, q_head, outer tile, inner tile) whose inner axis is
+* layout [B, H, S, D]; kernels ``flash_fwd``, ``flash_bwd_dkv`` and
+  ``flash_bwd_dq`` (the flash-v2 recomputation from the saved LSE), each over
+  a grid (batch, q_head, outer tile, inner tile) whose inner axis is
   sequential and carries its accumulators in VMEM scratch.
+* **the backward has two forms, and the call's shape alone chooses**
+  (:func:`_fuses_bwd`; no argument, variable or config key does).
+  *Fused*: ONE call, named ``flash_bwd_dkv`` because it is that kernel's grid
+  (batch, q head, kv tile, q tile), gives dK, dV and dQ: the scores, the
+  exponentials, the masks, dP and dS are made once a tile pair (five matmuls
+  and one elementwise chain, where the pair of kernels does seven and two).
+  dK and dV gather over the inner q tiles as they always did; dQ of the whole
+  row of the (batch, head) the grid is in gathers in an f32 VMEM scratch
+  ``[S, d]`` over the outer kv tiles, which therefore run in order
+  (``"arbitrary"``), and leaves through a ``[bq, d]`` output block of
+  ``q.dtype`` while the last kv tile walks the row. That row costs
+  ``S x lanes(d) x 4`` bytes plus the block twice (:func:`_dq_row_bytes`: 2.3
+  MiB at 4096 x 128, 4.3 at 8192 x 64, 8.5 at 8192 x 192), counted against
+  ``_DQ_ROW_CEILING`` beside, not inside, the budget the tiles are chosen
+  under, so the fused kernel's tiles are ``Tiles.dkv``. *Split*: a row too
+  long for the ceiling (64k of 128-wide heads: 32 MiB) keeps the pair,
+  ``flash_bwd_dkv`` for dK, dV and ``flash_bwd_dq`` on its own grid (batch,
+  q head, q tile, kv tile) with ``Tiles.dq``. Each traced backward counts
+  once under ``attn.flash.bwd.calls_fused`` or ``.calls_split``.
 * **tile schedule.** The tile sizes come from the call's shape
   (:func:`choose_tiles`: the largest that divide S and fit a VMEM budget, one
   pair per kernel). Which (q-tile, kv-tile) pairs hold any admitted
@@ -41,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from veomni_tpu.observability.metrics import get_registry
 from veomni_tpu.ops.kernel_registry import KERNEL_REGISTRY
 from veomni_tpu.utils.logging import get_logger
 
@@ -53,10 +73,13 @@ _ROWS = 8     # lane width of the column-form row stats (lse, delta): a block
 _TILE_SIZES = (1024, 512, 256, 128)
 _VMEM_BUDGET = 24 * 2 ** 20   # what a kernel's blocks, scratch and score-sized
                               # temporaries may come to (v5e: 128 MiB of VMEM)
+_DQ_ROW_CEILING = 24 * 2 ** 20  # what the fused backward's resident dQ row may
+                                # come to, beside (not inside) the tiles' budget
 _TABLE_WORDS = 64 * 1024      # the liveness table lives in SMEM: past this
                               # many entries a call goes without one
 _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
 
 
 def _interpret() -> bool:
@@ -74,6 +97,11 @@ class Tiles(NamedTuple):
     dq: Tuple[int, int]
 
 
+def _lane_padded(width: int) -> int:
+    """A block's last dim as VMEM holds it: whole 128-lane tiles."""
+    return -(-width // _LANES) * _LANES
+
+
 def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, itemsize: int,
                 dv: Optional[int] = None) -> int:
     """VMEM a kernel needs at these tiles: its blocks twice (the pipeline
@@ -87,7 +115,7 @@ def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, itemsize: int,
     if dv is None:
         dv = d
     else:
-        d, dv = (-(-w // _LANES) * _LANES for w in (d, dv))
+        d, dv = _lane_padded(d), _lane_padded(dv)
     # q (or dQ), k (or dK) blocks; v (or dV), o (or dO) blocks
     q_blk, k_blk, o_blk, v_blk = bq * d * itemsize, bk * d * itemsize, bq * dv * itemsize, \
         bk * dv * itemsize
@@ -104,6 +132,21 @@ def _vmem_bytes(kernel: str, bq: int, bk: int, d: int, itemsize: int,
         scratch = bk * (d + dv) * 4
         scores = 6 * bq * bk * 4
     return 2 * blocks + scratch + scores
+
+
+def _dq_row_bytes(s: int, bq: int, d: int, itemsize: int) -> int:
+    """VMEM the fused backward holds beside its tiles: dQ of the whole row of
+    one (batch, head) in f32, ``s`` x the lanes ``d`` fills x 4, and the
+    ``[bq, d]`` block it is handed out through, twice (4096 x 128: 2 MiB + 256
+    KiB; 8192 x 192, which fills 256 lanes: 8 MiB + 512 KiB)."""
+    return _lane_padded(d) * (s * 4 + 2 * bq * itemsize)
+
+
+def _fuses_bwd(s: int, d: int, dtype, tiles: Tiles) -> bool:
+    """Which backward a call takes, by its shape alone: the fused one where
+    the resident dQ row fits its ceiling (a 32k row of 128-wide heads does, at
+    16 MiB; a 64k row does not), the split pair elsewhere."""
+    return _dq_row_bytes(s, tiles.dkv[0], d, jnp.dtype(dtype).itemsize) <= _DQ_ROW_CEILING
 
 
 @functools.lru_cache(maxsize=None)
@@ -285,13 +328,18 @@ def _other_width(d: int, dv: int) -> Optional[int]:
     return None if dv == d else dv
 
 
-def _compiler_params(kernel: str, bq: int, bk: int, d: int, dtype, dv: Optional[int] = None):
+def _compiler_params(kernel: str, bq: int, bk: int, d: int, dtype, dv: Optional[int] = None,
+                     dq_row: int = 0):
+    """``dq_row``: the bytes of the fused backward's resident dQ row (0: the
+    kernel holds none). With one, the kv-tile axis carries it from tile to
+    tile and is sequential too (a v5e has one core: nothing is lost)."""
     need = _vmem_bytes(kernel, bq, bk, d, jnp.dtype(dtype).itemsize, dv)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", "arbitrary" if dq_row else "parallel",
+                             "arbitrary"),
         # twice the counted footprint: the compiler's own temporaries are not
         # all counted, and the limit only has to be one it can stay under
-        vmem_limit_bytes=int(min(max(2 * need, 16 * 2 ** 20), 100 * 2 ** 20)),
+        vmem_limit_bytes=int(min(max(2 * need + dq_row, 16 * 2 ** 20), 100 * 2 ** 20)),
     )
 
 
@@ -388,18 +436,32 @@ def _fwd(q, k, v, segment_ids, scale, causal, tiles):
 # ==========================================================================
 # Backward
 # ==========================================================================
-def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, table, segmented, where):
+def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, table, segmented, where, fused):
     """dK, dV of one kv tile, summed over the q tiles (the inner axis). The
     scores are computed transposed, [bk, bq]: p^T and ds^T are then the left
-    operands of plain matmuls, and lse and delta ride as rows."""
+    operands of plain matmuls, and lse and delta ride as rows.
+
+    ``fused``: dQ too, on the same walk. The whole row's dQ of the (batch,
+    head) the grid is in stays in an f32 scratch ``[S, d]``: a q tile's rows
+    are zeroed when the first kv tile visits them, take ``ds k`` (``ds^T``
+    turned round once) on every live step, and go out through ``dq_ref`` when
+    the last kv tile visits them, live step or not."""
     tbl_ref, seg_k_ref, seg_q_ref, refs = _split_refs(refs, table, segmented)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *refs = refs
+    dq_ref, dk_scr, dv_scr, dq_scr = refs if fused else (None, *refs, None)
     bi, jk, iq = pl.program_id(0), pl.program_id(2), pl.program_id(3)
 
     @pl.when(iq == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    if fused:
+        rows = pl.ds(pl.multiple_of(iq * bq, bq), bq)  # this q tile's, in the dQ row
+
+        @pl.when(jk == 0)
+        def _init_dq():
+            dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), dq_scr.dtype)
 
     @pl.when(_step_is_live(tbl_ref, bi, iq, jk, q_outer=False, causal=causal, bq=bq, bk=bk,
                            where=where))
@@ -428,11 +490,22 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, table, segmented, where):
         dst = pt * (dpt - delta) * scale
         dk_scr[...] += jax.lax.dot_general(
             dst, q, _NN, preferred_element_type=jnp.float32)
+        if fused:
+            # ds k with ds^T as the left operand, contracted on its rows; an
+            # explicit transpose, of the block as it is or rounded to the
+            # inputs' dtype first, read the same to 0.6% (PERF.md, PR 39)
+            dq_scr[rows, :] += jax.lax.dot_general(
+                dst, k, _TN, preferred_element_type=jnp.float32)
 
     @pl.when(iq == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[0, 0, :, :] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[...].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when(jk == pl.num_programs(2) - 1)
+        def _finish_dq():
+            dq_ref[0, 0, :, :] = dq_scr[rows, :].astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(*refs, scale, causal, bq, bk, table, segmented, where):
@@ -480,47 +553,61 @@ def _bwd(scale, causal, tiles, residuals, g):
     group = hq // hkv
     segmented = segment_ids is not None
     segs = _seg_forms(segment_ids)
+    fused = _fuses_bwd(s, d, q.dtype, tiles)
+    get_registry().counter(
+        "attn.flash.bwd.calls_fused" if fused else "attn.flash.bwd.calls_split").inc()
 
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,S]
-    # dQ takes the row stats as columns [B,H,S,_ROWS], dKV as rows [B,H,1,S]
-    delta_cols = jnp.broadcast_to(delta[..., None], delta.shape + (_ROWS,))
+    # the split dQ takes the row stats as columns [B,H,S,_ROWS], dKV as rows [B,H,1,S]
     lse_rows, delta_rows = lse[..., 0][:, :, None, :], delta[:, :, None, :]
 
-    # ---- dK, dV: grid (b, h, kv tile, q tile)
+    # ---- dK, dV (and, fused, dQ): grid (b, h, kv tile, q tile)
     bq, bk = tiles.dkv
     tbl, where = _schedule(segment_ids, s, bq, bk, causal, False)
     specs = _block_specs(bq, bk, d, group, segmented, False, where, dv_)
-    dk_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))
-    dv_spec = pl.BlockSpec((1, 1, bk, dv_), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))
-    dk_per_head, dv_per_head = pl.pallas_call(
+    out_specs = [pl.BlockSpec((1, 1, bk, d), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0)),
+                 pl.BlockSpec((1, 1, bk, dv_), lambda bi, hi, jk, iq, *t: (bi, hi, jk, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, hq, s, d), jnp.float32),
+                 jax.ShapeDtypeStruct((b, hq, s, dv_), jnp.float32)]
+    scratch_shapes = [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, dv_), jnp.float32)]
+    dq_row = 0
+    if fused:
+        dq_row = _dq_row_bytes(s, bq, d, q.dtype.itemsize)
+        last_kv = s // bk - 1
+        # the step's own q tile (not the table's: a dead step hands over too)
+        # while the last kv tile walks the row; block 0, unwritten, till then
+        out_specs.append(pl.BlockSpec(
+            (1, 1, bq, d), lambda bi, hi, jk, iq, *t: (bi, hi, jnp.where(jk == last_kv, iq, 0), 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, hq, s, d), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((s, d), jnp.float32))
+    dk_per_head, dv_per_head, *dq = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-                          table=tbl is not None, segmented=segmented, where=where),
+                          table=tbl is not None, segmented=segmented, where=where, fused=fused),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=int(tbl is not None),
             grid=(b, hq, s // bk, s // bq),
             in_specs=[*specs["segs"], specs["q"], specs["kv"], specs["v"], specs["o"],
                       specs["q_rows"], specs["q_rows"]],
-            out_specs=[dk_spec, dv_spec],
-            scratch_shapes=[
-                pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, dv_), jnp.float32),
-            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, s, dv_), jnp.float32),
-        ],
-        compiler_params=_compiler_params("dkv", bq, bk, d, q.dtype, _other_width(d, dv_)),
+        out_shape=out_shape,
+        compiler_params=_compiler_params("dkv", bq, bk, d, q.dtype, _other_width(d, dv_), dq_row),
         interpret=_interpret(),
+        # the fused call too: it is this kernel's grid, one event a backward
+        # call, and the benchmark reads the backward by this name
         name="flash_bwd_dkv",
     )(*_prefetch(tbl), *segs, q, k, v, do, lse_rows, delta_rows)
 
     # GQA: fold the q-head group into the kv head grad
     dk = dk_per_head.reshape(b, hkv, group, s, d).sum(axis=2).astype(k.dtype)
     dv = dv_per_head.reshape(b, hkv, group, s, dv_).sum(axis=2).astype(v.dtype)
+    if fused:
+        return dq[0], dk, dv, None
 
-    # ---- dQ: grid (b, h, q tile, kv tile)
+    # ---- dQ, split: grid (b, h, q tile, kv tile)
     bq, bk = tiles.dq
+    delta_cols = jnp.broadcast_to(delta[..., None], delta.shape + (_ROWS,))
     tbl, where = _schedule(segment_ids, s, bq, bk, causal, True)
     specs = _block_specs(bq, bk, d, group, segmented, True, where, dv_)
     dq = pl.pallas_call(
