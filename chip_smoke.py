@@ -25,7 +25,7 @@ fails the run. Without a TPU the first child exits non-zero with
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
 
 The phase functions take their sizes as arguments so that
-``tests/test_chip_compile.py`` can rehearse them at toy size on the CPU;
+``tests/test_chip_smoke.py`` can rehearse them at toy size on the CPU;
 the script itself has no size options. Wall times are printed as
 ``smoke_seconds``: they include whatever the phase compiled and are not
 rates.

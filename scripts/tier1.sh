@@ -2,7 +2,9 @@
 # Tier-1 as the driver runs it (ROADMAP.md "Tier-1 verify", docs/testing.md):
 # lint, the chaos smoke, then ONE pytest invocation over tests/ with six
 # xdist workers (one test file per worker at a time) under one timeout. The
-# driver's own run took 843 s of its 1,470 (its last run before PR 31).
+# driver's own run was cut at 1,472 s of its 1,470 on PR 39's tree; on one
+# sandbox that tree took 1,326 / 1,272 s (9,806 / 9,322 CPU s) and PR 40's
+# 859 / 867 (6,070 / 6,165), four runs in turn (docs/testing.md "Tier-1").
 # Prints DOTS_PASSED=<passed tests>; the worst exit code of the three wins,
 # and a failing stage never stops the later ones.
 #

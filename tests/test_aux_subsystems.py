@@ -227,7 +227,7 @@ def test_trim_safetensor_layers(tmp_path):
     r = subprocess.run(
         [sys.executable, "scripts/trim_safetensor_layers.py",
          "--model_dir", str(src), "--out_dir", str(out), "--num_layers", "2"],
-        capture_output=True, text=True, cwd="/root/repo",
+        capture_output=True, text=True, cwd="/root/repo", timeout=300,
     )
     assert r.returncode == 0, r.stderr
     from safetensors import safe_open
@@ -259,7 +259,7 @@ def test_merge_chrome_trace(tmp_path):
     r = subprocess.run(
         [sys.executable, "scripts/merge_chrome_trace.py", str(out),
          str(tmp_path / "t0.json"), str(tmp_path / "t1.json")],
-        capture_output=True, text=True, cwd="/root/repo",
+        capture_output=True, text=True, cwd="/root/repo", timeout=300,
     )
     assert r.returncode == 0, r.stderr
     with open(out) as f:

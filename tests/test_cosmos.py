@@ -11,13 +11,16 @@ from veomni_tpu.models.cosmos import (
     CosmosConfig,
     _dwt,
     _idwt,
-    decode,
-    decode_code,
-    encode,
     fsq_indices_to_codes,
     fsq_quantize,
-    init_params,
 )
+from veomni_tpu.models import cosmos, omni
+from veomni_tpu.utils.testing import under_jit
+
+# whole models as one program a shape, not op by op
+init_params, encode, decode, decode_code = (
+    under_jit(f) for f in (cosmos.init_params, cosmos.encode, cosmos.decode, cosmos.decode_code))
+init_omni_params, omni_loss_fn = under_jit(omni.init_omni_params), under_jit(omni.omni_loss_fn)
 
 TINY = dict(channels=8, channels_mult=(1, 2), num_res_blocks=1,
             attn_resolutions=(4,), in_channels=3, out_channels=3,
@@ -65,7 +68,7 @@ def test_encode_decode_shapes():
 
 
 def test_omni_composite_with_cosmos():
-    from veomni_tpu.models.omni import OmniConfig, init_omni_params, omni_loss_fn
+    from veomni_tpu.models.omni import OmniConfig
 
     TEXT = dict(model_type="qwen2", vocab_size=600, hidden_size=64,
                 intermediate_size=128, num_hidden_layers=2,
@@ -97,7 +100,7 @@ def test_omni_composite_with_cosmos():
     total, metrics = omni_loss_fn(params, cfg, batch)
     assert np.isfinite(float(total))
     assert int(metrics["gen_ntokens"]) == t_gen
-    grads = jax.grad(lambda p: omni_loss_fn(p, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: omni.omni_loss_fn(p, cfg, batch)[0]))(params)
     assert all(float(jnp.abs(g).max()) == 0.0
                for g in jax.tree.leaves(grads["image_gen"]["movq"]))
     assert float(jnp.abs(grads["image_gen"]["gen_head"]["fc2"]).sum()) > 0.0

@@ -14,12 +14,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.models.deepseek_v4 import (
-    DeepseekV4Config,
-    forward_logits,
-    init_params,
-    loss_fn,
-)
+from veomni_tpu.models import deepseek_v4
+from veomni_tpu.models.deepseek_v4 import DeepseekV4Config
+
+
+from veomni_tpu.utils.testing import under_jit
+
+init_params, loss_fn, forward_logits = (
+    under_jit(f) for f in (deepseek_v4.init_params, deepseek_v4.loss_fn, deepseek_v4.forward_logits))
 
 CFG = dict(
     vocab_size=128,
@@ -87,7 +89,7 @@ def test_forward_finite_and_grads(model):
     assert int(metrics["ntokens"]) == 2 * 31
 
     # allow_int: the frozen hash table (tid2eid, int32) rides in params
-    grads = jax.grad(lambda p: loss_fn(p, cfg, batch)[0], allow_int=True)(params)
+    grads = jax.jit(jax.grad(lambda p: deepseek_v4.loss_fn(p, cfg, batch)[0], allow_int=True))(params)
     # every trainable leaf gets gradient signal, EXCEPT: the frozen hash
     # table (int, non-diff) and the lightning indexer (it only drives the
     # non-differentiable top-k selection; the reference trains it with a

@@ -730,7 +730,7 @@ def _run_driver(tmp_path, cfg, ndev, extra_env=None):
     cfg_path.write_text(json.dumps(cfg))
     env = dict(
         os.environ, JAX_PLATFORMS="cpu", VEOMNI_LOG_LEVEL="WARNING",
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
+        XLA_FLAGS=f"{os.environ.get('XLA_FLAGS', '')} --xla_force_host_platform_device_count={ndev}",
     )
     env.pop("VEOMNI_FAULT_PLAN", None)
     if extra_env:

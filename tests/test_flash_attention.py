@@ -122,14 +122,16 @@ def _bwd_calls(registry):
 
 
 def _grads(q, k, v, w, seg, causal, tiles):
+    """One program of its own a call (not op by op): traced under whatever
+    the case planted, and one traced backward for the counters."""
     loss = lambda q, k, v: (_tiled_flash(q, k, v, seg, causal, tiles) * w).sum()
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
 
 def _dense_grads(q, k, v, w, seg, causal):
     seg = None if seg is None else jnp.asarray(seg)
     loss = lambda q, k, v: (_attention_dense(q, k, v, segment_ids=seg, causal=causal) * w).sum()
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
 
 def _check_parity(q, k, v, w, seg, causal, tiles):
@@ -142,12 +144,16 @@ def _check_parity(q, k, v, w, seg, causal, tiles):
     seg = None if seg is None else jnp.asarray(seg)
     scale = q.shape[-1] ** -0.5
     bhsd = lambda x: jnp.swapaxes(x, 1, 2)
-    out, residuals = fa._flash_fwd_rule(bhsd(q), bhsd(k), bhsd(v), seg, scale, causal, tiles)
-    ref = _attention_dense(q, k, v, segment_ids=seg, causal=causal)
+    # each side one program, not op by op; the backward's a fresh one a form,
+    # so that it is traced under that form's ceiling
+    out, residuals = jax.jit(lambda q, k, v: fa._flash_fwd_rule(
+        bhsd(q), bhsd(k), bhsd(v), seg, scale, causal, tiles))(q, k, v)
+    ref = jax.jit(lambda q, k, v: _attention_dense(q, k, v, segment_ids=seg, causal=causal))(q, k, v)
     np.testing.assert_allclose(np.asarray(bhsd(out)), np.asarray(ref), rtol=2e-5, atol=2e-5)
     assert fa._fuses_bwd(q.shape[1], q.shape[-1], q.dtype, tiles)
     g_ref = _dense_grads(q, k, v, w, seg, causal)
-    backward = lambda: [bhsd(g) for g in fa._bwd(scale, causal, tiles, residuals, bhsd(w))[:3]]
+    backward = lambda: jax.jit(lambda res, w: [
+        bhsd(g) for g in fa._bwd(scale, causal, tiles, res, bhsd(w))[:3]])(residuals, w)
     g_fused = backward()
     with _split_backward():
         g_split = backward()

@@ -14,9 +14,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.models.flux import (
-    FluxConfig, flux_forward, hf_to_params, init_params, loss_fn, params_to_hf,
-)
+from veomni_tpu.models import flux
+from veomni_tpu.models.flux import FluxConfig, hf_to_params, params_to_hf
+from veomni_tpu.utils.testing import under_jit
+
+# whole models as one program a shape, not op by op
+init_params, loss_fn, flux_forward = (
+    under_jit(f) for f in (flux.init_params, flux.loss_fn, flux.flux_forward))
 
 TINY = dict(
     in_channels=8,
@@ -97,7 +101,7 @@ def test_loss_and_grads_finite(model):
     }
     loss_sum, metrics = loss_fn(params, cfg, batch)
     assert np.isfinite(float(loss_sum))
-    grads = jax.grad(lambda p: loss_fn(p, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: flux.loss_fn(p, cfg, batch)[0]))(params)
     for path, g in jax.tree_util.tree_leaves_with_path(grads):
         assert np.all(np.isfinite(np.asarray(g))), jax.tree_util.keystr(path)
     # single-stream params receive signal

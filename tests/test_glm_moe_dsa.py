@@ -16,6 +16,10 @@ import jax.numpy as jnp
 
 from veomni_tpu.models.config import TransformerConfig
 from veomni_tpu.models import transformer
+from veomni_tpu.utils.testing import under_jit
+
+# whole models as one program a shape, not op by op
+init_params, loss_fn = under_jit(transformer.init_params), under_jit(transformer.loss_fn)
 
 BASE = dict(
     model_type="glm_moe_dsa",
@@ -51,7 +55,7 @@ BASE = dict(
 
 def _mk(cfg_kw):
     cfg = TransformerConfig(**cfg_kw)
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_params(jax.random.PRNGKey(0), cfg)
     return cfg, params
 
 
@@ -75,7 +79,7 @@ def test_topk_full_equals_dense():
     kw = dict(BASE, index_topk=s)
     cfg, params = _mk(kw)
     batch = _batch(cfg, rng, 2, s)
-    sparse_total, sparse_m = transformer.loss_fn(params, cfg, batch)
+    sparse_total, sparse_m = loss_fn(params, cfg, batch)
 
     dense_kw = dict(BASE)
     for k in ("index_n_heads", "index_head_dim", "index_topk"):
@@ -86,7 +90,7 @@ def test_topk_full_equals_dense():
         dense_params[tree_name] = {
             k: v for k, v in params[tree_name].items() if k != "indexer"
         }
-    dense_total, dense_m = transformer.loss_fn(dense_params, dense_cfg, batch)
+    dense_total, dense_m = loss_fn(dense_params, dense_cfg, batch)
     np.testing.assert_allclose(
         float(sparse_m["loss_sum"]), float(dense_m["loss_sum"]), rtol=1e-6
     )
@@ -99,9 +103,9 @@ def test_small_topk_differs_and_packs():
     # sparse != dense-selection (top-k actually bites)
     s = 16
     batch = _batch(cfg, rng, 1, s)
-    _, m_small = transformer.loss_fn(params, cfg, batch)
+    _, m_small = loss_fn(params, cfg, batch)
     cfg_full = TransformerConfig(**dict(BASE, index_topk=s))
-    _, m_full = transformer.loss_fn(params, cfg_full, batch)
+    _, m_full = loss_fn(params, cfg_full, batch)
     assert abs(float(m_small["loss_sum"]) - float(m_full["loss_sum"])) > 1e-6
 
     # packing equivalence: two segments in one row == two standalone rows
@@ -118,7 +122,7 @@ def test_small_topk_differs_and_packs():
             "position_ids": jnp.arange(n, dtype=jnp.int32)[None],
             "segment_ids": jnp.ones((1, n), jnp.int32),
         }
-        _, m = transformer.loss_fn(params, cfg, b)
+        _, m = loss_fn(params, cfg, b)
         return float(m["loss_sum"])
 
     packed = {
@@ -130,7 +134,7 @@ def test_small_topk_differs_and_packs():
         "segment_ids": jnp.asarray(np.concatenate(
             [np.ones(la, np.int32), np.full(lb, 2, np.int32)]))[None],
     }
-    _, mp = transformer.loss_fn(params, cfg, packed)
+    _, mp = loss_fn(params, cfg, packed)
     np.testing.assert_allclose(
         float(mp["loss_sum"]), solo(ids_a) + solo(ids_b), rtol=2e-5
     )
@@ -145,7 +149,7 @@ def test_shared_indexer_reuses_selection():
               indexer_types=("full", "shared", "shared"))
     cfg, params = _mk(kw)
     batch = _batch(cfg, rng, 1, 16)
-    base_loss = float(transformer.loss_fn(params, cfg, batch)[1]["loss_sum"])
+    base_loss = float(loss_fn(params, cfg, batch)[1]["loss_sum"])
 
     def bump(layer):
         # re-randomize the layer's indexer query projection: a fresh matrix
@@ -156,7 +160,7 @@ def test_shared_indexer_reuses_selection():
         wq[layer] = np.random.default_rng(99).standard_normal(wq[layer].shape) * 0.5
         idx["wq_b"] = jnp.asarray(wq)
         p2["layers"] = dict(p2["layers"], indexer=idx)
-        return float(transformer.loss_fn(p2, cfg, batch)[1]["loss_sum"])
+        return float(loss_fn(p2, cfg, batch)[1]["loss_sum"])
 
     assert bump(2) == base_loss            # shared layer: own indexer unused
     assert bump(0) != base_loss            # provider layer: selection shifts
@@ -166,7 +170,7 @@ def test_indexer_gets_no_lm_gradient():
     rng = np.random.default_rng(3)
     cfg, params = _mk(BASE)
     batch = _batch(cfg, rng, 1, 16)
-    grads = jax.grad(lambda p: transformer.loss_fn(p, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: transformer.loss_fn(p, cfg, batch)[0]))(params)
     for tree in ("dense_layers", "layers"):
         for leaf in jax.tree.leaves(grads[tree]["indexer"]):
             assert float(jnp.abs(leaf).max()) == 0.0

@@ -18,6 +18,7 @@ from veomni_tpu.models import granite_hybrid as gh
 from veomni_tpu.models import qwen3_next
 from veomni_tpu.models.auto import MODEL_REGISTRY, build_config
 from veomni_tpu.ops.ssd_scan import chunk_census
+from veomni_tpu.utils.testing import once_on_host, under_jit
 
 PERIOD = ["mamba", "mamba", "attention", "mamba"]
 # config.json's keys at a tiny size (the benchmark's rehearsal preset, two periods)
@@ -53,13 +54,24 @@ def packed_batch(seed=0, s=48):
             "position_ids": jnp.zeros_like(jnp.asarray(ids)), "labels": jnp.asarray(labels)}
 
 
-def seeded(model=MODEL, seed=5):
-    return ref.nest(ref.make_params(model, ref.seed_key(seed)))
+@once_on_host
+def _seeded():
+    return jax.jit(lambda key: ref.nest(ref.make_params(MODEL, key)))(ref.seed_key(5))
+
+
+def seeded():
+    """The reference's seeded weights, drawn once (under jit: drawn eagerly
+    each leaf is a dispatch of its own)."""
+    return jax.tree.map(jnp.asarray, _seeded())
 
 
 def program_loss(params, cfg, batch):
     total, metrics = FAMILY.loss_fn(params, cfg, batch)
     return total / metrics["ntokens"]
+
+
+# the whole stack as one program a (cfg, shape), not op by op
+forward_hidden = under_jit(gh.forward_hidden)
 
 
 @pytest.fixture(autouse=True)
@@ -70,7 +82,8 @@ def _exact_matmuls():
 
 # ----------------------------------------------------------- the reference
 def test_seeded_tree_is_the_programs_tree():
-    want, got = FAMILY.abstract_params(program_cfg()), jax.eval_shape(seeded)
+    want = FAMILY.abstract_params(program_cfg())
+    got = jax.eval_shape(lambda key: ref.nest(ref.make_params(MODEL, key)), ref.seed_key(5))
     assert jax.tree.structure(want) == jax.tree.structure(got)
     assert all(a.shape == b.shape and a.dtype == b.dtype
                for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)))
@@ -81,13 +94,21 @@ def test_seeded_tree_is_the_programs_tree():
     assert 0.001 <= float(dt.min()) and float(dt.max()) <= 0.1001
 
 
+@once_on_host
+def _reference_gradient():
+    """The reference's (loss, gradient leaves) from the seeded weights: it does
+    not depend on what a case plants in the program, so it is computed once."""
+    batch = packed_batch()
+    return jax.jit(jax.value_and_grad(
+        lambda p: ref.loss(p, MODEL, batch["input_ids"], batch["segment_ids"])))(seeded())
+
+
 def gaps_to_the_reference(cfg):
     """(relative gap of the loss, worst relative gap of a gradient leaf with
     its name) between the program under ``cfg`` and the reference."""
     params, batch = seeded(), packed_batch()
-    got, g_got = jax.value_and_grad(program_loss)(params, cfg, batch)
-    want, g_want = jax.value_and_grad(ref.loss)(
-        params, MODEL, batch["input_ids"], batch["segment_ids"])
+    got, g_got = jax.jit(lambda p, b: jax.value_and_grad(program_loss)(p, cfg, b))(params, batch)
+    want, g_want = _reference_gradient()
     g_got, g_want = ref.flatten(g_got), ref.flatten(g_want)
     assert set(g_got) == set(g_want) and len(g_want) == 22
     leaf = {name: float(jnp.linalg.norm(g_got[name] - w) / jnp.linalg.norm(w))
@@ -114,13 +135,13 @@ def test_a_multiplier_planted_wrong_fails_the_reference_comparison(field, wrong)
 
 def test_no_rotary_is_applied_and_the_scale_is_the_configured_one():
     params, batch = seeded(), packed_batch()
-    logits = lambda **kw: FAMILY.forward_logits(
-        params, program_cfg(**kw), batch["input_ids"], batch["position_ids"], batch["segment_ids"])
-    base = logits()
+    under = lambda **kw: jax.jit(lambda pos: FAMILY.forward_logits(
+        params, program_cfg(**kw), batch["input_ids"], pos, batch["segment_ids"]))
+    logits = lambda **kw: under(**kw)(batch["position_ids"])
+    plain = under()
+    base = plain(batch["position_ids"])
     assert jnp.array_equal(base, logits(rope_theta=123.0))
-    moved = FAMILY.forward_logits(params, program_cfg(), batch["input_ids"],
-                                  batch["position_ids"] + 7, batch["segment_ids"])
-    assert jnp.array_equal(base, moved)
+    assert jnp.array_equal(base, plain(batch["position_ids"] + 7))
     assert float(jnp.abs(base - logits(attention_multiplier=0.5)).max()) > 1e-6
 
 
@@ -145,17 +166,18 @@ def test_ssd_scan_is_the_token_by_token_recurrence(chunk, groups):
     seg = jnp.asarray(np.stack([np.repeat([1, 2, 3, 4, 0], [16, 1, 30, 20, 10]),
                                 np.repeat([1, 2], [32, 45])]), jnp.int32)
     w = jnp.asarray(rng.normal(size=(b, s, h, p)), jnp.float32)
-    got = lambda *t: ops.ssd_scan(*t, seg, chunk)
-    want = lambda *t: _recurrence(*t, seg)
+    # each side one program, forward and gradient (not op by op)
+    got = jax.jit(lambda *t: ops.ssd_scan(*t, seg, chunk))
+    want = jax.jit(lambda *t: _recurrence(*t, seg))
     args = (x, dt, a, bm, cm, d)
     y = want(*args)
     assert float(jnp.abs(got(*args) - y).max() / jnp.abs(y).max()) < 2e-5
-    grads = lambda fn: jax.grad(lambda *t: jnp.sum(fn(*t) * w), argnums=tuple(range(6)))(*args)
+    grads = lambda fn: jax.jit(jax.grad(lambda *t: jnp.sum(fn(*t) * w), argnums=tuple(range(6))))(*args)
     for name, g, r in zip(("x", "dt", "A", "B", "C", "D"), grads(got), grads(want)):
         assert float(jnp.abs(g - r).max() / jnp.abs(r).max()) < 2e-5, name
     # without segment ids it is one document
-    one = ops.ssd_scan(*args, None, chunk)
-    y = _recurrence(*args, jnp.ones_like(seg))
+    one = jax.jit(lambda *t: ops.ssd_scan(*t, None, chunk))(*args)
+    y = jax.jit(_recurrence)(*args, jnp.ones_like(seg))
     assert float(jnp.abs(one - y).max() / jnp.abs(y).max()) < 2e-5
 
 
@@ -172,12 +194,12 @@ def test_a_packed_row_is_each_document_alone():
     """Scan state AND conv taps: the hidden states of a packed row are those of
     its documents run alone, wherever the boundaries fall in a chunk."""
     cfg, params, batch = program_cfg(), seeded(), packed_batch()
-    packed = gh.forward_hidden(params, cfg, batch["input_ids"], None, batch["segment_ids"])
+    packed = forward_hidden(params, cfg, batch["input_ids"], None, batch["segment_ids"])
     ids, seg = np.asarray(batch["input_ids"]), np.asarray(batch["segment_ids"])
     for row in range(2):
         for doc in np.unique(seg[row][seg[row] > 0]):
             at = np.flatnonzero(seg[row] == doc)
-            alone = gh.forward_hidden(params, cfg, jnp.asarray(ids[row:row + 1, at]))
+            alone = forward_hidden(params, cfg, jnp.asarray(ids[row:row + 1, at]))
             gap = float(jnp.abs(alone[0] - packed[row, at]).max())
             assert gap < 2e-5, (row, doc, gap)
 
@@ -213,14 +235,17 @@ def test_period_scan_is_a_plain_loop_over_the_layer_types(period):
 
 def test_the_models_own_stack_is_a_plain_loop_over_its_layer_types():
     cfg, params, batch = program_cfg(), seeded(), packed_batch()
-    got = gh.forward_hidden(params, cfg, batch["input_ids"], None, batch["segment_ids"])
+    got = forward_hidden(params, cfg, batch["input_ids"], None, batch["segment_ids"])
     h = params["embed_tokens"][batch["input_ids"]] * cfg.embed_scale
     seen = {"mamba": 0, "attention": 0}
+    # one layer after another, each kind's layer one program (not op by op)
+    layer = {kind: jax.jit(lambda h, lp, kind=kind: gh._layer(
+        h, lp, kind=kind, cfg=cfg, segment_ids=batch["segment_ids"])) for kind in seen}
     for i, kind in enumerate(cfg.layer_types):
         at = (i // len(PERIOD), seen[kind] % PERIOD.count(kind))
         seen[kind] += 1
         lp = jax.tree.map(lambda t: t[at], params[gh.KINDS[kind]])
-        h, _ = gh._layer(h, lp, kind=kind, cfg=cfg, segment_ids=batch["segment_ids"])
+        h, _ = layer[kind](h, lp)
     h = ops.rms_norm(h, params["norm"], cfg.rms_norm_eps)
     assert float(jnp.abs(got - h).max()) < 1e-5
 

@@ -8,13 +8,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.models.janus import (
-    JanusConfig,
-    decode_code,
-    gen_vision_encode,
-    init_params,
-    loss_fn,
-)
+from veomni_tpu.models import janus
+from veomni_tpu.models.janus import JanusConfig, decode_code, gen_vision_encode, init_params
+from veomni_tpu.utils.testing import under_jit
+
+# the whole model as one program a shape, not op by op (init_params stays
+# eager: the VQ tower's draws compile slower as one program than they run)
+loss_fn = under_jit(janus.loss_fn)
 
 TEXT = dict(model_type="llama", vocab_size=600, hidden_size=64,
             intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
@@ -83,7 +83,7 @@ def test_loss_paths_live(model):
     b2["pixel_values"] = batch["pixel_values"] * -1.0
     assert float(loss_fn(params, cfg, b2)[0]) != float(total)
     # frozen VQ: gen_vision gets zero grads; gen head/aligner get signal
-    grads = jax.grad(lambda p: loss_fn(p, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: janus.loss_fn(p, cfg, batch)[0]))(params)
     assert all(float(jnp.abs(g).max()) == 0.0
                for g in jax.tree.leaves(grads["gen_vision"]))
     assert float(jnp.abs(grads["gen_head"]["fc2"]).sum()) > 0.0
@@ -105,7 +105,7 @@ def test_gen_loss_trains(model):
     @jax.jit
     def step(tr, opt_state):
         def f(tr_):
-            return loss_fn({**params, **tr_}, cfg, batch)
+            return janus.loss_fn({**params, **tr_}, cfg, batch)
 
         (_, m), g = jax.value_and_grad(f, has_aux=True)(tr)
         updates, opt_state = opt.update(g, opt_state, tr)

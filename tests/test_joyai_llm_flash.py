@@ -17,6 +17,7 @@ from benchmark.reference import mla_moe as ref
 from veomni_tpu.models import hf_io, transformer
 from veomni_tpu.models.auto import build_config
 from veomni_tpu.models.config import TransformerConfig
+from veomni_tpu.utils.testing import under_jit
 
 # the benchmark configuration's keys (its rehearsal preset's sizes): the
 # published names at the top, 4 of 16 experts held from the 5th
@@ -70,6 +71,25 @@ def _full_precision():
         yield
 
 
+# the whole model as one program a (cfg, shape), not op by op
+loss_fn = under_jit(transformer.loss_fn)
+
+
+def seeded(seed, model=MODEL):
+    """The reference's seeded weights, drawn as one program (drawn eagerly
+    each leaf is a dispatch of its own)."""
+    return jax.jit(lambda key: ref.nest(ref.make_params(model, key)))(ref.seed_key(seed))
+
+
+def moe_layer(x, lp, cfg):
+    """``transformer.moe_mlp_with_stats`` as one program."""
+    return jax.jit(lambda x, lp: transformer.moe_mlp_with_stats(x, lp, cfg))(x, lp)
+
+
+def ref_expert_layer(x, lp, model):
+    return jax.jit(lambda x, lp: ref.expert_layer(x, lp, model))(x, lp)
+
+
 # ------------------------------------------------------- program vs reference
 def test_seeded_weights_are_the_programs_tree():
     cfg = program_cfg()
@@ -95,7 +115,7 @@ def test_program_matches_the_reference_in_float32(factor):
     over the micro-batch's rows one after another."""
     cfg = program_cfg(moe_capacity_factor=factor)
     model = dict(MODEL, moe_capacity_factor=factor)
-    params = ref.nest(ref.make_params(MODEL, ref.seed_key(3)))
+    params = seeded(3)
     batch = packed_batch()
 
     def program(p):
@@ -129,16 +149,16 @@ def test_program_matches_the_reference_in_float32(factor):
 
 
 def test_mtp_term_is_in_the_loss_with_its_weight():
-    params = ref.nest(ref.make_params(MODEL, ref.seed_key(4)))
+    params = seeded(4)
     batch = packed_batch(1)
-    total, m = transformer.loss_fn(params, program_cfg(moe_capacity_factor=0.0), batch)
+    total, m = loss_fn(params, program_cfg(moe_capacity_factor=0.0), batch)
     n = float(m["ntokens"])
     assert float(m["mtp_loss"]) > 1.0
     np.testing.assert_allclose(float(total) / n,
                                float(m["loss_sum"]) / n + 0.3 * float(m["mtp_loss"]), rtol=1e-6)
     none = dict(MODEL, num_nextn_predict_layers=0)
     p0 = {k: v for k, v in params.items() if k != "mtp"}
-    total0, m0 = transformer.loss_fn(p0, program_cfg(none), batch)
+    total0, m0 = loss_fn(p0, program_cfg(none), batch)
     assert "mtp_loss" not in m0
     np.testing.assert_allclose(float(total0), float(m["loss_sum"]), rtol=1e-6)
 
@@ -149,9 +169,9 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     computes alike (the shared expert) counted once, is what the uncut
     reference layer gives."""
     whole = dict(MODEL, n_routed_experts=16, first_expert_held=0)
-    lp = jax.tree.map(lambda t: t[0], ref.nest(ref.make_params(whole, ref.seed_key(9)))["layers"])
+    lp = jax.tree.map(lambda t: t[0], seeded(9, whole)["layers"])
     x = jax.random.normal(jax.random.PRNGKey(1), (96, MODEL["hidden_size"]), jnp.float32)
-    uncut = ref.expert_layer(x, lp, whole)
+    uncut = ref_expert_layer(x, lp, whole)
     se = lp["shared_experts"]
     shared = ref._swiglu(x, se["gate_proj"], se["up_proj"], se["down_proj"], None)
     total, held_rows, shares = jnp.zeros_like(x), 0.0, 4
@@ -159,36 +179,36 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
         first = 4 * j
         part = dict(lp, experts={k: v[first:first + 4] for k, v in lp["experts"].items()})
         cfg = program_cfg(dict(MODEL, first_expert_held=first), moe_capacity_factor=0.0)
-        out, _, (dropped, held, *_) = transformer.moe_mlp_with_stats(x, part, cfg)
+        out, _, (dropped, held, *_) = moe_layer(x, part, cfg)
         assert float(dropped) == 0.0
         # the reference, given the same share, gives the same part
-        want = ref.expert_layer(x, part, dict(MODEL, first_expert_held=first))
+        want = ref_expert_layer(x, part, dict(MODEL, first_expert_held=first))
         np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-6)
         total, held_rows = total + out - shared, held_rows + float(held)
     assert held_rows == x.shape[0] * MODEL["num_experts_per_tok"]  # every assignment, once
     np.testing.assert_allclose(np.asarray(total + shared), np.asarray(uncut), rtol=1e-5, atol=1e-6)
     # and the program's own uncut layer (all experts held: the old path)
-    out, _, (_, held, *_, load) = transformer.moe_mlp_with_stats(x, lp, program_cfg(whole))
+    out, _, (_, held, *_, load) = moe_layer(x, lp, program_cfg(whole))
     np.testing.assert_allclose(np.asarray(out), np.asarray(uncut), rtol=1e-5, atol=1e-6)
     assert float(held) == held_rows and float(load) >= 1.0
 
 
 def test_a_buffer_too_short_drops_and_counts_and_a_long_one_is_dropless():
-    params = ref.nest(ref.make_params(MODEL, ref.seed_key(5)))
+    params = seeded(5)
     lp = jax.tree.map(lambda t: t[0], params["layers"])
     x = jax.random.normal(jax.random.PRNGKey(2), (128, MODEL["hidden_size"]), jnp.float32)
     dropless = program_cfg(moe_capacity_factor=0.0)
     assert transformer.held_rows(dropless, 128) == 128 * 4
-    full, _, (d0, held, *_) = transformer.moe_mlp_with_stats(x, lp, dropless)
+    full, _, (d0, held, *_) = moe_layer(x, lp, dropless)
     assert float(d0) == 0.0
     roomy = program_cfg(moe_capacity_factor=2.0)   # 2 x 128 = 256 rows >= what it got
     assert transformer.held_rows(roomy, 128) == 256 and float(held) <= 256
-    out, _, (d1, held1, *_) = transformer.moe_mlp_with_stats(x, lp, roomy)
+    out, _, (d1, held1, *_) = moe_layer(x, lp, roomy)
     assert float(d1) == 0.0 and float(held1) == float(held)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full), rtol=1e-6, atol=1e-7)
     tight = program_cfg(moe_capacity_factor=0.5)   # 128 rows: fewer than it got
     assert transformer.held_rows(tight, 128) == 128 < float(held)
-    short, _, (d2, held2, *_) = transformer.moe_mlp_with_stats(x, lp, tight)
+    short, _, (d2, held2, *_) = moe_layer(x, lp, tight)
     assert float(held2) == float(held)             # counted before the cut
     assert float(d2) == float(held) - 128         # the assignments past the buffer
     assert float(jnp.abs(short - full).max()) > 1e-3 and bool(jnp.isfinite(short).all())
@@ -199,14 +219,14 @@ def test_the_layer_counts_the_grouped_gemms_tile_visits():
     the live (row tile, expert) visits of its grouped GEMM and the pairs a
     grid over every pair would walk; at the toy widths both are 0."""
     wide = dict(MODEL, hidden_size=128, moe_intermediate_size=128)
-    lp = jax.tree.map(lambda t: t[0], ref.nest(ref.make_params(wide, ref.seed_key(5)))["layers"])
+    lp = jax.tree.map(lambda t: t[0], seeded(5, wide)["layers"])
     x = jax.random.normal(jax.random.PRNGKey(2), (128, 128), jnp.float32)
     cfg = program_cfg(wide, moe_capacity_factor=0.0)  # a buffer of 512 rows: 4 tiles of 128
-    _, _, (_, held, visits, pairs, _) = transformer.moe_mlp_with_stats(x, lp, cfg)
+    _, _, (_, held, visits, pairs, _) = moe_layer(x, lp, cfg)
     assert float(pairs) == 4 * 4
     # at least the tiles the held rows fill, at most one more per expert boundary
     assert -(-float(held) // 128) <= float(visits) <= -(-float(held) // 128) + 3
-    toy = jax.tree.map(lambda t: t[0], ref.nest(ref.make_params(MODEL, ref.seed_key(5)))["layers"])
+    toy = jax.tree.map(lambda t: t[0], seeded(5)["layers"])
     _, _, (_, _, visits, pairs, _) = transformer.moe_mlp_with_stats(
         x[:, :64], toy, program_cfg(moe_capacity_factor=0.0))
     assert (float(visits), float(pairs)) == (0.0, 0.0)
@@ -233,7 +253,7 @@ def test_held_experts_and_expert_parallel_do_not_mix():
 
     destroy_parallel_state()
     ps = init_parallel_state(ep_size=2)
-    params = ref.nest(ref.make_params(MODEL, ref.seed_key(6)))
+    params = seeded(6)
     try:
         with use_parallel_state(ps), pytest.raises(ValueError, match="moe_experts_held"):
             transformer.loss_fn(params, program_cfg(), packed_batch(rows=2))
@@ -261,13 +281,13 @@ def test_mtp_in_a_packed_row_is_each_document_alone():
     """A packed row's MTP loss is the sum over its documents, each run alone:
     the module's attention stays inside a document and no position predicts
     across a boundary."""
-    cfg = program_cfg(dict(MODEL, n_routed_experts=16, first_expert_held=0))
-    params = ref.nest(ref.make_params(dict(MODEL, n_routed_experts=16, first_expert_held=0),
-                                      ref.seed_key(7)))
+    whole = dict(MODEL, n_routed_experts=16, first_expert_held=0)
+    cfg = program_cfg(whole)
+    params = seeded(7, whole)
     batch = packed_batch(3, rows=1)
 
     def mtp_sum(b):
-        _, m = transformer.loss_fn(params, cfg, b)
+        _, m = loss_fn(params, cfg, b)
         n = int((transformer.mtp_labels(b["labels"], b["segment_ids"], 1) != -100).sum())
         return float(m["mtp_loss"]) * n, n
 
@@ -307,7 +327,7 @@ def test_dialect_mapping_and_flops_keys():
 
 def test_checkpoint_names_round_trip(tmp_path):
     cfg = program_cfg()
-    params = ref.nest(ref.make_params(MODEL, ref.seed_key(8)))
+    params = seeded(8)
     hf_io.save_hf_checkpoint(params, cfg, str(tmp_path))
     from safetensors import safe_open
 

@@ -2,10 +2,9 @@
 expert layer behind both, scanned by segments of its pattern) on the train
 path, at a tiny size on the CPU: ``ops.kda_scan`` against the token-by-token
 recurrence, the program against the benchmark's plain reference
-(``benchmark/reference/kda_hybrid.py``) for each mixer, the loss, every
-gradient leaf and two optimizer steps, a planted fault for every new group of
-parameters, the published pattern and the cut's count, the expert shares
-against the uncut layer, the checkpoint's names and the serving refusal.
+(``benchmark/reference/kda_hybrid.py``) for each mixer and a whole layer, the
+published pattern and the cut's count, the expert shares against the uncut
+layer, the checkpoint's names and the serving refusal.
 """
 
 import json
@@ -25,6 +24,7 @@ from veomni_tpu.models import transformer as core
 from veomni_tpu.models.auto import MODEL_REGISTRY, build_config
 from veomni_tpu.ops import kda
 from veomni_tpu.utils.count_flops import FlopsCounter
+from veomni_tpu.utils.testing import once_on_host
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the benchmark's rehearsal preset: the file's plain values, as the job hands
@@ -46,7 +46,7 @@ def program_cfg(model=MODEL, **kw):
     keys.update(CONFIG["program_overrides"])
     keys.update(CONFIG["rehearsal"]["program_overrides"])
     # no recompute in these tests: the same values for half the compile (the
-    # benchmark's rehearsal and tests/test_chip_compile.py run the step with it)
+    # benchmark's rehearsal and tests/test_chip_compile_steps.py run the step with it)
     keys.update(dtype="float32", remat=False)
     keys.update(kw)
     return build_config("kimi_linear", **keys)
@@ -67,8 +67,15 @@ def packed_batch(seed=0, s=160):
             "position_ids": jnp.zeros_like(jnp.asarray(ids)), "labels": jnp.asarray(labels)}
 
 
-def seeded(model=MODEL, seed=5):
-    return ref.nest(ref.make_params(model, ref.seed_key(seed)))
+@once_on_host
+def _seeded():
+    return jax.jit(lambda key: ref.nest(ref.make_params(MODEL, key)))(ref.seed_key(5))
+
+
+def seeded():
+    """The reference's seeded weights, drawn once (under jit: drawn eagerly
+    each leaf is a dispatch of its own)."""
+    return jax.tree.map(jnp.asarray, _seeded())
 
 
 def program_loss(params, cfg, batch):
@@ -111,11 +118,12 @@ def test_kda_scan_is_the_token_by_token_recurrence(chunk, strength):
     difference is far past f32."""
     args, seg = _scan_inputs(1, 2, 200, 3, 16, strength), jnp.asarray(SEGMENTS)
     w = jnp.asarray(np.random.default_rng(2).normal(size=(2, 200, 3, 16)), jnp.float32)
-    got = lambda *a: ops.kda_scan(*a, seg, chunk)
-    want = lambda *a: _recurrence(*a, seg)
+    # each side one program, forward and gradient (not op by op)
+    got = jax.jit(lambda *a: ops.kda_scan(*a, seg, chunk))
+    want = jax.jit(lambda *a: _recurrence(*a, seg))
     assert float(jnp.abs(got(*args) - want(*args)).max()) < 2e-6
-    g_got = jax.grad(lambda *a: (got(*a) * w).sum(), argnums=range(5))(*args)
-    g_want = jax.grad(lambda *a: (want(*a) * w).sum(), argnums=range(5))(*args)
+    g_got = jax.jit(jax.grad(lambda *a: (got(*a) * w).sum(), argnums=range(5)))(*args)
+    g_want = jax.jit(jax.grad(lambda *a: (want(*a) * w).sum(), argnums=range(5)))(*args)
     for name, a, b in zip("q k v g beta".split(), g_got, g_want):
         assert float(jnp.abs(a - b).max()) < 3e-5 * max(1.0, float(jnp.abs(b).max())), name
 
@@ -133,13 +141,15 @@ def test_pair_terms_about_the_chunks_start_overflow_where_the_ops_do_not(monkeyp
                           0.0) for x in lefts]
 
     seg = jnp.asarray(SEGMENTS)
+    scan = lambda: jax.jit(lambda *a: ops.kda_scan(*a, seg))   # traced under what is planted
+    want_of = jax.jit(lambda *a: _recurrence(*a, seg))
     for strength, stands in ((0.05, True), (3.0, False)):
         args = _scan_inputs(1, 2, 200, 3, 16, strength)
-        want = _recurrence(*args, seg)
-        assert float(jnp.abs(ops.kda_scan(*args, seg) - want).max()) < 2e-6
+        want = want_of(*args)
+        assert float(jnp.abs(scan()(*args) - want).max()) < 2e-6
         with monkeypatch.context() as m:
             m.setattr(kda, "_pair_terms", about_the_start)
-            wrong = ops.kda_scan(*args, seg)
+            wrong = scan()(*args)
         assert bool(jnp.isfinite(wrong).all() and jnp.abs(wrong - want).max() < 1e-4) is stands
 
 
@@ -151,7 +161,8 @@ def test_the_inverse_is_the_inverse():
 
 # ----------------------------------------------------------- the reference
 def test_seeded_tree_is_the_programs_tree():
-    want, got = FAMILY.abstract_params(program_cfg()), jax.eval_shape(seeded)
+    want = FAMILY.abstract_params(program_cfg())
+    got = jax.eval_shape(lambda key: ref.nest(ref.make_params(MODEL, key)), ref.seed_key(5))
     assert jax.tree.structure(want) == jax.tree.structure(got)
     assert all(a.shape == b.shape and a.dtype == b.dtype
                for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)))
@@ -174,12 +185,15 @@ def test_each_mixer_agrees_with_the_reference(kind):
     lp = _layer_params(kind)
     x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 160, MODEL["hidden_size"])), jnp.float32)
     seg = batch["segment_ids"]
+    # each side one program, not op by op
     if kind == "kda":
-        got = kl._kda_mixer(x, lp, cfg, seg)
+        got = jax.jit(lambda x, lp: kl._kda_mixer(x, lp, cfg, seg))(x, lp)
     else:
-        got = core._mla_attention(x, lp, cfg, *kl._mla_tables(cfg, None, (2, 160)), seg, None)
+        got = jax.jit(lambda x, lp: core._mla_attention(
+            x, lp, cfg, *kl._mla_tables(cfg, None, (2, 160)), seg, None))(x, lp)
     mixer = ref.kda_mixer if kind == "kda" else ref.mla_mixer
-    want = jnp.stack([mixer(x[r], lp, MODEL, ref.row_geometry(seg[r]), None) for r in range(2)])
+    want = jax.jit(lambda x, lp: jnp.stack(
+        [mixer(x[r], lp, MODEL, ref.row_geometry(seg[r]), None) for r in range(2)]))(x, lp)
     assert float(jnp.abs(got - want).max()) < 2e-5 * float(jnp.abs(want).max())
 
 
@@ -187,8 +201,9 @@ def test_a_whole_layer_agrees_with_the_reference():
     cfg, batch = program_cfg(), packed_batch()
     lp = _layer_params("kda", 1)
     x = jnp.asarray(np.random.default_rng(4).normal(size=(2, 160, MODEL["hidden_size"])), jnp.float32)
-    got, _ = kl._layer(x, lp, kind="kda", cfg=cfg, segment_ids=batch["segment_ids"], cos=None, sin=None)
-    want = ref.one_layer(x, lp, "kda", MODEL, batch["segment_ids"], None)
+    seg = batch["segment_ids"]
+    got, _ = jax.jit(lambda x, lp: kl._layer(x, lp, kind="kda", cfg=cfg, segment_ids=seg, cos=None, sin=None))(x, lp)
+    want = jax.jit(lambda x, lp: ref.one_layer(x, lp, "kda", MODEL, seg, None))(x, lp)
     assert float(jnp.abs(got - want).max()) < 2e-5 * float(jnp.abs(want).max())
 
 
@@ -206,9 +221,10 @@ def gaps_to_the_reference(cfg=None, program=None, params=None):
     batch = packed_batch()
     if cfg is None and program is None:
         got, g_got = _program_gradient(seeded() if params is None else params, batch)
-    else:
-        got, g_got = jax.value_and_grad(program or program_loss)(
-            seeded(), cfg or program_cfg(), batch)
+    else:   # a compile of its own: traced under whatever the case planted
+        cfg = cfg or program_cfg()
+        got, g_got = jax.jit(lambda p, b: jax.value_and_grad(program or program_loss)(p, cfg, b))(
+            seeded(), batch)
     want, g_want = _reference_gradient()
     g_got = ref.flatten(g_got)
     assert set(g_got) == set(g_want)
@@ -217,19 +233,15 @@ def gaps_to_the_reference(cfg=None, program=None, params=None):
     return abs(float(got) - float(want)) / float(want), leaf
 
 
-_REFERENCE = {}
-
-
+@once_on_host
 def _reference_gradient():
-    if not _REFERENCE:
-        batch = packed_batch()
-        with jax.default_matmul_precision("highest"):
-            value, grads = jax.jit(jax.value_and_grad(
-                lambda p: ref.loss(p, MODEL, batch["input_ids"], batch["segment_ids"])))(seeded())
-        # on the host: a device array kept here would stay live for every later
-        # test of this worker (tests/test_cost_observatory.py counts them)
-        _REFERENCE["got"] = jax.device_get((value, ref.flatten(grads)))
-    return _REFERENCE["got"]
+    """The reference's (loss, gradient leaves) from the seeded weights: it does
+    not depend on what a case plants in the program, so it is computed once."""
+    batch = packed_batch()
+    with jax.default_matmul_precision("highest"):
+        value, grads = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss(p, MODEL, batch["input_ids"], batch["segment_ids"])))(seeded())
+    return value, ref.flatten(grads)
 
 
 def test_loss_and_every_gradient_leaf_agree_with_the_reference():
@@ -248,13 +260,18 @@ def test_two_optimizer_steps_agree_with_the_reference():
     want = ref.train_reference(MODEL, opt, 5, [rows, rows])
     tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(3e-4, b1=0.9, b2=0.95, eps=1e-8,
                                                                weight_decay=0.0))
+
+    @jax.jit
+    def update(grads, state, params):
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
     params = p0 = seeded()
     state = tx.init(params)
     losses = []
     for _ in range(2):
         value, grads = _program_gradient(params, batch)
-        updates, state = tx.update(grads, state, params)
-        params = optax.apply_updates(params, updates)
+        params, state = update(grads, state, params)
         losses.append(float(value))
     assert np.allclose(losses, want["losses"], rtol=2e-6)
     change = jax.device_get(ref.leaf_norms(jax.tree.map(jnp.subtract, params, p0)))
@@ -313,15 +330,14 @@ def test_rotary_left_on_fails_the_comparison_and_nope_reads_no_position():
     _, leaf = gaps_to_the_reference(program_cfg(mla_use_nope=False), program=_with_positions)
     assert max(leaf.values()) > 1e-3
     params, batch = seeded(), packed_batch()
-    logits = lambda pos, **kw: FAMILY.forward_logits(
-        params, program_cfg(**kw), batch["input_ids"], pos, batch["segment_ids"])
+    logits = lambda pos, **kw: jax.jit(lambda pos: FAMILY.forward_logits(
+        params, program_cfg(**kw), batch["input_ids"], pos, batch["segment_ids"]))(pos)
     base = logits(batch["position_ids"])
     assert jnp.array_equal(base, logits(batch["position_ids"] + 7, rope_theta=123.0))
 
 
 def _with_positions(params, cfg, batch):
-    seg = np.asarray(batch["segment_ids"])
-    pos = np.stack([np.arange(seg.shape[1])] * 2).astype(np.int32)
+    pos = np.stack([np.arange(batch["segment_ids"].shape[1])] * 2).astype(np.int32)
     return program_loss(params, cfg, dict(batch, position_ids=jnp.asarray(pos)))
 
 
@@ -411,16 +427,21 @@ def test_the_stack_is_a_plain_loop_over_its_layers():
     cfg = program_cfg(num_hidden_layers=9, linear_attn_config=lac)
     kinds = kl.layer_kinds(cfg)
     assert kl.segments_of(kinds) == [(("kda_dense",), 1), (("kda", "kda", "mla", "kda"), 2)]
-    params, batch = FAMILY.init_params(jax.random.PRNGKey(0), cfg), packed_batch()
-    got = kl.forward_layers(params, cfg, batch["input_ids"], None, batch["segment_ids"])
+    batch = packed_batch()
+    params = jax.jit(lambda key: FAMILY.init_params(key, cfg))(jax.random.PRNGKey(0))
+    got = jax.jit(lambda p, ids, seg: kl.forward_layers(p, cfg, ids, None, seg))(
+        params, batch["input_ids"], batch["segment_ids"])
     hidden = params["embed_tokens"][batch["input_ids"]]
     cos, sin = kl._mla_tables(cfg, None, hidden.shape[:2])
+    # one layer after another, each kind's layer one program (not op by op)
+    layer = {kind: jax.jit(lambda h, lp, kind=kind: kl._layer(
+        h, lp, kind=kind, cfg=cfg, segment_ids=batch["segment_ids"], cos=cos, sin=sin))
+        for kind in set(kinds)}
     at, load = {k: 0 for k in kl.KINDS}, 0.0
     for kind in kinds:
         lp = jax.tree.map(lambda t: t[at[kind]], params[kl.KINDS[kind]])
         at[kind] += 1
-        hidden, stats = kl._layer(hidden, lp, kind=kind, cfg=cfg,
-                                  segment_ids=batch["segment_ids"], cos=cos, sin=sin)
+        hidden, stats = layer[kind](hidden, lp)
         load = max(load, float(stats[5]))
     want = core._norm(hidden, params["norm"], cfg)
     assert float(jnp.abs(got["hidden"] - want).max()) < 1e-5
@@ -436,16 +457,17 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     whole = {**MODEL, "num_experts": 16, "first_expert_held": 0, "moe_capacity_factor": 0}
     lp = jax.tree.map(lambda t: t[0], ref.nest(ref.make_params(whole, ref.seed_key(7)))["mla_layers"])
     x = jnp.asarray(np.random.default_rng(5).normal(size=(96, MODEL["hidden_size"])), jnp.float32)
-    want = ref.expert_layer(x, lp, whole)
+    expert_layer = lambda x, lp, model: jax.jit(lambda x, lp: ref.expert_layer(x, lp, model))(x, lp)   # one program
+    want = expert_layer(x, lp, whole)
     se = lp["shared_experts"]
     shared = ref._swiglu(x, se["gate_proj"], se["up_proj"], se["down_proj"], None)
     total = shared
     for first in range(0, 16, 4):
         cfg = program_cfg(moe_experts_held=4, moe_experts_held_first=first, moe_capacity_factor=0.0)
         part = dict(lp, experts=jax.tree.map(lambda t: t[first:first + 4], lp["experts"]))
-        got, _, stats = core.moe_mlp_with_stats(x, part, cfg)
+        got, _, stats = jax.jit(lambda x, part: core.moe_mlp_with_stats(x, part, cfg))(x, part)
         mine = {**whole, "num_experts": 4, "num_experts_published": 16, "first_expert_held": first}
-        assert float(jnp.abs(got - ref.expert_layer(x, part, mine)).max()) < 1e-5
+        assert float(jnp.abs(got - expert_layer(x, part, mine)).max()) < 1e-5
         total = total + (got - shared)
     assert float(jnp.abs(total - want).max()) < 2e-5
 

@@ -46,7 +46,7 @@ def test_lora_init_zero_delta_and_gradients():
     def loss(lora_tree):
         return model.loss_fn(merge_lora_params(base, lora_tree), batch)[0]
 
-    g = jax.grad(loss)(lora)
+    g = jax.jit(jax.grad(loss))(lora)
     ga = g["layers"]["q_proj"]["lora_a"]
     gb = g["layers"]["q_proj"]["lora_b"]
     # dB nonzero (dA is 0 at init because B=0 — standard LoRA property)

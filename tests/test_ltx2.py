@@ -9,9 +9,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.models.ltx2 import (
-    LTX2Config, hf_to_params, init_params, loss_fn, ltx2_forward, params_to_hf,
-)
+from veomni_tpu.models import ltx2
+from veomni_tpu.models.ltx2 import LTX2Config, hf_to_params, params_to_hf
+from veomni_tpu.utils.testing import under_jit
+
+# whole models as one program a shape, not op by op
+init_params, loss_fn, ltx2_forward = (
+    under_jit(f) for f in (ltx2.init_params, ltx2.loss_fn, ltx2.ltx2_forward))
 
 TINY = dict(
     num_attention_heads=2,
@@ -112,7 +116,7 @@ def test_loss_and_grads(model):
     }
     total, metrics = loss_fn(params, cfg, batch)
     assert np.isfinite(float(total))
-    grads = jax.grad(lambda p: loss_fn(p, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: ltx2.loss_fn(p, cfg, batch)[0]))(params)
     # both streams and the A/V cross projections receive signal
     for key in ("patchify_proj", "audio_patchify_proj"):
         assert float(jnp.abs(grads[key]).sum()) > 0.0

@@ -475,7 +475,7 @@ def test_debug_numerics_endpoint():
     try:
         def get():
             with urllib.request.urlopen(
-                    f"http://127.0.0.1:{port}/debug/numerics") as r:
+                    f"http://127.0.0.1:{port}/debug/numerics", timeout=10) as r:
                 return json.loads(r.read())
 
         set_active_monitor(None)

@@ -7,6 +7,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from veomni_tpu.arguments import VeOmniArguments
+from veomni_tpu.models import omni
+from veomni_tpu.models.omni import init_omni_params
+from veomni_tpu.utils.testing import under_jit
+
+# the whole model as one program a shape, not op by op (init_omni_params stays
+# eager: the MoVQ tower's draws compile slower as one program than they run)
+omni_loss_fn = under_jit(omni.omni_loss_fn)
 
 TEXT = dict(model_type="qwen2", vocab_size=600, hidden_size=64,
             intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
@@ -68,7 +75,7 @@ def _gen_batch(cfg, with_gen: bool):
 
 
 def test_image_gen_loss_trains_and_text_invariant():
-    from veomni_tpu.models.omni import OmniConfig, init_omni_params, omni_loss_fn
+    from veomni_tpu.models.omni import OmniConfig
 
     cfg = _gen_cfg()
     params = init_omni_params(jax.random.PRNGKey(0), cfg)
@@ -77,7 +84,7 @@ def test_image_gen_loss_trains_and_text_invariant():
     @jax.jit
     def step(p):
         (total, metrics), grads = jax.value_and_grad(
-            lambda q: omni_loss_fn(q, cfg, batch), has_aux=True
+            lambda q: omni.omni_loss_fn(q, cfg, batch), has_aux=True
         )(p)
         # train only aligner + gen head (freeze_tokenizer semantics keep the
         # movq grads zero; LM drift would also move gen loss, so isolate)
@@ -100,7 +107,7 @@ def test_image_gen_loss_trains_and_text_invariant():
     assert gl1 < gl0 - 0.05, (gl0, gl1)
 
     # movq tokenizer stays frozen: its grads are exactly zero
-    grads = jax.grad(lambda q: omni_loss_fn(q, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda q: omni.omni_loss_fn(q, cfg, batch)[0]))(params)
     assert all(
         float(jnp.abs(g).max()) == 0.0
         for g in jax.tree.leaves(grads["image_gen"]["movq"])
@@ -123,7 +130,7 @@ def test_image_gen_janus_vq_decoder():
     """The seed_omni decoder registry: the same composite machinery drives
     the llamagen/janus VQ decoder (reference decoder/janusvq16) via
     ImageGenConfig.decoder_type."""
-    from veomni_tpu.models.omni import OmniConfig, init_omni_params, omni_loss_fn
+    from veomni_tpu.models.omni import OmniConfig
 
     cfg = OmniConfig(
         text=dict(TEXT),
@@ -145,7 +152,7 @@ def test_image_gen_janus_vq_decoder():
     assert np.isfinite(float(total))
     assert int(metrics["gen_ntokens"]) == 16
     # frozen VQ; aligner/head trainable
-    grads = jax.grad(lambda p: omni_loss_fn(p, cfg, batch)[0])(params)
+    grads = jax.jit(jax.grad(lambda p: omni.omni_loss_fn(p, cfg, batch)[0]))(params)
     assert all(float(jnp.abs(g).max()) == 0.0
                for g in jax.tree.leaves(grads["image_gen"]["movq"]))
     assert float(jnp.abs(grads["image_gen"]["gen_head"]["fc2"]).sum()) > 0.0
@@ -154,7 +161,7 @@ def test_image_gen_janus_vq_decoder():
 def test_generate_image():
     """lm_generate contract: autoregressive code sampling + VQ decode
     produce a correctly-shaped image; greedy determinism at temperature~0."""
-    from veomni_tpu.models.omni import generate_image, init_omni_params
+    from veomni_tpu.models.omni import generate_image
 
     cfg = _gen_cfg()
     params = init_omni_params(jax.random.PRNGKey(0), cfg)

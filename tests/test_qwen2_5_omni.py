@@ -82,10 +82,8 @@ def test_audio_encoder_parity(hf_and_ours):
 
     from veomni_tpu.models.qwen2_5_omni import audio_encoder_forward
 
-    got = audio_encoder_forward(
-        params["audio_tower"], acfg,
-        jnp.asarray(mel.transpose(0, 2, 1)), dtype=jnp.float32,
-    )
+    got = jax.jit(lambda tower, mel: audio_encoder_forward(tower, acfg, mel, dtype=jnp.float32))(
+        params["audio_tower"], jnp.asarray(mel.transpose(0, 2, 1)))
     np.testing.assert_allclose(np.asarray(got)[0], ref, rtol=2e-4, atol=2e-4)
 
 
@@ -147,7 +145,7 @@ def test_thinker_loss_parity(hf_and_ours):
         "audio_features": jnp.asarray(mel.transpose(0, 2, 1)),
         "audio_mask": jnp.ones((1,), bool),
     }
-    loss_sum, metrics = model.loss_fn(params, batch)
+    loss_sum, metrics = jax.jit(model.loss_fn)(params, batch)
     got_loss = float(loss_sum) / float(metrics["ntokens"])
     np.testing.assert_allclose(got_loss, ref_loss, rtol=2e-4)
 

@@ -96,12 +96,11 @@ def test_vision_tower_parity(hf_and_ours):
     meta = vision_metadata(grids, cfg.vision, n_pad_patches=pixel_values.shape[0] + 8)
     px = np.zeros((pixel_values.shape[0] + 8, pixel_values.shape[1]), np.float32)
     px[: pixel_values.shape[0]] = pixel_values
-    got = vision_forward(
-        params["vision_tower"], cfg.vision,
+    got = jax.jit(lambda tower, *a: vision_forward(tower, cfg.vision, *a, dtype=jnp.float32))(
+        params["vision_tower"],
         jnp.asarray(px)[jnp.asarray(meta["patch_gather"])],
         jnp.asarray(meta["pos_hw"]), jnp.asarray(meta["seg_window"]),
         jnp.asarray(meta["seg_full"]), jnp.asarray(meta["reverse"]),
-        dtype=jnp.float32,
     )
     got = np.asarray(got)[np.asarray(meta["merged_mask"])]
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
@@ -175,7 +174,7 @@ def test_full_loss_parity(hf_and_ours):
         "vis_reverse": jnp.asarray(meta["reverse"]),
         "vis_merged_mask": jnp.asarray(meta["merged_mask"]),
     }
-    loss_sum, metrics = model.loss_fn(params, batch)
+    loss_sum, metrics = jax.jit(model.loss_fn)(params, batch)
     got_loss = float(loss_sum) / float(metrics["ntokens"])
     np.testing.assert_allclose(got_loss, ref_loss, rtol=2e-4)
 
@@ -290,7 +289,7 @@ def test_qwen25_vl_sp_equivalence(hf_and_ours):
         "vis_merged_mask": jnp.asarray(meta["merged_mask"]),
     }
     destroy_parallel_state()
-    ref_loss, ref_metrics = model.loss_fn(params, batch)
+    ref_loss, ref_metrics = jax.jit(model.loss_fn)(params, batch)
     ref = float(ref_loss) / float(ref_metrics["ntokens"])
     try:
         ps = init_parallel_state(ulysses_size=2, dp_shard_size=2)

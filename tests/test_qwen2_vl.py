@@ -93,10 +93,9 @@ def test_vision_tower_parity(hf_and_ours):
     meta = vision_metadata(grids, cfg.vision, n_pad_patches=pixel_values.shape[0] + 8)
     px = np.zeros((pixel_values.shape[0] + 8, pixel_values.shape[1]), np.float32)
     px[: pixel_values.shape[0]] = pixel_values
-    got = vision_forward(
-        params["vision_tower"], cfg.vision,
+    got = jax.jit(lambda tower, *a: vision_forward(tower, cfg.vision, *a, dtype=jnp.float32))(
+        params["vision_tower"],
         jnp.asarray(px), jnp.asarray(meta["pos_hw"]), jnp.asarray(meta["seg"]),
-        dtype=jnp.float32,
     )
     got = np.asarray(got)[np.asarray(meta["merged_mask"])]
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
@@ -167,7 +166,7 @@ def test_full_loss_parity(hf_and_ours):
         "vis_seg": jnp.asarray(meta["seg"]),
         "vis_merged_mask": jnp.asarray(meta["merged_mask"]),
     }
-    loss_sum, metrics = model.loss_fn(params, batch)
+    loss_sum, metrics = jax.jit(model.loss_fn)(params, batch)
     got_loss = float(loss_sum) / float(metrics["ntokens"])
     np.testing.assert_allclose(got_loss, ref_loss, rtol=2e-4)
 

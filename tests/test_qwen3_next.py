@@ -147,6 +147,7 @@ def test_gated_delta_rule_segment_reset():
     beta = jax.nn.sigmoid(mk(b, s, h))
     seg = jnp.asarray([[1] * la + [2] * lb] * b, jnp.int32)
 
+    chunk_gated_delta_rule = jax.jit(chunk_gated_delta_rule)   # one program a shape
     packed = chunk_gated_delta_rule(q, k, v, g, beta, segment_ids=seg)
     out_a = chunk_gated_delta_rule(
         q[:, :la], k[:, :la], v[:, :la], g[:, :la], beta[:, :la])
@@ -167,8 +168,11 @@ def test_forward_packed_vs_separate_documents():
     standalone forward (conv taps, delta-rule state, and full attention all
     boundary-isolated)."""
     from veomni_tpu.models.qwen3_next import abstract_params  # noqa: F401
-    from veomni_tpu.models.qwen3_next import forward_hidden, init_params
+    from veomni_tpu.models import qwen3_next
+    from veomni_tpu.utils.testing import under_jit
 
+    # the whole stack as one program a shape, not op by op
+    init_params, forward_hidden = under_jit(qwen3_next.init_params), under_jit(qwen3_next.forward_hidden)
     cfg = _cfg(moe=False)
     params = init_params(jax.random.PRNGKey(1), cfg)
     rng = np.random.default_rng(3)

@@ -125,10 +125,10 @@ def test_audio_tower_parity(hf_and_ours):
     n_chunk_pad, n_frame_pad = 4, 64
     meta = audio_metadata(AUDIO_LENS, cfg.audio, n_chunk_pad, n_frame_pad)
     chunks = pack_audio_chunks(mels, cfg.audio, n_chunk_pad)
-    got = audio_forward(
-        params["audio_tower"], cfg.audio, jnp.asarray(chunks),
+    got = jax.jit(lambda tower, *a: audio_forward(tower, cfg.audio, *a, dtype=jnp.float32))(
+        params["audio_tower"], jnp.asarray(chunks),
         jnp.asarray(meta["frame_gather"]),
-        jnp.asarray(meta["seg"]), dtype=jnp.float32,
+        jnp.asarray(meta["seg"]),
     )
     got = np.asarray(got)[meta["frame_mask"]]
     np.testing.assert_allclose(got, ref, rtol=3e-4, atol=3e-4)
@@ -217,7 +217,7 @@ def test_full_loss_parity(hf_and_ours):
         "aud_seg": jnp.asarray(ameta["seg"]),
         "aud_frame_mask": jnp.asarray(ameta["frame_mask"]),
     }
-    loss_sum, metrics = model.loss_fn(params, batch)
+    loss_sum, metrics = jax.jit(model.loss_fn)(params, batch)
     got_loss = float(loss_sum) / float(metrics["ntokens"])
     np.testing.assert_allclose(got_loss, ref_loss, rtol=3e-4)
 

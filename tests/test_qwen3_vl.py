@@ -111,11 +111,10 @@ def test_vision_tower_parity(hf_and_ours):
     from veomni_tpu.models.qwen3_vl import vision_forward
 
     meta, px = _metadata_and_px(cfg, pixel_values)
-    got, got_deep = vision_forward(
-        params["vision_tower"], cfg.vision, jnp.asarray(px),
+    got, got_deep = jax.jit(lambda tower, *a: vision_forward(tower, cfg.vision, *a, dtype=jnp.float32))(
+        params["vision_tower"], jnp.asarray(px),
         jnp.asarray(meta["pos_hw"]), jnp.asarray(meta["pos_interp_idx"]),
         jnp.asarray(meta["pos_interp_w"]), jnp.asarray(meta["seg_full"]),
-        dtype=jnp.float32,
     )
     mask = np.asarray(meta["merged_mask"])
     np.testing.assert_allclose(
@@ -202,7 +201,7 @@ def test_full_loss_parity(hf_and_ours):
         "vis_seg_full": jnp.asarray(meta["seg_full"]),
         "vis_merged_mask": jnp.asarray(meta["merged_mask"]),
     }
-    loss_sum, metrics = model.loss_fn(params, batch)
+    loss_sum, metrics = jax.jit(model.loss_fn)(params, batch)
     got_loss = float(loss_sum) / float(metrics["ntokens"])
     np.testing.assert_allclose(got_loss, ref_loss, rtol=2e-4)
 
@@ -324,7 +323,7 @@ def test_qwen3_vl_moe_loss_parity(tmp_path):
         "vis_seg_full": jnp.asarray(meta["seg_full"]),
         "vis_merged_mask": jnp.asarray(meta["merged_mask"]),
     }
-    loss_sum, metrics = model.loss_fn(params, batch)
+    loss_sum, metrics = jax.jit(model.loss_fn)(params, batch)
     got_loss = float(loss_sum) / float(metrics["ntokens"])
     np.testing.assert_allclose(got_loss, ref_loss, rtol=3e-4)
 

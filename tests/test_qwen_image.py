@@ -10,10 +10,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.models.qwen_image import (
-    QwenImageConfig, hf_to_params, init_params, loss_fn, params_to_hf,
-    qwen_image_forward, rope_plan,
-)
+from veomni_tpu.models import qwen_image
+from veomni_tpu.models.qwen_image import QwenImageConfig, hf_to_params, params_to_hf, rope_plan
+from veomni_tpu.utils.testing import under_jit
+
+# whole models as one program a shape, not op by op
+init_params, qwen_image_forward = (
+    under_jit(f) for f in (qwen_image.init_params, qwen_image.qwen_image_forward))
 
 TINY = dict(
     patch_size=2,
@@ -90,7 +93,7 @@ def test_loss_and_grads_finite(model):
         "target": jnp.asarray(rng.standard_normal((2, 16, 16)), jnp.float32),
     }
 
-    loss, grads = jax.value_and_grad(lambda p: loss_fn(p, cfg, batch)[0])(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: qwen_image.loss_fn(p, cfg, batch)[0]))(params)
     assert np.isfinite(float(loss))
     flat = jax.tree_util.tree_leaves(grads)
     assert all(np.isfinite(np.asarray(g)).all() for g in flat)

@@ -1361,7 +1361,7 @@ def test_subprocess_data_skip_budget_across_resume_and_exhaustion(tmp_path):
     # (not inherited from the pytest process) so batch assembly — and with
     # it which records each step consumes — is identical across legs
     # however the suite is invoked.
-    xla4 = {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    xla4 = {"XLA_FLAGS": f"{os.environ.get('XLA_FLAGS', '')} --xla_force_host_platform_device_count=4"}
     common = dict(dataset_type="streaming", data_skip_budget=1,
                   lr_decay_style="constant")
 

@@ -72,7 +72,7 @@ def test_vlm_loss_and_grads():
     }
     loss, metrics = jax.jit(model.loss_fn)(params, batch)
     assert np.isfinite(float(loss))
-    g = jax.grad(lambda p: model.loss_fn(p, batch)[0])(params)
+    g = jax.jit(jax.grad(lambda p: model.loss_fn(p, batch)[0]))(params)
     assert float(jnp.abs(g["vision_tower"]["patch_embed"]).sum()) > 0
 
 

@@ -84,7 +84,7 @@ def _losses(model_type, collator_cls, loss_fn):
 
             model = build_foundation_model(config=cfg)
             model_params = model.init(jax.random.PRNGKey(0))
-        loss, metrics = loss_fn(model_params, cfg, batch)
+        loss, metrics = jax.jit(lambda p, b: loss_fn(p, cfg, b))(model_params, batch)
         out.append((float(loss), float(metrics["ntokens"])))
     return out
 

@@ -14,10 +14,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from veomni_tpu.models.wan import (
-    WanConfig, hf_to_params, init_params, loss_fn, params_to_hf, rope_3d,
-    wan_forward,
-)
+from veomni_tpu.models import wan
+from veomni_tpu.models.wan import WanConfig, hf_to_params, params_to_hf, rope_3d
+from veomni_tpu.utils.testing import under_jit
+
+# whole models as one program a shape, not op by op
+init_params, wan_forward = under_jit(wan.init_params), under_jit(wan.wan_forward)
 
 TINY = dict(
     patch_size=(1, 2, 2),
@@ -87,11 +89,7 @@ def test_loss_and_grads_finite(model):
         "target": jnp.asarray(rng.standard_normal((2, 4, 2, 8, 8)), jnp.float32),
     }
 
-    def scalar(p):
-        l, _ = loss_fn(p, cfg, batch)
-        return l
-
-    loss, grads = jax.value_and_grad(scalar)(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: wan.loss_fn(p, cfg, batch)[0]))(params)
     assert np.isfinite(float(loss))
     flat = jax.tree_util.tree_leaves(grads)
     assert all(np.isfinite(np.asarray(g)).all() for g in flat)
