@@ -8,10 +8,18 @@ from typing import Dict, Tuple
 import numpy as np
 
 
-def check(name: str, value: float, limit: float) -> dict:
-    """One compared number: passes where ``value <= limit``."""
+def check(name: str, value: float, limit: float, **where) -> dict:
+    """One compared number under a short plain name: passes where
+    ``value <= limit``. ``where`` says where it was read (the worst ``leaf``)
+    and goes into the result line beside it."""
     ok = bool(np.isfinite(value) and value <= limit)
-    return {"name": name, "value": float(value), "limit": float(limit), "ok": ok}
+    return {"name": name, "value": float(value), "limit": float(limit), "ok": ok, **where}
+
+
+def said(c: dict) -> str:
+    """A check as every run prints it: the number beside its limit."""
+    where = "".join(f" {k} {v}" for k, v in c.items() if k not in ("name", "value", "limit", "ok"))
+    return f"{c['name']}: {c['value']!r} (limit {c['limit']!r}){where}"
 
 
 def worst_leaf_gap(got: Dict[str, np.ndarray], want: Dict[str, np.ndarray]) -> Tuple[float, str]:

@@ -4,19 +4,22 @@ functions for operations and bytes in the module ``args["module"]`` beside
 
 ``ops_bytes`` gives the least work of ONE call from: ``per_step`` (entries of
 the job's shapes that are summed over the traced steps, divided by them),
-``from_shapes``, ``from_model``, ``fixed``, and ``program_ratio`` (a ratio of
-two of the program's counters, the upper one less a third where three are named). The share is that least time x the calls the
-trace holds (``call_pattern``: the one event there is per call; a call cut by
-the window's edge counts by its part inside) over the device time of the
-events matching ``pattern``. A call run again (a rematerialized forward) is
-work done. None without a trace, without the kernel in it, or without the
-program's counters."""
+``from_shapes``, ``from_model``, ``fixed``, and ``shape_ratio`` (one entry of
+the job's shapes over another, both summed over the very steps the trace holds:
+the rows the held experts multiplied over all assignments). The share is that
+least time x the calls the trace holds (``call_pattern``: the one event there
+is per call; a call cut by the window's edge counts by its part inside) over
+the device time of the events matching ``pattern``. A call run again (a
+rematerialized forward) is work done. None without a trace cut on whole steps,
+without the kernel in it, without the shapes (a model that routes nothing), or
+where the ratio's upper entry is nought (the traced steps multiplied no row:
+the kernel's launches then did no work to hold against their time)."""
 
 import importlib
 
 from benchmark import flops
 from benchmark import trace as tr
-from benchmark.reducers import kernel_roofline, program_value
+from benchmark.reducers import kernel_roofline
 
 
 def reduce(obs, args):
@@ -31,10 +34,10 @@ def reduce(obs, args):
     kwargs.update({k: obs["shapes"][v] for k, v in args.get("from_shapes", {}).items()})
     kwargs.update({k: obs["model"][v] for k, v in args.get("from_model", {}).items()})
     kwargs.update(args.get("fixed", {}))
-    for k, counters in args.get("program_ratio", {}).items():
-        kwargs[k] = program_value.ratio(*counters)  # over, under[, less]
-        if kwargs[k] is None:
-            return None
+    for k, (over, under) in args.get("shape_ratio", {}).items():
+        if not obs["shapes"].get(under) or not obs["shapes"].get(over):
+            return None  # nothing routed, or no row multiplied: an empty launch has no roofline
+        kwargs[k] = obs["shapes"][over] / obs["shapes"][under]
     one = getattr(importlib.import_module(f"benchmark.{args['module']}"), args["ops_bytes"])(**kwargs)
     least = flops.roofline_seconds(one, obs["peaks"])
     obs["log"](f"kernel roofline {args['pattern']!r}: {calls:.2f} calls in the trace "

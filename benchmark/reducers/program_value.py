@@ -13,12 +13,9 @@ def read(name: str):
         return None
 
 
-def ratio(over: str, under: str, less: str = None):
-    """``over`` (less ``less``, where given) over ``under``."""
+def ratio(over: str, under: str):
+    """``over`` over ``under``, both the program's; None without either."""
     a, b = read(over), read(under)
-    if less is not None and a is not None:
-        c = read(less)
-        a = None if c is None else a - c
     return None if a is None or not b else a / b
 
 

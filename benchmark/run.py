@@ -23,6 +23,7 @@ T_START = time.perf_counter()
 import argparse
 import importlib
 import json
+import math
 import os
 import shutil
 import sys
@@ -151,6 +152,10 @@ def per_layer_values(manifest, ctx, result, device_doc) -> dict:
                 "trace": None})
     if os.path.isdir(ctx.trace_dir):
         obs["trace"] = tr.load_xplane(tr.newest_xplane(ctx.trace_dir))
+        if "on_trace" in obs:
+            # the job cuts the window on its own program's executions and
+            # fills in what goes with the steps it finds (trace.py)
+            obs["on_trace"](obs["trace"])
     out = {}
     for m in cell_metrics(manifest, "per_layer", ctx.cell["name"]):
         with open(os.path.join(HERE, "layer_metrics", f"{m['name']}.json")) as f:
@@ -234,9 +239,6 @@ def main(argv=None) -> int:
             + (", REHEARSAL: tiny preset, no metric is reported" if args.rehearsal else ""))
     result = job.run(ctx)
 
-    for c in result["checks"]:
-        ctx.log(f"check {'ok  ' if c['ok'] else 'FAIL'} {c['name']}: "
-                f"{c['value']!r} (limit {c['limit']!r})")
     device_doc = dict(device, memory_peak_bytes=result["memory_peak_bytes"])
     values = dict(result["values"], setup_s=ctx.window_t0 - T_START)
     if args.trace:
@@ -252,9 +254,29 @@ def main(argv=None) -> int:
         line["rehearsal_metric_names"] = sorted(metrics)
     if "breakdown" in result:
         line["breakdown"] = result["breakdown"]
+    # what was compared, last in the line: every check's number beside its
+    # limit, so that a record of a run that was not correct says which
+    line["checks"] = [_jsonable_check(c) for c in result["checks"]]
     ctx.log(f"wall {time.perf_counter() - T_START:.1f} s")
     print(json.dumps(line), flush=True)
+    # and, as the driver's record of a run keeps the end of each stream, as the
+    # last lines of standard error
+    from benchmark import compare
+
+    for c in result["checks"]:
+        print(f"{head} check {'ok  ' if c['ok'] else 'FAIL'} {compare.said(c)}",
+              file=sys.stderr, flush=True)
     return 0
+
+
+def _jsonable_check(c: dict) -> dict:
+    """A check for the result line: numbers as numbers; one that is not
+    finite (JSON has no word for it) as null with what it was said beside."""
+    out = dict(c)
+    for key in ("value", "limit"):
+        if not math.isfinite(out[key]):
+            out[key], out[f"{key}_said"] = None, repr(c[key])
+    return out
 
 
 if __name__ == "__main__":

@@ -12,7 +12,10 @@ an event gets its scope and its phase from that.
 
 The taxonomy is the benchmark's own copy of the program's
 ``veomni_tpu/observability/scopes.py`` (a test holds the two equal), so that a
-later PR cannot move what a scope metric sums by editing the program's list.
+later PR cannot move what a scope metric sums by editing the program's list:
+the program's ``TRAIN_SCOPES``, and the mixers' scopes it lists under
+``MODULE_SCOPES`` (``ssm*``, ``kda*``), which hold no other scope of the
+taxonomy inside them and so are parts of its sum like any other.
 Against a program that has no scope map (the parent of the PR that brought
 this file) :func:`program_scope_map` returns None and every reader built on it
 leaves its metric out.
@@ -25,9 +28,15 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 from benchmark import trace as tr
 
+# a state-space or a Kimi Delta Attention mixer, input norm to residual add:
+# its leaf scopes, and the module's own name for what lies under it and under
+# none of its leaves (the mixer's norm and residual). An event's scope is the
+# innermost name of its path, so ``.../ssm/ssm.scan/...`` is ``ssm.scan``
+MIXER_SCOPES = ("ssm", "ssm.proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
+                "kda", "kda.proj", "kda.conv", "kda.gate", "kda.scan")
 TRAIN_SCOPES = ("embed", "attn.qkv", "attn.flash", "attn.out", "mlp", "moe.route",
                 "moe.dispatch", "moe.experts", "moe.combine", "lm_head_loss", "grad_clip",
-                "optimizer")
+                "optimizer") + MIXER_SCOPES
 SERVE_SCOPES = ("paged.gather", "paged.attend", "sampler")
 SCOPES = TRAIN_SCOPES + SERVE_SCOPES
 # a Pallas kernel's name is its instruction's name, so its scope needs no
@@ -35,13 +44,17 @@ SCOPES = TRAIN_SCOPES + SERVE_SCOPES
 # op_name, a forward kernel may turn out to be the recomputed copy)
 KERNELS = {"flash_fwd": ("attn.flash", "forward"), "flash_bwd_dkv": ("attn.flash", "backward"),
            "flash_bwd_dq": ("attn.flash", "backward"), "gmm_fwd": ("moe.experts", "forward"),
-           "gmm_dlhs": ("moe.experts", "backward"), "gmm_drhs": ("moe.experts", "backward")}
+           "gmm_dlhs": ("moe.experts", "backward"), "gmm_drhs": ("moe.experts", "backward"),
+           "qk_norm_rope_fwd": ("attn.qkv", "forward"), "qk_norm_rope_bwd": ("attn.qkv", "backward"),
+           "mla_qkv_rope_fwd": ("attn.qkv", "forward"), "mla_qkv_rope_bwd": ("attn.qkv", "backward")}
 PHASES = ("forward", "recompute", "backward", "optimizer")
 UNATTRIBUTED = "(unattributed)"
 
 # a scope is a whole component of the path, or stands alone inside a
-# transformation's brackets: jvp(mlp), transpose(jvp(attn.out))
-_SCOPE = re.compile(r"(?:^|[/(])(" + "|".join(re.escape(s) for s in SCOPES) + r")(?=$|[/)])")
+# transformation's brackets: jvp(mlp), transpose(jvp(attn.out)); the longest
+# name first, so that ``ssm.scan`` is never read as ``ssm``
+_SCOPE = re.compile(r"(?:^|[/(])(" + "|".join(
+    re.escape(s) for s in sorted(SCOPES, key=len, reverse=True)) + r")(?=$|[/)])")
 _NUMBERED = re.compile(r"^(.*?)\.\d+$")
 
 
@@ -164,7 +177,7 @@ def log_table(obs: dict, tab: dict) -> None:
     """The component sum beside ``busy_s``: all scopes plus the unattributed
     have to come to the traced window's busy time."""
     busy, _ = tr.busy_and_window_s(obs["trace"])
-    steps = max(obs["shapes"].get("traced_steps", 1), 1)
+    steps = obs["shapes"].get("traced_steps") or 1
     total = sum(tab["by_scope"].values())
     rows = sorted(tab["by_scope"].items(), key=lambda kv: -kv[1])
     obs["log"]("device time by scope, ms a step over %d traced steps: %s" % (

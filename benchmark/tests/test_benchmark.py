@@ -131,6 +131,14 @@ def test_cell_rehearses_on_the_cpu(cell, trace):
     assert set(line["rehearsal_metric_names"]) <= want
     if not trace:
         assert set(line["rehearsal_metric_names"]) == want
+    # what was compared comes last in the line: every number beside its limit
+    assert list(line)[-1] == "checks" and len(line["checks"]) >= 7
+    for c in line["checks"]:
+        assert NAME.match(c["name"]) and c["ok"] is True
+        assert isinstance(c["value"], float) and isinstance(c["limit"], float)
+        assert c["value"] <= c["limit"]
+    leaves = {c["name"]: c.get("leaf") for c in line["checks"]}
+    assert leaves["first_grad_norm_worst_leaf"] and leaves["loss_1_rel_gap"] is None
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -150,6 +158,10 @@ def test_control_in_lower_precision_is_not_correct(cell):
     line = _last_line(_run(["--workload", cell, "--seed", "77", "--seconds", "1",
                             "--trace", "0", "--rehearsal", "--control", control]))
     assert line["correct"] is False
+    failed = [c for c in line["checks"] if not c["ok"]]
+    assert failed and all(c["value"] > c["limit"] for c in failed)
+    assert {c["name"] for c in failed} & {"first_grad_norm_worst_leaf",
+                                          "param_change_norm_2_steps_worst_leaf"}
 
 
 # ------------------------------------------------------------- broken paths
@@ -157,9 +169,10 @@ def _drive_in_process(cell, monkeypatch, capsys, seed=5):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     rc = bench_run.main(["--workload", cell, "--seed", str(seed), "--seconds", "1",
                          "--trace", "0", "--rehearsal"])
-    out = capsys.readouterr().out
+    said = capsys.readouterr()
     assert rc == 0
-    return json.loads(out.strip().splitlines()[-1]), out
+    # the result line is standard output's last; the checks are standard error's
+    return json.loads(said.out.strip().splitlines()[-1]), said.out + said.err
 
 
 def test_train_step_that_returns_its_state_unchanged_is_not_correct(monkeypatch, capsys):
@@ -181,6 +194,11 @@ def test_train_step_that_returns_its_state_unchanged_is_not_correct(monkeypatch,
     line, out = _drive_in_process("qwen3_0p6b.train_packed_4k", monkeypatch, capsys)
     assert line["correct"] is False
     assert re.search(r"check FAIL param_change_norm", out)
+    # the line names the failing check, with the gap of a state left unchanged
+    # (1 by this measure) beside its limit and the leaf it was read on
+    failed = {c["name"]: c for c in line["checks"] if not c["ok"]}
+    gap = failed["param_change_norm_2_steps_worst_leaf"]
+    assert gap["value"] == pytest.approx(1.0, abs=1e-3) and gap["limit"] < 0.01 and gap["leaf"]
 
 
 def test_train_step_that_leaves_out_rows_is_not_correct(monkeypatch, capsys):
@@ -199,6 +217,8 @@ def test_train_step_that_leaves_out_rows_is_not_correct(monkeypatch, capsys):
     line, out = _drive_in_process("qwen3_0p6b.train_packed_4k", monkeypatch, capsys)
     assert line["correct"] is False
     assert re.search(r"check FAIL (loss|first_grad_norm)", out)
+    assert {c["name"] for c in line["checks"] if not c["ok"]} & {
+        "loss_1_rel_gap", "loss_2_rel_gap", "first_grad_norm_worst_leaf"}
 
 
 # ------------------------------------------------------------------- trace
@@ -284,6 +304,18 @@ def test_traffic_same_seed_same_inputs_and_large_seeds():
     assert all((x == y).all() for x, y in zip(a, b))
     assert [len(x) for x in a] == [len(x) for x in c], "every seed: the same sizes, same order"
     assert any((x != y).any() for x, y in zip(a, c)), "another seed: other ids"
+
+
+def test_a_check_that_is_not_finite_still_makes_a_line_json_can_hold():
+    c = compare.check("first_grad_norm_worst_leaf", float("inf"), 7e-3, leaf="layers.q_proj[3]")
+    assert c["ok"] is False and "leaf layers.q_proj[3]" in compare.said(c)
+    doc = json.loads(json.dumps(bench_run._jsonable_check(c), allow_nan=False))
+    assert doc == {"name": "first_grad_norm_worst_leaf", "value": None, "value_said": "inf",
+                   "limit": 7e-3, "ok": False, "leaf": "layers.q_proj[3]"}
+    nan = bench_run._jsonable_check(compare.check("loss_1_rel_gap", float("nan"), 6e-5))
+    assert nan["ok"] is False and nan["value"] is None and nan["value_said"] == "nan"
+    fine = compare.check("loss_1_rel_gap", 1e-6, 6e-5)
+    assert bench_run._jsonable_check(fine) == fine and fine["ok"] is True
 
 
 def test_worst_leaf_gap_uses_the_median_floor():

@@ -1,11 +1,10 @@
 """Tests of what the JoyAI-LLM-Flash configuration added to the benchmark as
 files: ``flops_mla_moe.py`` against the program's counter, the new reducers on
 synthetic observations, the cell's limits against planted faults (a dropped
-assignment, the MTP loss left out), and that nothing the benchmark had was
-changed but the six ``workloads`` lists the issue names.
+assignment, the MTP loss left out). That what the benchmark had stands is
+``test_unchanged.py``'s, one case a cell.
 """
 
-import hashlib
 import json
 import os
 import re
@@ -136,11 +135,36 @@ def test_kernel_roofline_mod_counts_one_call_against_its_module():
                                             v_head_dim=16)
     least = flops.roofline_seconds(one, obs["peaks"])["seconds"]
     assert kernel_roofline_mod.reduce(obs, args) == pytest.approx(100 * least * 2 / 4000e-9)
-    # no such kernel in the trace, no trace, or a program without the counters: left out
+    # no such kernel in the trace, no trace, or a model that routes nothing: left out
     assert kernel_roofline_mod.reduce(obs, dict(args, pattern="^%?gmm_fwd\\.\\d+ = ")) is None
     assert kernel_roofline_mod.reduce(dict(obs, trace=None), args) is None
-    ratio = dict(args, program_ratio={"held_share": ["no.such.counter", "nor.this"]})
+    ratio = dict(args, shape_ratio={"held_share": ["moe_rows_multiplied", "moe_assignments"]})
     assert kernel_roofline_mod.reduce(obs, ratio) is None
+
+
+def test_gmm_roofline_reads_the_rows_of_the_traced_steps():
+    """The rows are the traced steps' own (held less dropped over all
+    assignments), not the run's: routing moves over a run, and the program's
+    counters cover all of it."""
+    trace = _trace([["%gmm_fwd.1 = bf16[] custom-call()", 100, 1000],
+                    ["%gmm_fwd.2 = bf16[] custom-call()", 2000, 3000]])
+    with open(os.path.join(BENCH, "layer_metrics", "gmm_fwd_roofline.json")) as f:
+        args = json.load(f)["args"]
+    assert "program_ratio" not in args
+    obs = _obs(trace, attention_tokens=2 * 16384.0, moe_assignments=2 * 5 * 16384 * 8.0,
+               moe_rows_multiplied=2 * 5 * 4096.0)
+    obs["model"] = {"num_experts_per_tok": 8, "hidden_size": 2048, "moe_intermediate_size": 768,
+                    "n_routed_experts": 16}
+    one = flops_mla_moe.gmm_ops_bytes(tokens=16384.0, top_k=8, held_share=4096 / (16384 * 8.0),
+                                      hidden_size=2048, expert_width=768, experts=16)
+    least = flops.roofline_seconds(one, obs["peaks"])["seconds"]
+    assert kernel_roofline_mod.reduce(obs, args) == pytest.approx(100 * least * 2 / 4000e-9)
+    fewer = dict(obs, shapes=dict(obs["shapes"], moe_rows_multiplied=2 * 5 * 1024.0))
+    assert kernel_roofline_mod.reduce(fewer, args) < kernel_roofline_mod.reduce(obs, args)
+    # steps in which the held experts got no row: an empty launch's time is
+    # held against no work at all, so the metric is left out, never a share
+    none = dict(obs, shapes=dict(obs["shapes"], moe_rows_multiplied=0.0))
+    assert kernel_roofline_mod.reduce(none, args) is None
 
 
 def test_program_value_reads_the_programs_registry_and_nothing_else():
@@ -156,11 +180,9 @@ def test_program_value_reads_the_programs_registry_and_nothing_else():
         get_registry().counter("moe.assignments_held").inc(100)
         assert program_value.reduce({}, {"counter": "moe.assignments_held", "over":
                                          "moe.assignments", "scale": 100.0}) == pytest.approx(6.25)
-        # the rows really multiplied: held less dropped; None without the third counter
-        names = ("moe.assignments_held", "moe.assignments", "moe.assignments_dropped")
-        assert program_value.ratio(*names) is None
+        assert program_value.ratio("moe.assignments_dropped", "moe.assignments_held") is None
         get_registry().counter("moe.assignments_dropped").inc(20)
-        assert program_value.ratio(*names) == pytest.approx(0.05)
+        assert program_value.ratio("moe.assignments_dropped", "moe.assignments_held") == 0.2
         assert program_value.reduce({}, {"counter": "moe.assignments_dropped", "over":
                                          "moe.assignments_held", "scale": 100.0}) == pytest.approx(20.0)
     finally:
@@ -201,25 +223,9 @@ def test_every_new_metric_is_in_the_cells_traced_line_and_no_other_cells():
     assert {m["name"] for m in MANIFEST["per_layer"]
             if m["moves"] == "setup_s" and CELL in m["workloads"]} == setup
     qwen = {m["name"] for m in bench_run.cell_metrics(MANIFEST, "per_layer", "qwen3_0p6b.train_packed_4k")}
-    assert not qwen & want and "mfu_pct.train" in qwen and len(qwen) == 20
+    assert not qwen & want and "mfu_pct.train" in qwen and len(qwen) == 19
     for m in MANIFEST["per_layer"]:
         assert "workloads" in m, f"{m['name']} would be asked of every later train cell"
-
-
-def test_nothing_the_benchmark_had_is_changed():
-    """Every file of PR 26-28 as it was (the recorded digests of PR 26's, and
-    the rest against their content at the parent is the reviewer's diff);
-    BENCHMARK.json's old entries as they were but for the six lists."""
-    with open(os.path.join(HERE, "data", "pr26_files.sha256.json")) as f:
-        recorded = json.load(f)
-    for rel, digest in recorded.items():
-        with open(os.path.join(ROOT, rel), "rb") as f:
-            assert hashlib.sha256(f.read()).hexdigest() == digest, f"{rel} changed"
-    assert MANIFEST["configs"][0]["name"] == "qwen3_0p6b" and MANIFEST["run_seconds"] == 30
-    assert [w["name"] for w in MANIFEST["workloads"]] == ["qwen3_0p6b.train_packed_4k", CELL]
-    assert [m["bound"] for m in MANIFEST["end_to_end"]] == [0.01, 0.1]
-    listed = [m["name"] for m in MANIFEST["per_layer"][:20]]
-    assert listed[:4] == ["data_wait_share.train", "padding_share.train", "step_ms.train", "mfu_pct.train"]
 
 
 # ------------------------------------------------------------ planted faults
@@ -227,9 +233,10 @@ def _drive_in_process(monkeypatch, capsys, seed=5):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     rc = bench_run.main(["--workload", CELL, "--seed", str(seed), "--seconds", "1",
                          "--trace", "0", "--rehearsal"])
-    out = capsys.readouterr().out
+    said = capsys.readouterr()
     assert rc == 0
-    return json.loads(out.strip().splitlines()[-1]), out
+    # the result line is standard output's last; the checks are standard error's
+    return json.loads(said.out.strip().splitlines()[-1]), said.out + said.err
 
 
 def test_a_dropped_assignment_is_not_correct(monkeypatch, capsys):
@@ -244,6 +251,11 @@ def test_a_dropped_assignment_is_not_correct(monkeypatch, capsys):
     line, out = _drive_in_process(monkeypatch, capsys)
     assert line["correct"] is False
     assert re.search(r"check FAIL (loss|first_grad_norm)", out)
+    # and the result line shows the failing check by name, its number past its limit
+    failed = [c for c in line["checks"] if not c["ok"]]
+    assert {c["name"] for c in failed} & {"loss_1_rel_gap", "loss_2_rel_gap",
+                                          "first_grad_norm_worst_leaf"}
+    assert all(c["value"] > c["limit"] for c in failed) and list(line)[-1] == "checks"
 
 
 def test_the_mtp_loss_left_out_is_not_correct(monkeypatch, capsys):

@@ -12,7 +12,7 @@ for set in $(seq 1 "$sets"); do
     grep "check FAIL\|Error\|window:" chiprun_out/sets/last.log | cut -c1-700
     grep "check \|window:\|reference\|seconds between" chiprun_out/sets/last.log | sed "s/^/set=$set seed=$seed /" \
       >> "chiprun_out/sets/$cell.checks.log"
-    line=$(tail -n 1 chiprun_out/sets/last.log)
+    line=$(grep "^{\"correct\"" chiprun_out/sets/last.log | tail -n 1)  # standard error ends with the checks
     echo "set=$set seed=$seed rc=$rc $line"
     echo "{\"cell\": \"$cell\", \"set\": $set, \"seed\": $seed, \"rc\": $rc, \"line\": $line}" \
       >> "chiprun_out/sets/$cell.jsonl"

@@ -14,6 +14,12 @@ its body's operations nested inside its own interval), ``XLA Modules`` one
 per executed program. Host planes hold one line per thread, with the
 program's spans (``TraceAnnotation``) among their events. All planes share
 one clock, in nanoseconds.
+
+**The window** every reduction below reads is :func:`window_ns`: where the job
+has cut the trace on its step program's executions (:func:`cut_to_steps`),
+from the start of the first execution the trace holds whole to the end of the
+last; else the host's ``bench.window`` span; else the device operations'
+extent.
 """
 
 from __future__ import annotations
@@ -29,6 +35,7 @@ MODULES_LINE = "XLA Modules"
 # the program's spans and the benchmark's own are dotted lower-case names
 SPAN_NAME = re.compile(r"^[a-z_]+(\.[a-z_0-9]+)+$")
 WINDOW_SPAN = "bench.window"
+STEP_WINDOW = "step_window_ns"  # the key cut_to_steps leaves in a trace
 
 
 def short_name(op: str) -> str:
@@ -88,9 +95,11 @@ def host_spans(trace: dict) -> List[list]:
     return sorted(out, key=lambda e: e[1])
 
 
-def window_ns(trace: dict) -> Tuple[int, int]:
-    """The traced window: the ``bench.window`` span where the host plane has
-    it, else from the first device operation's start to the last one's end."""
+def traced_span_ns(trace: dict) -> Tuple[int, int]:
+    """The stretch in which the profiler was certainly on: the ``bench.window``
+    span where the host plane has it (entered after the profiler started, left
+    before it stopped), else from the first device operation's start to the
+    last one's end."""
     marks = [ev for ev in host_spans(trace) if ev[0] == WINDOW_SPAN]
     if marks:
         return marks[0][1], marks[0][1] + marks[0][2]
@@ -98,6 +107,67 @@ def window_ns(trace: dict) -> Tuple[int, int]:
     if not evs:
         raise ValueError("the trace holds no device operation")
     return min(e[1] for e in evs), max(e[1] + e[2] for e in evs)
+
+
+def window_ns(trace: dict) -> Tuple[int, int]:
+    """The window every reduction reads: the whole step executions
+    :func:`cut_to_steps` found, where it was asked and found two or more;
+    else :func:`traced_span_ns`."""
+    return tuple(trace.get(STEP_WINDOW) or traced_span_ns(trace))
+
+
+def program_executions(trace: dict, pattern: str) -> List[Tuple[int, int]]:
+    """(start, end) of every execution of the programs whose name matches
+    ``pattern`` (``re.search`` over the ``XLA Modules`` events' names, one
+    event an execution), on the first device plane, by start."""
+    planes = device_planes(trace)
+    if not planes:
+        return []
+    rx = re.compile(pattern)
+    return sorted((s, s + d) for n, s, d in line_events(planes[0], MODULES_LINE) if rx.search(n))
+
+
+def whole_executions(trace: dict, pattern: str) -> List[Tuple[int, int]]:
+    """The executions the trace holds WHOLE: those that start and end strictly
+    inside :func:`traced_span_ns` AND strictly inside the device's own first
+    and last event. One that was running when the profiler started, or still
+    is when it stops, shows cut to the trace's edge (or with its true start
+    outside) and is left out; the device's events can begin a hair after the
+    host's span does, so the span alone does not tell (a cut execution read
+    768 ms among whole ones of 810-857, PR 43)."""
+    runs = program_executions(trace, pattern)
+    if not runs:
+        return []
+    lo, hi = traced_span_ns(trace)
+    device = [ev for name in (OPS_LINE, MODULES_LINE)
+              for ev in line_events(device_planes(trace)[0], name)]
+    lo = max(lo, min(s for _, s, _ in device))
+    hi = min(hi, max(s + d for _, s, d in device))
+    return [(a, b) for a, b in runs if a > lo and b < hi]
+
+
+def cut_to_steps(trace: dict, pattern: str, at_most: Optional[int] = None, skip: int = 0) -> int:
+    """Cut the trace's window on the step program's own executions: from the
+    start of the first whole execution to the end of the last of those that
+    follow it in a row (``at_most`` of them). ``skip`` leaves out the first
+    executions the trace SHOWS, whole or cut, and the one after them has to be
+    whole: a job that knows which step the trace's first execution is so names
+    the steps of its window by number. Returns how many whole executions the
+    window holds; with fewer than two the window is left as it was and the
+    count is what was seen, so that a caller never divides by a step it did not
+    see."""
+    whole = set(whole_executions(trace, pattern))
+    steps: List[Tuple[int, int]] = []
+    for run in program_executions(trace, pattern)[skip:]:
+        if run in whole:
+            steps.append(run)
+        elif steps or skip:
+            break
+    steps = steps[:at_most]
+    trace.pop(STEP_WINDOW, None)
+    if len(steps) >= 2:
+        trace[STEP_WINDOW] = [steps[0][0], steps[-1][1]]
+    return len(steps)
 
 
 def clip(events: Iterable[list], lo: int, hi: int) -> List[list]:
