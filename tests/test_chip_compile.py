@@ -430,8 +430,11 @@ def test_paged_attend_lowers_for_v5e_and_reads_the_pool_in_place(v5e, on_chip_ke
 PAGED_STEPS = {"decode_nb256": 256, "decode_nb512": 512, "prefill_cb32_nb8": 8}
 
 
-@pytest.mark.parametrize("step", sorted(PAGED_STEPS))
-def test_paged_steps_carry_the_pool_and_move_nothing_of_its_size(v5e, on_chip_kernels, step):
+def _compile_paged_step(v5e, step, sampled=False):
+    """One of ``PAGED_STEPS`` compiled at the published widths of four layers
+    for the described chip; ``sampled``: the decode step as the engine jits
+    it, the per-slot key split and ``sample_tokens`` behind the logits.
+    Returns (the compiled step, cfg, the pool's [layers, blocks, block size])."""
     import yaml
 
     from veomni_tpu.models import build_foundation_model
@@ -455,6 +458,14 @@ def test_paged_steps_carry_the_pool_and_move_nothing_of_its_size(v5e, on_chip_ke
         fn = lambda p, k, v, tables, pos, tok: dm.paged_decode_step(p, cfg, (k, v), tables, pos, tok)
         args = [described(v5e[0], (slots, nb), jnp.int32), described(v5e[0], (slots,), jnp.int32),
                 described(v5e[0], (slots,), jnp.int32)]
+        if sampled:
+            def fn(p, k, v, tables, pos, tok, keys, temps, top_ks, top_ps):
+                logits, pools = dm.paged_decode_step(p, cfg, (k, v), tables, pos, tok)
+                split = jax.vmap(lambda key: jax.random.split(key, 2))(keys)
+                return dm.sample_tokens(logits, split[:, 1], temps, top_ks, top_ps), split[:, 0], pools
+
+            args += [described(v5e[0], (slots, 2), jnp.uint32), described(v5e[0], (slots,), jnp.float32),
+                     described(v5e[0], (slots,), jnp.int32), described(v5e[0], (slots,), jnp.float32)]
     else:
         fn = lambda p, k, v, table, start, tok, n: dm.paged_prefill_step(
             p, cfg, (k, v), table, start, tok, n, 32)
@@ -465,6 +476,13 @@ def test_paged_steps_carry_the_pool_and_move_nothing_of_its_size(v5e, on_chip_ke
         compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(params, pool, pool, *args).compile()
     finally:
         KERNEL_REGISTRY.clear_pins()
+    return compiled, cfg, (layers, pool_blocks, bs)
+
+
+@pytest.mark.parametrize("step", sorted(PAGED_STEPS))
+def test_paged_steps_carry_the_pool_and_move_nothing_of_its_size(v5e, on_chip_kernels, step):
+    compiled, cfg, (layers, pool_blocks, bs) = _compile_paged_step(v5e, step)
+    hkv, d = cfg.num_key_value_heads, cfg.head_dim
     text = compiled.as_text()
     if step.startswith("decode"):
         # one kernel, in the loop's body, reading the carried stack
@@ -490,3 +508,20 @@ def test_paged_steps_carry_the_pool_and_move_nothing_of_its_size(v5e, on_chip_ke
             name, opcode)
     # and no second pool among the temporaries: under one layer's K and V
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * one_layer * 2
+
+
+def test_the_decode_step_with_its_sampler_sorts_nothing_and_switches_three_ways(v5e, on_chip_kernels):
+    """The engine's decode step with its sampler (PR 49): no instruction of
+    the compiled step orders a row (`sort`, which is what `lax.top_k` becomes
+    here too), the sampler is one `conditional` of three branches, and the
+    greedy tick's branch hands the `argmax` on and does nothing else."""
+    compiled, _, _ = _compile_paged_step(v5e, "decode_nb256", sampled=True)
+    text = compiled.as_text()
+    assert not re.search(r" sort\(", text)
+    switches = [line for line in text.splitlines()
+                if re.search(r" conditional\(", line) and "/sampler/" in line]
+    assert len(switches) == 1, switches
+    branches = re.search(r"branch_computations=\{([^}]*)\}", switches[0]).group(1).split(",")
+    assert len(branches) == 3
+    greedy = re.search(r"\n%s \([^\n]*\{\n(.*?)\n\}" % re.escape(branches[0].strip()), text, re.S)
+    assert greedy and len(greedy.group(1).splitlines()) <= 3, greedy

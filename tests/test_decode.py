@@ -170,6 +170,163 @@ def test_per_slot_sample_tokens_matches_scalar_semantics():
     assert (out == out2).all()
 
 
+# --------------------------------------------------------------------------
+# The sampler does what a call's rows ask for (PR 49): `argmax` alone, the
+# categorical draw, or a threshold by value. Held against HF's rule written
+# here in float64: temperature, top-k, top-p over the top-k's renormalised
+# mass, the crossing token and all its ties kept, top-1 always.
+
+SAMPLER_V = 4099
+SAMPLER_ROWS = 6
+
+
+def _sampler_logits():
+    """Six rows, flat to peaked, with ties planted where a filter cuts: two
+    equal maxima (rows 0, 3), the 20th to 22nd largest equal, the 50th and
+    51st, the 1,024th and 1,025th."""
+    rng = np.random.default_rng(49)
+    rows = []
+    for i, scale in enumerate((0.5, 1.0, 2.0, 3.0, 5.0, 8.0)):
+        vals = np.sort(rng.normal(size=SAMPLER_V).astype(np.float32) * np.float32(scale))[::-1].copy()
+        if i in (0, 3):
+            vals[1] = vals[0]
+        vals[20:22] = vals[19]
+        vals[50] = vals[49]
+        vals[1024] = vals[1023]
+        rows.append(vals[rng.permutation(SAMPLER_V)])
+    return np.stack(rows)
+
+
+def _hf_kept(l, top_k, top_p):
+    """(kept [V] bool, mass above each token [V]) of one row ``l`` float64."""
+    v = l.size
+    sl = np.sort(l)[::-1]
+    k = top_k if 0 < top_k < v else v
+    p = np.exp(sl[:k] - sl[0])
+    p /= p.sum()
+    before = np.cumsum(p) - p  # the mass strictly ahead of a sorted place
+    n = max(int((before < top_p).sum()), 1) if top_p < 1.0 else k
+    # the mass of the values above a token's own, which decides its value's
+    # fate (1 for a token that top-k has cut already)
+    above = np.append(before, 1.0)[np.searchsorted(-sl[:k], -l, side="left")]
+    return l >= sl[n - 1], above
+
+
+def _rows(temperature, top_k, top_p):
+    full = lambda x, dt: np.full(SAMPLER_ROWS, x, dt)
+    return full(temperature, np.float32), full(top_k, np.int32), full(top_p, np.float32)
+
+
+SAMPLER_CASES = {
+    "greedy": _rows(0.0, 0, 1.0),
+    "greedy_whatever_the_filters_say": _rows(0.0, 20, 0.5),
+    "unfiltered": _rows(0.7, 0, 1.0),
+    "top_k_1": _rows(0.7, 1, 1.0), "top_k_20": _rows(0.6, 20, 1.0), "top_k_50": _rows(1.0, 50, 1.0),
+    "top_k_1024": _rows(1.3, 1024, 1.0), "top_k_1025": _rows(1.3, 1025, 1.0),
+    "top_k_v": _rows(0.7, SAMPLER_V, 1.0), "top_k_v_plus_1": _rows(0.7, SAMPLER_V + 1, 1.0),
+    "top_p_1e-6": _rows(0.7, 0, 1e-6), "top_p_0p5": _rows(1.0, 0, 0.5),
+    "top_p_0p95": _rows(0.6, 0, 0.95),
+    # hot and flat: the nucleus holds thousands of tokens
+    "top_p_wider_than_1024": _rows(4.0, 0, 0.9),
+    "top_k_20_top_p_0p95": _rows(0.6, 20, 0.95), "top_k_50_top_p_0p5": _rows(1.0, 50, 0.5),
+    "top_k_inside_the_nucleus": _rows(2.0, 5, 0.999),
+    # every kind in one call, greedy rows among them
+    "mixed": (np.asarray([0.0, 0.7, 0.6, 1.0, 0.0, 4.0], np.float32),
+              np.asarray([20, 0, 20, 0, 0, 1025], np.int32),
+              np.asarray([0.5, 1.0, 0.95, 0.5, 1.0, 0.9], np.float32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SAMPLER_CASES))
+def test_sample_tokens_keeps_and_draws_what_hfs_rule_in_float64_does(case):
+    from veomni_tpu.models import decode as dm
+
+    temperature, top_k, top_p = SAMPLER_CASES[case]
+    logits = _sampler_logits()
+    keys = np.asarray(jax.vmap(jax.random.PRNGKey)(jnp.arange(100, 100 + SAMPLER_ROWS)))
+    sample = jax.jit(dm.sample_tokens)
+    tokens = np.asarray(sample(logits, keys, temperature, top_k, top_p))
+    l32 = logits / np.maximum(temperature, np.float32(1e-6))[:, None]
+    thresh = np.asarray(jax.jit(dm._filter_threshold)(l32, top_k, top_p))
+    want_path = dm.SAMPLER_PATHS[int(dm.sampler_path(temperature, top_k, top_p, SAMPLER_V))]
+    assert want_path == {"greedy": "greedy", "greedy_whatever_the_filters_say": "greedy",
+                         "unfiltered": "unfiltered", "top_k_v": "unfiltered",
+                         "top_k_v_plus_1": "unfiltered"}.get(case, "filtered")
+    for i in range(SAMPLER_ROWS):
+        if temperature[i] <= 0.0:
+            assert tokens[i] == np.argmax(logits[i])
+            continue
+        kept, above = _hf_kept(l32[i].astype(np.float64), int(top_k[i]), float(top_p[i]))
+        got = l32[i] >= thresh[i]
+        differ = np.flatnonzero(got != kept)
+        # only a token whose fate hangs on the mass above it to within 1e-6
+        assert np.all(np.abs(above[differ] - top_p[i]) <= 1e-6), (case, i, differ[:5])
+        assert got[np.argmax(logits[i])]  # top-1 always survives
+        assert got[tokens[i]]
+        if not differ.size:
+            alone = jax.random.categorical(keys[i], jnp.where(kept, l32[i], -jnp.inf))
+            assert tokens[i] == int(alone), (case, i)
+    # a row's answer is its own: the same alone, and beside other rows
+    for i in range(SAMPLER_ROWS):
+        alone = sample(logits[i:i + 1], keys[i:i + 1], temperature[i:i + 1],
+                       top_k[i:i + 1], top_p[i:i + 1])
+        assert int(alone[0]) == tokens[i], (case, i)
+    turned = np.roll(np.arange(SAMPLER_ROWS), 2)
+    again = np.asarray(sample(logits[turned], keys[turned], temperature[turned],
+                              top_k[turned], top_p[turned]))
+    assert (again == tokens[turned]).all()
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr and in what it calls."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitives(sub)
+    return names
+
+
+def test_the_greedy_branch_is_argmax_and_no_branch_sorts():
+    from veomni_tpu.models import decode as dm
+
+    s, v = 4, 512
+    jaxpr = jax.make_jaxpr(dm.sample_tokens)(
+        jnp.zeros((s, v)), jnp.zeros((s, 2), jnp.uint32), jnp.zeros(s), jnp.zeros(s, jnp.int32),
+        jnp.ones(s)).jaxpr
+    switch = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "cond"]
+    assert len(switch) == 1 and len(switch[0].params["branches"]) == len(dm.SAMPLER_PATHS)
+    greedy, unfiltered, filtered = (_primitives(b.jaxpr) for b in switch[0].params["branches"])
+    order = {"sort", "cumsum", "top_k"}
+    draw = {"random_bits", "threefry2x32"}
+    loops = {"while", "scan"}
+    # argmax is made once, outside the switch, and the greedy branch hands it on
+    assert "argmax" in {eqn.primitive.name for eqn in jaxpr.eqns}
+    assert not greedy, greedy
+    assert unfiltered & draw and not unfiltered & (order | loops)
+    assert filtered & draw and filtered & loops and not filtered & order
+
+
+def test_sampler_path_is_one_predicate_for_numpy_and_traced_arrays():
+    from veomni_tpu.models import decode as dm
+
+    v = 64
+    rows = [  # temperature, top_k, top_p -> path
+        ((0.0, 20, 0.5), 0), ((0.0, 0, 1.0), 0), ((1.0, 0, 1.0), 1), ((1.0, v, 1.0), 1),
+        ((1.0, v + 1, 1.0), 1), ((1.0, -1, 2.0), 1), ((1.0, v - 1, 1.0), 2), ((1.0, 0, 0.999), 2),
+    ]
+    for (t, k, p), want in rows:
+        for beside in ((0.0, 20, 0.5), (1.0, 0, 1.0)):  # a greedy row, an unfiltered one
+            arrays = (np.asarray([t, beside[0]], np.float32), np.asarray([k, beside[1]], np.int32),
+                      np.asarray([p, beside[2]], np.float32))
+            expect = max(want, 1 if beside[0] > 0 else 0)
+            assert int(dm.sampler_path(*arrays, v)) == expect
+            assert int(jax.jit(lambda a, b, c: dm.sampler_path(a, b, c, v))(*arrays)) == expect
+
+
 def test_prompt_length_bucketing_keeps_compiles_flat():
     """Distinct prompt lengths inside one power-of-two bucket must reuse the
     SAME prefill/decode compilation (each retrace costs 20-40s on TPU) and

@@ -692,6 +692,47 @@ def test_engine_metrics_are_host_floats(qwen3):
     assert WandbCallback._host_floats(mixed) == host_floats(mixed)
 
 
+def test_engine_counts_its_decode_ticks_by_what_the_sampler_had_to_do(qwen3):
+    """``serve.sampler_ticks.<path>``: one count a decode tick, by the most
+    work any running slot asked of the sampler (PR 49). The expected path of
+    every decode step is reckoned here from the arrays the step was handed,
+    slot by slot in plain Python."""
+    from veomni_tpu.observability.metrics import get_registry
+
+    params, cfg = qwen3
+    eng = InferenceEngine(params, cfg, EngineConfig(num_slots=2, block_size=8, max_model_len=64))
+    counters = {path: get_registry().counter(f"serve.sampler_ticks.{path}")
+                for path in decode_mod.SAMPLER_PATHS}
+    before = {path: c.value for path, c in counters.items()}
+    # the host counts over the vocabulary the program's switch sees: the logits' width
+    assert eng._head_width == decode_mod.lm_head_kernel(params, cfg).shape[-1]
+    step, seen = eng._decode_step, []
+
+    def noting(*args):
+        temps, top_ks, top_ps = (np.asarray(a) for a in args[7:10])
+        samples = [t > 0 for t in temps]
+        filters = [s and ((0 < k < cfg.vocab_size) or p < 1.0)
+                   for s, k, p in zip(samples, top_ks, top_ps)]
+        seen.append("filtered" if any(filters) else "unfiltered" if any(samples) else "greedy")
+        return step(*args)
+
+    eng._decode_step = noting
+    a, b = _prompts((9, 12), seed=11)
+
+    def run(*samplings):
+        eng.run([Request(prompt_ids=p, sampling=sp) for p, sp in zip((a, b), samplings)])
+
+    run(SamplingParams(max_new_tokens=4), SamplingParams(max_new_tokens=5, top_k=3, top_p=0.5))
+    assert set(seen) == {"greedy"}  # a filter on a greedy request asks for nothing
+    run(SamplingParams(max_new_tokens=3), SamplingParams(max_new_tokens=6, temperature=1.0))
+    assert set(seen) == {"greedy", "unfiltered"}
+    run(SamplingParams(max_new_tokens=7, temperature=1.0, top_k=cfg.vocab_size),
+        SamplingParams(max_new_tokens=4, temperature=0.7, top_k=5, top_p=0.9))
+    assert set(seen) == set(decode_mod.SAMPLER_PATHS) and seen[-1] == "unfiltered"
+    for path, c in counters.items():
+        assert c.value - before[path] == seen.count(path), (path, seen)
+
+
 def test_engine_ttft_is_window_scoped(qwen3):
     """Satellite bugfix: ttft_avg_s resets with the metrics window like
     decode_tokens_per_sec; the lifetime average lives under its own key."""

@@ -254,6 +254,62 @@ def test_spec_enabled_honors_registry_pin(qwen3):
 
 
 # ------------------------------------------------------------- engine parity
+# ------------------------------------------------- verify_accept, by name
+def _one_token_chain(logits, tokens, n_input, keys, temperature, top_k, top_p):
+    """What the one-token path emits over a verify step's columns: a split
+    and a ``sample_tokens`` call a token, going on while the draft matched."""
+    s, kb, _ = logits.shape
+    targets = np.zeros((s, kb), np.int32)
+    n_emit = np.zeros(s, np.int32)
+    new_keys = np.zeros((s, 2), np.uint32)
+    for i in range(s):
+        carry, emitting = jnp.asarray(keys[i], jnp.uint32), True
+        for j in range(kb):
+            carry, sub = jax.random.split(carry)
+            tok = int(decode_mod.sample_tokens(
+                logits[i:i + 1, j], sub[None], temperature[i:i + 1], top_k[i:i + 1],
+                top_p[i:i + 1])[0])
+            targets[i, j] = tok
+            if emitting:  # every draft before this column was accepted
+                n_emit[i], new_keys[i] = j + 1, np.asarray(carry)
+                emitting = j + 1 < n_input[i] and tokens[i, j + 1] == tok
+    return targets, n_emit, new_keys
+
+
+VERIFY_ROWS = {
+    # temperature, top_k, top_p of four slots
+    "greedy": ([0.0] * 4, [0] * 4, [1.0] * 4),
+    "a_filtered_row_in_the_batch": ([0.0, 0.8, 0.0, 1.0], [0, 5, 0, 0], [1.0, 0.9, 1.0, 1.0]),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(VERIFY_ROWS))
+def test_verify_accept_is_the_one_token_paths_chain_bit_for_bit(rows):
+    s, kb, v = 4, 4, 96
+    rng = np.random.default_rng(7)
+    logits = jnp.asarray(rng.normal(size=(s, kb, v)) * 3, jnp.float32)
+    keys = np.asarray(jax.vmap(jax.random.PRNGKey)(jnp.arange(40, 40 + s)))
+    temperature, top_k, top_p = (np.asarray(x, dt) for x, dt in
+                                 zip(VERIFY_ROWS[rows], (np.float32, np.int32, np.float32)))
+    n_input = np.asarray([4, 3, 1, 4], np.int32)
+    # drafts: the chain's own tokens (accepted) with one planted miss a slot
+    free, _, _ = _one_token_chain(logits, np.zeros((s, kb), np.int32), np.ones(s, np.int32),
+                                  keys, temperature, top_k, top_p)
+    tokens = np.zeros((s, kb), np.int32)
+    tokens[:, 1:] = free[:, :-1]
+    tokens[0, 3] = (tokens[0, 3] + 1) % v  # slot 0: the third draft misses
+    tokens[1, 1] = (tokens[1, 1] + 1) % v  # slot 1: the first draft misses
+    want = _one_token_chain(logits, tokens, n_input, keys, temperature, top_k, top_p)
+    got = jax.jit(decode_mod.verify_accept)(logits, tokens, n_input, keys, temperature, top_k, top_p)
+    targets, n_emit, new_keys = (np.asarray(x) for x in got)
+    assert n_emit.tolist() == want[1].tolist() == [3, 1, 1, 4]
+    for i in range(s):
+        assert targets[i, :n_emit[i]].tolist() == want[0][i, :n_emit[i]].tolist()
+    assert (new_keys == want[2]).all()
+    greedy = temperature <= 0
+    assert (targets[greedy] == np.asarray(jnp.argmax(logits, -1))[greedy]).all()
+
+
 def test_spec_engine_greedy_parity_staggered(qwen3):
     """The acceptance gate: staggered arrivals through a spec_k=4 engine
     emit exactly the tokens isolated generation produces — and on a

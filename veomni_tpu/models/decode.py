@@ -916,6 +916,83 @@ def _select_token(logits, rng, temperature: float, top_k: int,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+#: what a ``sample_tokens`` call has to do, least first: ``argmax`` alone;
+#: a categorical draw and no threshold; a threshold for the rows that filter
+SAMPLER_PATHS = ("greedy", "unfiltered", "filtered")
+
+
+def sampler_path(temperature, top_k, top_p, vocab: int):
+    """Index into ``SAMPLER_PATHS`` of the most work any row of a call asks
+    for. Written in what numpy arrays and traced ones share, so the engine's
+    count on the host (``serve.sampler_ticks``) and the ``lax.switch`` inside
+    ``sample_tokens`` are one predicate and cannot drift."""
+    samples = temperature > 0.0
+    filters = samples & (((top_k > 0) & (top_k < vocab)) | (top_p < 1.0))
+    return samples.any() * 1 + filters.any() * 1
+
+
+def _ordered_of_float(x):
+    """The place of float32 ``x`` among all float32 bit patterns, lowest
+    value first, as uint32 (-inf is 0x007fffff, +inf 0xff800000)."""
+    sign = jnp.uint32(0x80000000)
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >= sign, ~bits, bits | sign)
+
+
+def _float_of_ordered(u):
+    sign = jnp.uint32(0x80000000)
+    bits = jnp.where(u >= sign, u ^ sign, ~u)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _lowest_value(row_max, keeps):
+    """Per row, the lowest float32 ``x`` with ``keeps(x [S]) -> bool [S]``,
+    given that it holds at ``row_max`` and is monotone in ``x``. Where
+    ``keeps`` reads the row through sums over ``row > x`` alone, it is
+    constant between two neighbouring values of the row, so the answer IS
+    one of the row's values: a selection by value, 32 halvings over
+    float32's ordered bit patterns at one pass over the row each, where a
+    sort would order the whole vocabulary."""
+    hi = _ordered_of_float(row_max)
+    lo = jnp.full_like(hi, jnp.uint32(0x007FFFFF))  # -inf: no logit lies below it
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = lo + (hi - lo) // 2
+        ok = keeps(_float_of_ordered(mid))
+        return jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)
+
+    return _float_of_ordered(jax.lax.fori_loop(0, 32, halve, (lo, hi))[1])
+
+
+def _filter_threshold(l, top_k, top_p):
+    """[S] the lowest logit each row of ``l`` [S,V] keeps under HF's rule
+    (top-k, then top-p over the top-k's renormalised mass; the crossing
+    token and all its ties kept; top-1 always survives), -inf for a row that
+    sets no filter. The threshold is the lowest value ``x`` of the row with
+    fewer than ``k`` logits above it and less than ``top_p`` of the kept
+    mass above it, and both counts only grow as ``x`` falls."""
+    v = l.shape[-1]
+    top = l.max(axis=-1)
+    cuts = (top_k > 0) & (top_k < v)
+    k = jnp.where(cuts, top_k, v)
+
+    def above(x):
+        return l > x[:, None]
+
+    kth = _lowest_value(top, lambda x: above(x).sum(-1) < k)
+    # the mass of exactly k logits, as a sort's first k hold it: all above
+    # the k-th value, and as many of its ties as fill the count
+    e = jnp.exp(l - top[:, None])
+    n_above = above(kth).sum(-1)
+    mass_k = (jnp.where(above(kth), e, 0.0).sum(-1)
+              + (k - n_above) * jnp.exp(kth - top))
+    crossing = _lowest_value(top, lambda x: (x >= top) | (
+        jnp.where(above(x), e, 0.0).sum(-1) < top_p * mass_k))
+    return jnp.maximum(jnp.where(cuts, kth, -jnp.inf),
+                       jnp.where(top_p < 1.0, crossing, -jnp.inf))
+
+
 @jax.named_scope("sampler")  # observability/scopes.py
 def sample_tokens(logits, keys, temperature, top_k, top_p):
     """Per-slot sampling for the serving engine: every parameter is a traced
@@ -926,30 +1003,32 @@ def sample_tokens(logits, keys, temperature, top_k, top_p):
 
     Per-slot semantics match ``_select_token``: temperature<=0 is greedy,
     top_k<=0 keeps everything (clamped to vocab), top_p>=1 keeps everything.
+
+    The call does what its rows ask for and no more (``sampler_path``, read
+    from the three arrays at run time inside the one program): ``argmax``
+    alone where no row samples, the categorical draw with no threshold where
+    no sampling row filters, ``_filter_threshold`` where one does. No path
+    sorts the vocabulary.
     """
-    v = logits.shape[-1]
     temperature = jnp.asarray(temperature, jnp.float32)
     top_k = jnp.asarray(top_k, jnp.int32)
     top_p = jnp.asarray(top_p, jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    l = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    # ONE full-vocab sort serves both filters (this is the per-token decode
-    # hot path): top-k keeps a prefix of the sorted order and the nucleus
-    # keeps a prefix of THAT, so both reduce to one threshold from ``sl``.
-    sl = jnp.sort(l, axis=-1)[..., ::-1]
-    k_idx = jnp.clip(jnp.where(top_k > 0, top_k, v), 1, v) - 1
-    in_k = jnp.arange(v)[None] <= k_idx[:, None]
-    p = jax.nn.softmax(jnp.where(in_k, sl, -jnp.inf), axis=-1)
-    cum = jnp.cumsum(p, axis=-1)
-    keep = in_k & ((cum - p) < top_p[:, None])
-    nkeep = jnp.maximum(keep.sum(-1), 1)
-    thresh = jnp.take_along_axis(sl, (nkeep - 1)[:, None], axis=-1)
-    l = jnp.where(l < thresh, -jnp.inf, l)
-    sampled = jax.vmap(
-        lambda key, row: jax.random.categorical(key, row)
-    )(keys, l).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    def draw(filtered: bool):
+        l = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        if filtered:
+            l = jnp.where(l < _filter_threshold(l, top_k, top_p)[:, None],
+                          -jnp.inf, l)
+        sampled = jax.vmap(
+            lambda key, row: jax.random.categorical(key, row)
+        )(keys, l).astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy, sampled)
+
+    return jax.lax.switch(
+        sampler_path(temperature, top_k, top_p, logits.shape[-1]),
+        (lambda: greedy, lambda: draw(False), lambda: draw(True)),
+    )
 
 
 def _decode_impl(params, cfg: TransformerConfig, caches, first_token,

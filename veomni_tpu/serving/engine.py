@@ -450,6 +450,15 @@ class InferenceEngine:
         # the paged_attend kernel reads of what a gather to the table's
         # width would have copied
         self._m_live_pages = reg.gauge("serve.paged_live_page_share")
+        # decode ticks by the work their slots ask of the sampler: the same
+        # predicate the program's own switch reads (decode.sampler_path), on
+        # the same vocabulary, the head's output width
+        self._head_width = jax.eval_shape(
+            lambda p: decode_mod.lm_head_kernel(p, cfg), params).shape[-1]
+        self._m_sampler_ticks = tuple(
+            reg.counter(f"serve.sampler_ticks.{path}")
+            for path in decode_mod.SAMPLER_PATHS
+        )
         self._m_preempt = reg.gauge("serve.preemptions")
         self._m_tps = reg.gauge("serve.decode_tokens_per_sec")
         self._m_hit_rate = reg.gauge("serve.prefix_hit_rate")
@@ -1096,6 +1105,8 @@ class InferenceEngine:
         # an idle slot attends position 0 of the null block: one page
         bs = self.config.block_size
         self._m_live_pages.set(float((positions // bs + 1).sum()) / tables.size)
+        self._m_sampler_ticks[decode_mod.sampler_path(
+            temps, top_ks, top_ps, self._head_width)].inc()
         return tables, positions, keys, temps, top_ks, top_ps
 
     def _plain_decode_tick(
